@@ -15,8 +15,12 @@ ops) and transcribes ``hash_layout.mix_rounds`` line for line, so the
 interpret-mode test in tests/test_kernels.py pins that on CPU.
 
 Never imported on the hot path off-TPU: the dispatcher resolves
-``pallas → fused`` when ``jax.default_backend() != "tpu"``, and any
-lowering failure on-TPU trips the PR 3 breaker and degrades.
+``pallas → fused`` when ``jax.default_backend() != "tpu"``.  On a TPU
+``auto`` takes this rung, and a lowering failure there RAISES out of
+the query: ``cached_kernel`` calls the jitted kernel directly unless a
+fault is armed or a breaker is already open, so nothing degrades in
+silence.  tests/test_chip_compile.py compiles the kernel for a
+described v5e under x64 so that such a failure is met before the chip.
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # rows per grid step: one VREG-friendly lane block, small enough that
 # (limbs × block) stays far under VMEM even for wide key sets
@@ -67,11 +72,15 @@ def hash_pairs(his: jnp.ndarray, los: jnp.ndarray,
         oh_ref[:] = h
         ol_ref[:] = l
 
+    # index maps must yield int32: the engine runs under x64, where a
+    # Python literal traces as i64 and Mosaic refuses the mixed
+    # (i64, i32) return
+    zero = np.int32(0)
     oh, ol = pl.pallas_call(
         kernel,
         grid=(n // blk,),
-        in_specs=[pl.BlockSpec((limbs, blk), lambda i: (0, i)),
-                  pl.BlockSpec((limbs, blk), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((limbs, blk), lambda i: (zero, i)),
+                  pl.BlockSpec((limbs, blk), lambda i: (zero, i))],
         out_specs=[pl.BlockSpec((blk,), lambda i: (i,)),
                    pl.BlockSpec((blk,), lambda i: (i,))],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.uint32),

@@ -22,13 +22,6 @@ import numpy as np
 
 from spark_rapids_tpu.runtime.device import ensure_initialized
 
-# jax promoted shard_map out of experimental in 0.6; support both so the
-# collective layer runs on the full range of baked-in jax versions
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
-
 SHUFFLE_AXIS = "shuffle"
 
 
@@ -77,4 +70,4 @@ def all_to_all_shuffle(mesh: jax.sharding.Mesh, parts: jax.Array
         return y[None]  # [1, P, ...]: row p = slice received from device p
 
     spec = jax.sharding.PartitionSpec(axis)
-    return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(parts)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(parts)

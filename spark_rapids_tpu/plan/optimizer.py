@@ -169,7 +169,7 @@ def _prune_inmemory(rel: L.InMemoryRelation, required: Set[int]):
     """Narrowed in-memory relation + old→new index map.  The H2D analog
     of parquet projection pushdown [REF: Spark's ColumnPruning +
     InMemoryTableScanExec partition pruning — here the win is not
-    transferring unused columns through the host↔device tunnel]."""
+    transferring unused columns from host to device]."""
     fields = rel.schema.fields
     if not required:
         required = {0}

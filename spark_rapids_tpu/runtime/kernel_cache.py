@@ -10,9 +10,9 @@ compilation per arithmetic op.
 
 Three layers, innermost first: jax's jit cache (per shape bucket), this
 module's fingerprint cache (per op structure), and — when
-``spark.rapids.tpu.kernel.cacheDir`` is set — jax's on-disk
-compilation cache (per machine, survives process restarts; see
-``configure_persistent_cache``).  The shape plane (runtime/shapes.py)
+off the CPU backend — jax's on-disk compilation cache (survives
+process restarts; ``runtime/device.py::compile_cache_dir`` places
+it).  The shape plane (runtime/shapes.py)
 bounds the bucket axis so all three stay small.
 """
 
@@ -180,14 +180,13 @@ def compile_snapshot() -> Tuple[int, float]:
 # ---------------------------------------------------------------------------
 #
 # The in-process layers above make each (op, schema, bucket) compile once
-# per PROCESS; this layer makes it compile once per MACHINE.  It enables
-# jax's on-disk compilation cache under the conf'd directory, so a fresh
-# QueryServer process whose cacheDir was warmed by a previous run (or by
-# ``session.warmup``) loads executables from disk instead of invoking
-# XLA on the hot path.
+# per PROCESS; this layer makes it compile once per MACHINE: a fresh
+# QueryServer process whose cache directory was warmed by a previous
+# run (or by ``session.warmup``) loads executables from disk instead of
+# invoking XLA on the hot path.  The manifest below guards only a
+# directory the user named with kernel.cacheDir.
 
 MANIFEST_NAME = "tpuq_cache_manifest.json"
-_PERSISTENT_DIR: Optional[str] = None
 
 
 def _cache_versions() -> Dict[str, str]:
@@ -235,43 +234,22 @@ def _sync_manifest(cache_dir: str) -> bool:
 
 
 def configure_persistent_cache(conf) -> Optional[str]:
-    """Point jax's on-disk compilation cache at kernel.cacheDir.
+    """Route ``kernel.cacheDir`` through runtime/device.py's one choice
+    of compile-cache directory.
 
     Called at session init (after the backend is resolved).  An empty
-    cacheDir leaves the runtime/device.py env-var default in charge.
-    On the XLA:CPU backend this is a hard no-op regardless of conf —
-    CPU AOT cache entries carry target pseudo-features the loader's
-    host check rejects, and reading one SEGFAULTS the process (see
-    runtime/device.py) — TPU compile times are what the cache is for.
-    Returns the active directory, or None when disabled."""
-    import os
-
+    cacheDir leaves what ``ensure_initialized`` put in force;
+    ``JAX_COMPILATION_CACHE_DIR`` outranks the conf; on the XLA:CPU
+    backend the cache is off whatever is set (see
+    ``device.configure_compile_cache``).  Returns the directory in
+    force, or None when the cache is off."""
     from spark_rapids_tpu import conf as C
-    from spark_rapids_tpu.runtime.device import (
-        _machine_fingerprint, ensure_initialized)
-    global _PERSISTENT_DIR
+    from spark_rapids_tpu.runtime import device
+    device.ensure_initialized()
     cache_dir = str(conf.get(C.KERNEL_CACHE_DIR)).strip()
     if not cache_dir:
-        return _PERSISTENT_DIR
-    ensure_initialized()
-    if jax.default_backend() == "cpu":
-        return None
-    cache_dir = os.path.join(os.path.expanduser(cache_dir),
-                             _machine_fingerprint())
-    os.makedirs(cache_dir, exist_ok=True)
-    _sync_manifest(cache_dir)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # persist EVERY executable, not only slow ones: the warm-restart
-    # contract is zero hot-path compiles, and a 50 ms compile skipped
-    # from disk is still a compile the storm detector would count
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _PERSISTENT_DIR = cache_dir
-    return cache_dir
-
-
-def persistent_cache_dir() -> Optional[str]:
-    """The conf-selected on-disk cache directory, when one is active."""
-    return _PERSISTENT_DIR
+        return device.cache_dir_in_force()
+    return device.configure_compile_cache(cache_dir)
 
 
 def clear() -> None:

@@ -207,10 +207,8 @@ class SpillableBatch:
         b = self._batch
         leaves, treedef = jax.tree.flatten(b)
         # one overlapped transfer round trip (see columnar.device_to_host)
-        from spark_rapids_tpu.shims import get_shim
-        shim = get_shim()
         for x in leaves:
-            shim.async_copy_to_host(x)
+            x.copy_to_host_async()
         self._host = ([np.asarray(x) for x in leaves], treedef)
         self._batch = None
         self._host_accounted = True
@@ -388,14 +386,23 @@ class DeviceMemoryManager:
 
     @staticmethod
     def _detect_budget(fraction: float) -> int:
-        try:
-            import jax
-            stats = jax.local_devices()[0].memory_stats()
-            if stats and stats.get("bytes_limit"):
-                return int(stats["bytes_limit"] * fraction)
-        except Exception:
-            pass
-        return int((4 << 30) * fraction)
+        """HBM budget = ``fraction`` of the SMALLEST local device's
+        ``bytes_limit`` (batches of a mesh query land on every device,
+        so the tightest one bounds them).  Only the CPU backend, which
+        reports no limit, gets the nominal 4 GiB; an accelerator that
+        cannot say how much memory it has is an error, not a default
+        that hides the device."""
+        import jax
+        if jax.default_backend() == "cpu":
+            return int((4 << 30) * fraction)
+        limits = [(d.memory_stats() or {}).get("bytes_limit")
+                  for d in jax.local_devices()]
+        if not all(limits):
+            raise LookupError(
+                f"backend {jax.default_backend()!r} reports no "
+                f"bytes_limit for its devices ({limits}); set "
+                "spark.rapids.tpu.memory.poolSize explicitly")
+        return int(min(limits) * fraction)
 
     # -- accounting ---------------------------------------------------------
     def reserve(self, nbytes: int, _restoring=None,

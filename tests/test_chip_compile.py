@@ -1,0 +1,107 @@
+"""Compiles for a DESCRIBED TPU v5e (no chip attached).
+
+The one file that holds such compiles: the TPU library belongs to one
+process, so the topology is described inside a module-scoped fixture
+(never at import) and every case compiles in this process.  Each case
+lowers a main-path kernel under x64 at a shape the SF1 run uses and
+hands it to the chip's own compiler — Mosaic included, which
+``interpret=True`` tests never meet.  Nothing runs: a compile that
+passes says nothing about results or times.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_tpu.runtime.device import ensure_initialized
+
+BATCH_ROWS = 1 << 20  # spark.rapids.tpu.batchRows default
+SMALL = 1 << 11  # keeps each sort compile to a few seconds here
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    ensure_initialized()  # x64 on, as every session has it
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry written for a described chip cannot be read back here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("limbs,n", [(1, 48), (2, SMALL), (3, BATCH_ROWS)])
+def test_hash_pairs_compiles(one_chip, limbs, n):
+    from spark_rapids_tpu.kernels import pallas_backend as PB
+    assert jax.config.jax_enable_x64
+    c = _compile(PB.hash_pairs, one_chip,
+                 ((limbs, n), jnp.uint32), ((limbs, n), jnp.uint32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("limbs", [1, 2, 3])
+def test_hash_limbs_pallas_compiles(one_chip, limbs):
+    from spark_rapids_tpu.kernels import hash_layout as HL
+    c = _compile(lambda *ls: HL.hash_limbs(list(ls), use_pallas=True),
+                 one_chip, *[((BATCH_ROWS,), jnp.uint64)] * limbs)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_match_fused_compiles(one_chip):
+    from spark_rapids_tpu.kernels import hash_join as KNJ
+
+    def fn(l0, r0, excl):
+        return KNJ.match_fused([l0], [r0], excl, use_pallas=True)
+
+    c = _compile(fn, one_chip, ((SMALL,), jnp.uint64),
+                 ((SMALL,), jnp.uint64), ((SMALL,), jnp.bool_))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_group_layout_fused_compiles(one_chip):
+    from spark_rapids_tpu.kernels import hash_agg as KNA
+
+    def fn(k0, k1):
+        return KNA.group_layout_fused([k0, k1], use_pallas=True)
+
+    c = _compile(fn, one_chip, ((SMALL,), jnp.uint64),
+                 ((SMALL,), jnp.uint64))
+    assert "tpu_custom_call" in c.as_text()
+
+
+# on a TPU `auto` resolves the sort kernel to the tiled ("fused") form
+# (kernels.resolve with supports_pallas=False); "jnp" is its t == 1 arm
+@pytest.mark.parametrize("backend", ["fused", "jnp"])
+def test_sort_perm_compiles(one_chip, backend):
+    from spark_rapids_tpu.kernels import segmented_sort as KNS
+
+    def fn(k0, k1):
+        return KNS.sort_perm([k0, k1], backend=backend)
+
+    _compile(fn, one_chip, ((SMALL,), jnp.uint64), ((SMALL,), jnp.uint64))
+
+
+def test_q6_step_compiles(one_chip):
+    import __graft_entry__ as G
+    fn, args = G.entry()
+    shapes = [((BATCH_ROWS,), np.asarray(a).dtype) for a in args]
+    _compile(fn, one_chip, *shapes)

