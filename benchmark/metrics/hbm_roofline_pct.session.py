@@ -1,0 +1,4 @@
+"""``hbm_roofline_pct`` where the end-to-end metric is ``query_s``
+(session.q1, session.q14)."""
+
+from readers import hbm_roofline_pct as read  # noqa: F401
