@@ -1,0 +1,4 @@
+"""``plan_ms`` where the end-to-end metric is ``scan_query_s``
+(session.q6)."""
+
+from book_readers import plan_ms as read  # noqa: F401

@@ -1,0 +1,4 @@
+"""``result_d2h_ms`` where the end-to-end metric is ``query_s``
+(session.q1, session.q14)."""
+
+from book_readers import result_d2h_ms as read  # noqa: F401

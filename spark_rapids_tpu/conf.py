@@ -776,7 +776,10 @@ MEMORY_DEBUG = (
 PROFILE_ENABLED = (
     conf("spark.rapids.profile.enabled")
     .doc("Capture a per-query device profile (jax/xplane trace, viewable "
-         "in TensorBoard/XProf) [REF: spark-rapids-jni profiler].")
+         "in TensorBoard/XProf) [REF: spark-rapids-jni profiler].  With "
+         "the tracer on (attribution, the default, or trace.enabled) the "
+         "query's spans are host events tpuq.<op>:<stage> in the same "
+         "xplane, on the device ops' clock.")
     .boolean()
     .create_with_default(False)
 )
@@ -792,19 +795,12 @@ TRACE_ENABLED = (
     conf("spark.rapids.sql.trace.enabled")
     .doc("Per-query span tracing (the NVTX-range analog): every exec's "
          "partition pump and internal stages (compile, transfer, compute, "
-         "collective) record spans, exported as Chrome-trace JSON "
-         "(chrome://tracing / Perfetto) plus a per-operator self-time vs "
-         "total-time rollup.")
+         "collective) record spans, reduced to a per-operator self-time "
+         "vs total-time rollup in the query log.  The spans themselves "
+         "are on the profiler's timeline wherever a jax profiler session "
+         "records (spark.rapids.profile.enabled).")
     .boolean()
     .create_with_default(False)
-)
-
-TRACE_PATH = (
-    conf("spark.rapids.sql.trace.path")
-    .doc("Directory for Chrome-trace exports "
-         "(query-<id>.trace.json per traced query).")
-    .string()
-    .create_with_default("/tmp/tpuq-trace")
 )
 
 QUERY_LOG_PATH = (

@@ -28,6 +28,8 @@ from spark_rapids_tpu.columnar.column import (
     DeviceBatch, DeviceColumn, host_to_device, round_up_pow2)
 from spark_rapids_tpu.exec.base import CpuExec, ExecNode, TpuExec
 from spark_rapids_tpu.ops.expressions import Expression
+from spark_rapids_tpu.runtime import telemetry as TM
+from spark_rapids_tpu.runtime import trace
 
 
 def _slice_table(table: pa.Table, num_partitions: int) -> List[pa.Table]:
@@ -81,6 +83,16 @@ import weakref
 # the analog of Spark's columnar cache).  Entries die with their table.
 _scan_cache: dict = {}
 _scan_cache_lock = threading.Lock()
+
+_TM_SCAN_HITS = TM.REGISTRY.counter(
+    "tpuq_scan_cache_hits_total",
+    "TpuScanExec partitions served from device-resident batches")
+_TM_SCAN_MISSES = TM.REGISTRY.counter(
+    "tpuq_scan_cache_misses_total",
+    "TpuScanExec partitions streamed from the arrow table (H2D)")
+_TM_H2D_BYTES = TM.REGISTRY.counter(
+    "tpuq_h2d_bytes_total",
+    "arrow bytes TpuScanExec copied to the device on a miss")
 
 
 def _scan_cache_get(table: pa.Table, key):
@@ -161,6 +173,7 @@ class TpuScanExec(TpuExec):
                partition)
         cached = _scan_cache_get(self.table, key)
         if cached is not None:
+            _TM_SCAN_HITS.inc()
             for bi, (sp, nrows) in enumerate(cached):
                 try:
                     # restores the batch if the arbiter spilled it
@@ -178,6 +191,7 @@ class TpuScanExec(TpuExec):
                 self.metric("numOutputBatches").add(1)
                 yield restored
             return
+        _TM_SCAN_MISSES.inc()
         yield from self._stream(partition, key, register=True)
 
     def _stream(self, partition: int, key=None, register: bool = False,
@@ -193,10 +207,11 @@ class TpuScanExec(TpuExec):
             chunk = part.slice(lo, self.batch_rows)
             if chunk.num_rows == 0 and lo > 0:
                 break
-            with self.timer():
+            with self.timer(), trace.span("TpuScanExec", "h2dTime"):
                 b = host_to_device(chunk, min_bucket=self.min_bucket)
                 b = DeviceBatch(self.schema, b.columns, b.sel,
                                 compacted=True)
+            _TM_H2D_BYTES.inc(chunk.nbytes)
             # row count is known host-side — never sync the device here
             # (a D2H per scanned batch would serialize the pump)
             nrows = chunk.num_rows

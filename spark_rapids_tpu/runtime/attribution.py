@@ -58,8 +58,18 @@ BUCKETS: Dict[str, str] = {
                       "(concurrentGpuTasks) or the pre-materialize hold",
     "compile": "XLA kernel / fused-region / exchange-program compiles "
                "detected on this query's clock",
-    "kernel_dispatch": "device compute, H2D/D2H transfer, gather/"
-                       "broadcast/concat and other device-batch work",
+    "plan": "logical optimization, physical planning and the device "
+            "overrides, before the first batch is pumped",
+    "kernel_launch": "host time inside cached-kernel calls: handing "
+                     "one compiled program to the runtime",
+    "scan_h2d": "a device scan filling the device: reading files and "
+                "the H2D copy of a scan-cache miss",
+    "result_d2h": "the result leaving the device: D2H at a device->"
+                  "host boundary (where a device-bound query's host "
+                  "waits) and the root's Arrow conversion and concat",
+    "kernel_dispatch": "exec code around the launches: operator "
+                       "bodies, mid-plan transfers, gather/broadcast/"
+                       "concat and other device-batch work",
     "exchange_collective": "ICI exchange collectives (the compiled "
                            "exchange's device launches)",
     "host_shuffle": "host-side shuffle partition/serialize/read/write",
@@ -82,6 +92,10 @@ BUCKET_VERDICTS: Dict[str, str] = {
     "queue_wait": "queue-bound",
     "semaphore_wait": "admission-bound",
     "compile": "compile-bound",
+    "plan": "plan-bound",
+    "kernel_launch": "launch-bound",
+    "scan_h2d": "scan-bound",
+    "result_d2h": "result-bound",
     "kernel_dispatch": "kernel-bound",
     "exchange_collective": "exchange-bound",
     "host_shuffle": "shuffle-bound",
@@ -100,9 +114,16 @@ BUCKET_VERDICTS: Dict[str, str] = {
 STAGE_BUCKETS: Dict[str, Optional[str]] = {
     "pump": "pump_idle",            # Cpu* ops -> host_fallback
     "pumpTask": "pump_idle",
+    "optimize": "plan",
+    "physicalPlan": "plan",
+    "overrides": "plan",
+    "kernelLaunch": "kernel_launch",
     "opTime": "kernel_dispatch",
-    "kernel": "kernel_dispatch",
-    "transferTime": "kernel_dispatch",
+    "transferTime": "kernel_dispatch",  # DeviceToHostExec -> result_d2h
+    "h2dTime": "scan_h2d",
+    "scanTime": "scan_h2d",         # Cpu* ops -> host_fallback
+    "resultD2H": "result_d2h",
+    "resultConcat": "result_d2h",
     "concatTime": "kernel_dispatch",
     "gatherTime": "kernel_dispatch",
     "broadcastTime": "kernel_dispatch",
@@ -116,7 +137,6 @@ STAGE_BUCKETS: Dict[str, Optional[str]] = {
     "writeTime": "host_shuffle",
     "readTime": "host_shuffle",
     "udfTime": "host_fallback",
-    "scanTime": "host_fallback",
     "spillTime": "spill_io",
     "restoreTime": "spill_io",
     "semaphoreWait": "semaphore_wait",
@@ -129,15 +149,20 @@ STAGE_BUCKETS: Dict[str, Optional[str]] = {
     # charging it would absorb every uninstrumented gap and make the
     # closure check vacuous
     "execute": None,
+    # the epilogue runs after the wall the ledger closes on: its span
+    # is timed for ``record_s`` (and mirrored), never charged
+    "record": None,
 }
 
 # Specificity order for overlap resolution, most specific first: an
 # instant covered by several threads' spans charges to the
 # highest-priority active bucket.  Waits and one-shot I/O stages beat
-# compute; compute beats the pump envelope.
+# compute; a launch call (the innermost span there is) beats the
+# stage it is made from; compute beats the pump envelope.
 BUCKET_PRIORITY: Tuple[str, ...] = (
     "compile", "preempted", "semaphore_wait", "spill_io",
     "exchange_collective", "host_shuffle", "cache", "host_fallback",
+    "kernel_launch", "scan_h2d", "result_d2h", "plan",
     "kernel_dispatch", "queue_wait", "pump_idle",
 )
 
@@ -158,8 +183,10 @@ _TM_DUMPS = TM.REGISTRY.labeled_counter(
 def span_bucket(op: str, stage: str) -> Optional[str]:
     """Bucket of one span; None = uncharged (unknown stage or the
     query-root envelope)."""
-    if stage == "pump" and op.startswith("Cpu"):
+    if op.startswith("Cpu") and stage in ("pump", "scanTime"):
         return "host_fallback"
+    if stage == "transferTime" and op == "DeviceToHostExec":
+        return "result_d2h"
     return STAGE_BUCKETS.get(stage)
 
 
@@ -220,9 +247,10 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     queue wait) — they extend e2e rather than competing for it.
 
     Returns ``{"buckets", "e2e_s", "unaccounted_s", "closed",
-    "tolerance", "verdict", "dominant", "dominant_share"}`` with
-    buckets rounded, exclusive, and summing (with ``unaccounted``) to
-    ``e2e_s`` exactly."""
+    "tolerance", "verdict", "dominant", "dominant_share", "launches"}``
+    with buckets rounded, exclusive, and summing (with ``unaccounted``)
+    to ``e2e_s`` exactly; ``launches`` counts the cached-kernel calls
+    (the ``Kernel.<label>`` spans)."""
     if tracer is not None:
         spans = list(tracer.events)
         t0 = tracer.t_start
@@ -242,7 +270,10 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     e2e = max(t1 - t0, 0.0)
     pri_index = {b: i for i, b in enumerate(BUCKET_PRIORITY)}
     intervals: List[Tuple[float, float, int]] = []
+    launches = 0
     for sp in spans:
+        if sp.op.startswith("Kernel."):
+            launches += 1
         b = span_bucket(sp.op, sp.stage)
         if b is None:
             continue
@@ -269,9 +300,39 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
         "tolerance": tol,
         "dominant": dominant,
         "dominant_share": round(share, 4),
+        "launches": launches,
     }
     att["verdict"] = verdict_line(att)
     return att
+
+
+# ---------------------------------------------------------------------------
+# The books, published: the last ledgers of the process, for readers
+# outside the query (the benchmark's per-layer metrics)
+# ---------------------------------------------------------------------------
+
+RECENT_MAX = 4096
+_RECENT: deque = deque(maxlen=RECENT_MAX)
+
+
+def publish(att: Dict[str, Any], tracer) -> Dict[str, Any]:
+    """Stamp a query's ledger with its id and its wall on
+    ``time.monotonic()`` (the clock a caller times requests on; the
+    tracer keeps ``perf_counter`` for durations) and put it into the
+    bounded ring ``recent()`` reads.  ``record_s`` — what the epilogue
+    after the wall cost the caller — is filled in by ``toArrow`` when
+    ``_record_query`` returns.  Returns ``att``, the ring's entry."""
+    att["query_id"] = tracer.query_id
+    att["t0_mono"] = tracer.t_start_mono
+    att["t1_mono"] = tracer.t_start_mono + (tracer.wall_s or 0.0)
+    att["record_s"] = None
+    _RECENT.append(att)
+    return att
+
+
+def recent() -> List[Dict[str, Any]]:
+    """The last ``RECENT_MAX`` published ledgers, oldest first."""
+    return list(_RECENT)
 
 
 def verdict_line(att: Dict[str, Any]) -> str:

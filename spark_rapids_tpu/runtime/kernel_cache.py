@@ -19,6 +19,7 @@ bounds the bucket axis so all three stay small.
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
@@ -45,6 +46,10 @@ _TM_COMPILES = TM.REGISTRY.counter(
 _TM_COMPILE_S = TM.REGISTRY.counter(
     "tpuq_kernel_compile_seconds_total",
     "seconds spent in dispatches that triggered an XLA compile")
+_TM_LAUNCHES = TM.REGISTRY.counter(
+    "tpuq_program_launches_total",
+    "calls of a cached kernel: every program the kernel cache "
+    "launched, tracer or not")
 TM.REGISTRY.gauge(
     "tpuq_kernel_cache_size", "live cached kernel wrappers",
     fn=lambda: len(_CACHE))
@@ -66,8 +71,13 @@ def fingerprint(v) -> object:
     return repr(v)
 
 
-def _jit_once(fn: Callable) -> Callable:
-    """jit ``fn`` unless the builder already did.
+def _jit_once(fn: Callable, label: str) -> Callable:
+    """jit ``fn`` under the name ``tpuq_<label>``, its body under
+    ``jax.named_scope(label)``, unless the builder already jitted it:
+    the host's ``PjitFunction(tpuq_<label>)`` event, the device's
+    ``jit_tpuq_<label>`` module and each op's metadata then say which
+    kernel they are.  The name is set once, here, so it costs a call
+    nothing and a warm window compiles nothing.
 
     SPMD exchange programs come out of their builders pre-jitted with
     ``donate_argnums`` — re-wrapping them would trace THROUGH the inner
@@ -75,7 +85,15 @@ def _jit_once(fn: Callable) -> Callable:
     donation set, empty, is the one that counts).  ``_cache_size`` is
     the jit-wrapper attribute the compile detector below already keys
     on, so its presence is the reliable already-jitted signal."""
-    return fn if hasattr(fn, "_cache_size") else jax.jit(fn)
+    if hasattr(fn, "_cache_size"):
+        return fn
+
+    def named(*args, **kw):
+        with jax.named_scope(label):
+            return fn(*args, **kw)
+
+    named.__name__ = named.__qualname__ = f"tpuq_{label}"
+    return jax.jit(named)
 
 
 def _build_wrapper(key: tuple, builder: Callable[[], Callable]):
@@ -85,23 +103,25 @@ def _build_wrapper(key: tuple, builder: Callable[[], Callable]):
     boundary every XLA compile passes).  Degradation returns the raw
     un-jitted builder output — eager per-op dispatch instead of one
     compiled executable."""
+    label = _op_label(key)
     if not R.active():
-        return _jit_once(builder())
+        return _jit_once(builder(), label)
 
     def attempt():
         R.INJECTOR.on("compile")
-        return _jit_once(builder())
+        return _jit_once(builder(), label)
 
     def degrade():
         return builder()
 
-    return R.run_guarded("compile", attempt, op=_op_label(key),
-                         degrade=degrade)
+    return R.run_guarded("compile", attempt, op=label, degrade=degrade)
 
 
 def _op_label(key: tuple) -> str:
+    """The key's head (``agg_reduce``, ``join_mat``, ``concat_norm`` …)
+    as an identifier: the kernel's name in spans, jit names and scopes."""
     head = key[0] if key else "kernel"
-    return head if isinstance(head, str) else repr(head)
+    return re.sub(r"\W", "_", head if isinstance(head, str) else repr(head))
 
 
 def cached_kernel(key: tuple, builder: Callable[[], Callable]) -> Callable:
@@ -121,8 +141,10 @@ def cached_kernel(key: tuple, builder: Callable[[], Callable]) -> Callable:
             return fn
         _TM_MISSES.inc()
         jfn = _build_wrapper(key, builder)
+        label = _op_label(key)
+        span_op = "Kernel." + label
 
-        def _call(args, kw, __jfn=jfn, __key=key, __builder=builder):
+        def _call(args, kw, __jfn=jfn, __builder=builder):
             if not R.active():
                 return __jfn(*args, **kw)
 
@@ -133,10 +155,11 @@ def cached_kernel(key: tuple, builder: Callable[[], Callable]) -> Callable:
             def degrade():
                 return __builder()(*args, **kw)
 
-            return R.run_guarded("execute", attempt,
-                                 op=_op_label(__key), degrade=degrade)
+            return R.run_guarded("execute", attempt, op=label,
+                                 degrade=degrade)
 
         def fn(*args, __jfn=jfn, **kw):
+            _TM_LAUNCHES.inc()
             tr = trace.current()
             # jax.jit compiles lazily at first call per shape bucket;
             # the cache-size delta distinguishes an XLA compile from a
@@ -147,7 +170,8 @@ def cached_kernel(key: tuple, builder: Callable[[], Callable]) -> Callable:
             if tr is None and before is None:
                 return _call(args, kw)
             t0 = time.perf_counter()
-            sp = tr.begin("Kernel", "kernel") if tr is not None else None
+            sp = (tr.begin(span_op, "kernelLaunch")
+                  if tr is not None else None)
             try:
                 return _call(args, kw)
             finally:

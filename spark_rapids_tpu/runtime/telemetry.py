@@ -371,7 +371,7 @@ def ensure_producers() -> None:
                 "runtime.lockdep", "runtime.shapes", "adaptive",
                 "shuffle.manager", "shuffle.exchange",
                 "parallel.executor", "parallel.shuffle",
-                "parallel.rendezvous", "exec.distributed",
+                "parallel.rendezvous", "exec.basic", "exec.distributed",
                 "kernels", "cache", "fusion"):
         try:
             importlib.import_module(f"spark_rapids_tpu.{mod}")

@@ -7,8 +7,15 @@ gather, shuffle/collective) open spans on a per-query ``Tracer``.  Spans
 nest per thread (the executor pool's task threads each keep their own
 stack), accumulate their children's time so self-time vs total-time per
 operator is finally attributable — the fix for ``opTime``
-double-counting across parent/child iterators — and export as
-Chrome-trace JSON (loadable in ``chrome://tracing`` / Perfetto).
+double-counting across parent/child iterators.
+
+Durations stay on ``time.perf_counter()``.  Where a jax profiler
+session is recording when the query starts (the benchmark's
+``jax.profiler.start_trace``, ``spark.rapids.profile.enabled``), every
+span is also a ``jax.profiler.TraceAnnotation`` named
+``tpuq.<op>:<stage>`` with the ``query_id`` (and ``partition``) as
+stats: a host event on the clock the device plane uses, same thread,
+same nesting — one timeline, no exporter of its own.
 
 The event log is the reference's driver-log "plan conversion report"
 made machine-readable: one JSONL entry per query
@@ -27,6 +34,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------------------
 # Spans
 # ---------------------------------------------------------------------------
@@ -38,7 +47,7 @@ class Span:
     ``self_time = dur - child_time`` is this span's exclusive time."""
 
     __slots__ = ("op", "stage", "tid", "t0", "t1", "child_time",
-                 "parent_op", "args")
+                 "parent_op", "args", "ann")
 
     def __init__(self, op: str, stage: str, tid: int, t0: float,
                  parent_op: Optional[str], args: Optional[dict]):
@@ -50,6 +59,8 @@ class Span:
         self.child_time = 0.0
         self.parent_op = parent_op
         self.args = args
+        # the span's mirror on the profiler's clock (mirroring tracers)
+        self.ann = None
 
     @property
     def dur(self) -> float:
@@ -58,6 +69,13 @@ class Span:
     @property
     def self_time(self) -> float:
         return max(self.dur - self.child_time, 0.0)
+
+
+def _close_mirror(span: Span) -> None:
+    ann = span.ann
+    if ann is not None:
+        span.ann = None
+        ann.__exit__(None, None, None)
 
 
 class _SpanCtx:
@@ -98,10 +116,15 @@ class Tracer:
     self-time.  Spans on a pool thread with no enclosing span start a
     fresh top-level track for that thread."""
 
-    def __init__(self, query_id: int, max_events: int = 100_000):
+    def __init__(self, query_id: int, max_events: int = 100_000,
+                 mirror: bool = False):
         self.query_id = query_id
         self.max_events = max_events
+        # spans are also TraceAnnotations (a profiler session records)
+        self.mirror = mirror
         self.t_start = time.perf_counter()
+        # the same instant on the clock callers stamp requests with
+        self.t_start_mono = time.monotonic()
         self.wall_s: Optional[float] = None
         self.dropped = 0
         self.events: List[Span] = []
@@ -125,6 +148,12 @@ class Tracer:
         parent_op = st[-1].op if st else None
         sp = Span(op, stage, threading.get_ident(), time.perf_counter(),
                   parent_op, args)
+        if self.mirror:
+            stats = {"query_id": self.query_id}
+            if args and "partition" in args:
+                stats["partition"] = args["partition"]
+            sp.ann = TraceAnnotation(f"tpuq.{op}:{stage}", **stats)
+            sp.ann.__enter__()
         st.append(sp)
         return sp
 
@@ -134,9 +163,10 @@ class Tracer:
         # pop back to (and including) this span — tolerate a leaked
         # child that never closed (generator dropped mid-pump)
         while st and st[-1] is not span:
-            st.pop()
+            _close_mirror(st.pop())
         if st:
             st.pop()
+        _close_mirror(span)
         if st:
             st[-1].child_time += span.dur
         with self._lock:
@@ -156,43 +186,6 @@ class Tracer:
         self.wall_s = time.perf_counter() - self.t_start
 
     # -- export -------------------------------------------------------------
-    def to_chrome_trace(self) -> Dict[str, Any]:
-        """The ``chrome://tracing`` / Perfetto JSON object format:
-        complete ('X') events with microsecond timestamps relative to
-        query start, one track per pump thread."""
-        tids: Dict[int, int] = {}
-        events: List[dict] = []
-        with self._lock:
-            spans = list(self.events)
-        for sp in spans:
-            tid = tids.setdefault(sp.tid, len(tids) + 1)
-            ev = {
-                "name": f"{sp.op}:{sp.stage}",
-                "cat": sp.stage,
-                "ph": "X",
-                "ts": round((sp.t0 - self.t_start) * 1e6, 3),
-                "dur": round(sp.dur * 1e6, 3),
-                "pid": 1,
-                "tid": tid,
-            }
-            if sp.args:
-                ev["args"] = sp.args
-            events.append(ev)
-        for ident, tid in tids.items():
-            events.append({
-                "name": "thread_name", "ph": "M", "pid": 1, "tid": tid,
-                "args": {"name": f"pump-{tid}"
-                         if tid > 1 else "query-main"},
-            })
-        return {
-            "traceEvents": events,
-            "displayTimeUnit": "ms",
-            "otherData": {
-                "query_id": self.query_id,
-                "dropped_spans": self.dropped,
-            },
-        }
-
     def rollup(self) -> Dict[str, Dict[str, Any]]:
         """Per-operator total vs self time derived from the span tree.
 
@@ -245,12 +238,15 @@ def current() -> Optional[Tracer]:
 def start_query(query_id: int, max_events: int = 100_000
                 ) -> Optional[Tracer]:
     """Install a fresh tracer; returns None when another query already
-    owns tracing (the caller is a nested execution)."""
+    owns tracing (the caller is a nested execution).  Asks the profiler
+    once, here, whether a session is recording: the tracer then mirrors
+    its spans for the whole query, and otherwise never makes one."""
     global _ACTIVE
     with _ACTIVE_LOCK:
         if _ACTIVE is not None:
             return None
-        _ACTIVE = Tracer(query_id, max_events=max_events)
+        _ACTIVE = Tracer(query_id, max_events=max_events,
+                         mirror=bool(TraceAnnotation.is_enabled()))
         return _ACTIVE
 
 
@@ -317,20 +313,3 @@ def append_query_log(path: str, entry: Dict[str, Any]) -> None:
     except OSError as e:
         print(f"[tpuq] query log write failed: {e}", file=sys.stderr,
               flush=True)
-
-
-def write_chrome_trace(dir_path: str, tracer: Tracer) -> Optional[str]:
-    """``<dir>/query-<id>.trace.json``; returns the path (None on
-    failure)."""
-    import sys
-    try:
-        os.makedirs(dir_path, exist_ok=True)
-        out = os.path.join(dir_path,
-                           f"query-{tracer.query_id:06d}.trace.json")
-        with open(out, "w") as f:
-            json.dump(tracer.to_chrome_trace(), f)
-        return out
-    except OSError as e:
-        print(f"[tpuq] chrome trace write failed: {e}", file=sys.stderr,
-              flush=True)
-        return None

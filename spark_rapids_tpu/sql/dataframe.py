@@ -764,9 +764,14 @@ class DataFrame:
     # -- actions ------------------------------------------------------------
     def _execute_plan(self):
         from spark_rapids_tpu.plan.optimizer import optimize
+        from spark_rapids_tpu.runtime import trace
         conf = self.session.rapids_conf()
-        cpu = plan_physical(optimize(self._plan, conf), conf)
-        result = apply_overrides(cpu, conf)
+        with trace.span("Plan", "optimize"):
+            logical = optimize(self._plan, conf)
+        with trace.span("Plan", "physicalPlan"):
+            cpu = plan_physical(logical, conf)
+        with trace.span("Plan", "overrides"):
+            result = apply_overrides(cpu, conf)
         self._last_override = result
         return result.plan
 
@@ -815,29 +820,24 @@ class DataFrame:
         from spark_rapids_tpu.runtime import telemetry
         from spark_rapids_tpu.runtime import trace
         conf = self.session.rapids_conf()
-        plan = self._execute_plan()
-        self._last_plan = plan
-        cache_store = ckey = None
-        if conf.get(C.CACHE_ENABLED):
-            from spark_rapids_tpu import cache as cache_mod
-            cache_store = cache_mod.get_cache(conf)
-            try:
-                ckey = cache_mod.result_key(self._plan, plan, conf,
-                                            tenant=tenant)
-            except Exception:
-                # unkeyable inputs (e.g. a vanished scan file) —
-                # execute uncached
-                cache_store = None
         qid = query_id if query_id is not None else trace.next_query_id()
-        qwin = telemetry.begin_query(qid)
-        from spark_rapids_tpu.runtime import resilience
-        rwin = resilience.begin_query(qid)
-        cwin = cancel_mod.begin_query(qid, conf, timeout_ms=timeout_ms,
-                                      token=cancel_token)
+        stack = contextlib.ExitStack()
+        profile_dir = None
+        if conf.get(C.PROFILE_ENABLED):
+            # per-query xplane capture, dump dir named after the query id
+            # so trace + event-log entries cross-link
+            # [REF: spark-rapids-jni profiler].  Entered before the
+            # tracer starts: the tracer then mirrors its spans into it
+            import jax
+            import os
+            profile_dir = os.path.join(str(conf.get(C.PROFILE_PATH)),
+                                       f"query-{qid:06d}")
+            os.makedirs(profile_dir, exist_ok=True)
+            stack.enter_context(jax.profiler.trace(profile_dir))
         # the attribution plane rides the tracer: when attribution is on
         # (the default) the tracer runs even with trace.enabled off, but
-        # _record_query only emits the rollup/chrome-trace artifacts the
-        # user asked for — the spans feed the ledger + flight recorder
+        # _record_query only emits the rollup the user asked for — the
+        # spans feed the ledger + flight recorder
         from spark_rapids_tpu.runtime import attribution as attr_mod
         attr_on = bool(conf.get(C.ATTRIBUTION_ENABLED))
         tracer = None
@@ -850,98 +850,109 @@ class DataFrame:
                 qid, ring_size=int(conf.get(C.ATTRIBUTION_RING_SIZE)))
             if tracer is not None and arec is not None:
                 tracer.recorder = arec
-        collector = None
-        if conf.get(C.STATS_ENABLED):
-            collector = stats_mod.start_query(
-                qid, level=str(conf.get(C.STATS_LEVEL)),
-                skew_threshold=float(conf.get(C.STATS_SKEW_THRESHOLD)))
-        profile = contextlib.nullcontext()
-        profile_dir = None
-        if conf.get(C.PROFILE_ENABLED):
-            # per-query xplane capture, dump dir named after the query id
-            # so trace + event-log entries cross-link
-            # [REF: spark-rapids-jni profiler]
-            import jax
-            import os
-            profile_dir = os.path.join(str(conf.get(C.PROFILE_PATH)),
-                                       f"query-{qid:06d}")
-            os.makedirs(profile_dir, exist_ok=True)
-            profile = jax.profiler.trace(profile_dir)
-        root = (tracer.span("Query", "execute")
-                if tracer is not None else contextlib.nullcontext())
+        # the root opens before planning: the wall the books close on
+        # is the caller's, plan included
+        root = (tracer.begin("Query", "execute")
+                if tracer is not None else None)
+        plan = None
+        cache_store = ckey = None
+        qwin = rwin = cwin = collector = None
+        scoped = False
         error = None
         cancelled = None
         cache_info = None
         flight = None
         try:
-            with profile, root:
-                served = None
-                if cache_store is not None:
-                    with trace.span("ResultCache", "cacheProbe"):
-                        served = cache_store.lookup(ckey.key)
-                        if served is None:
-                            role, fl = cache_store.join_flight(ckey.key)
-                            if role == "leader":
-                                flight = fl
-                                fl.leader_qid = qid
-                            else:
-                                # another execution of this exact key is
-                                # in progress — wait for it, then
-                                # re-probe; compute ourselves if it
-                                # failed or skipped
-                                tok = cancel_mod.current()
-                                while not fl.done.wait(0.05):
-                                    cancel_mod.check()
-                                    if tok is not None:
-                                        tok.preempt_point()
-                                    lq = fl.leader_qid
-                                    lt = (cancel_mod.get_token(lq)
-                                          if lq is not None else None)
-                                    if (lt is not None
-                                            and lt.preempt_pending()):
-                                        # the leader was preempted
-                                        # mid-flight; followers waiting
-                                        # on it while holding run slots
-                                        # would starve the scheduler of
-                                        # the very slot the leader needs
-                                        # to resume — break away and
-                                        # compute independently
-                                        break
-                                served = cache_store.lookup(ckey.key)
-                                if served is not None:
-                                    cache_info = {"coalesced": True}
-                if served is not None:
-                    out = served.value
-                    cache_info = {
-                        "status": "hit", "key": served.key,
-                        "signature": served.sig,
-                        "bytes": served.nbytes,
-                        "saved_s": round(served.runtime_s, 6),
-                        "age_s": round(
-                            _time.monotonic() - served.created, 6),
-                        **(cache_info or {})}
-                else:
-                    t_exec = _time.perf_counter()
-                    tables = self._pump_partitions(plan, conf)
-                    with trace.span("Result", "concatTime"):
-                        if not tables:
-                            out = self._reassemble_structs(pa.table(
-                                {f.name: pa.array(
-                                    [], type=T.to_arrow(f.dtype))
-                                 for f in self.schema.fields}))
+            plan = self._execute_plan()
+            self._last_plan = plan
+            if conf.get(C.CACHE_ENABLED):
+                from spark_rapids_tpu import cache as cache_mod
+                cache_store = cache_mod.get_cache(conf)
+                try:
+                    ckey = cache_mod.result_key(self._plan, plan, conf,
+                                                tenant=tenant)
+                except Exception:
+                    # unkeyable inputs (e.g. a vanished scan file) —
+                    # execute uncached
+                    cache_store = None
+            qwin = telemetry.begin_query(qid)
+            from spark_rapids_tpu.runtime import resilience
+            rwin = resilience.begin_query(qid)
+            cwin = cancel_mod.begin_query(qid, conf, timeout_ms=timeout_ms,
+                                          token=cancel_token)
+            scoped = True
+            if conf.get(C.STATS_ENABLED):
+                collector = stats_mod.start_query(
+                    qid, level=str(conf.get(C.STATS_LEVEL)),
+                    skew_threshold=float(
+                        conf.get(C.STATS_SKEW_THRESHOLD)))
+            served = None
+            if cache_store is not None:
+                with trace.span("ResultCache", "cacheProbe"):
+                    served = cache_store.lookup(ckey.key)
+                    if served is None:
+                        role, fl = cache_store.join_flight(ckey.key)
+                        if role == "leader":
+                            flight = fl
+                            fl.leader_qid = qid
                         else:
-                            out = self._reassemble_structs(
-                                pa.concat_tables(tables))
-                    if cache_store is not None:
-                        runtime_s = _time.perf_counter() - t_exec
-                        cache_store.note_miss()
-                        with trace.span("ResultCache", "cacheServe"):
-                            stored = cache_store.put(
-                                ckey, out, out.nbytes, runtime_s)
-                        cache_info = {
-                            "key": ckey.key, "signature": ckey.sig,
-                            "bytes": out.nbytes,
-                            "runtime_s": round(runtime_s, 6), **stored}
+                            # another execution of this exact key is
+                            # in progress — wait for it, then
+                            # re-probe; compute ourselves if it
+                            # failed or skipped
+                            tok = cancel_mod.current()
+                            while not fl.done.wait(0.05):
+                                cancel_mod.check()
+                                if tok is not None:
+                                    tok.preempt_point()
+                                lq = fl.leader_qid
+                                lt = (cancel_mod.get_token(lq)
+                                      if lq is not None else None)
+                                if (lt is not None
+                                        and lt.preempt_pending()):
+                                    # the leader was preempted
+                                    # mid-flight; followers waiting
+                                    # on it while holding run slots
+                                    # would starve the scheduler of
+                                    # the very slot the leader needs
+                                    # to resume — break away and
+                                    # compute independently
+                                    break
+                            served = cache_store.lookup(ckey.key)
+                            if served is not None:
+                                cache_info = {"coalesced": True}
+            if served is not None:
+                out = served.value
+                cache_info = {
+                    "status": "hit", "key": served.key,
+                    "signature": served.sig,
+                    "bytes": served.nbytes,
+                    "saved_s": round(served.runtime_s, 6),
+                    "age_s": round(
+                        _time.monotonic() - served.created, 6),
+                    **(cache_info or {})}
+            else:
+                t_exec = _time.perf_counter()
+                tables = self._pump_partitions(plan, conf)
+                with trace.span("Result", "resultConcat"):
+                    if not tables:
+                        out = self._reassemble_structs(pa.table(
+                            {f.name: pa.array(
+                                [], type=T.to_arrow(f.dtype))
+                             for f in self.schema.fields}))
+                    else:
+                        out = self._reassemble_structs(
+                            pa.concat_tables(tables))
+                if cache_store is not None:
+                    runtime_s = _time.perf_counter() - t_exec
+                    cache_store.note_miss()
+                    with trace.span("ResultCache", "cacheServe"):
+                        stored = cache_store.put(
+                            ckey, out, out.nbytes, runtime_s)
+                    cache_info = {
+                        "key": ckey.key, "signature": ckey.sig,
+                        "bytes": out.nbytes,
+                        "runtime_s": round(runtime_s, 6), **stored}
         except cancel_mod.QueryCancelled as e:
             cancelled = e
             error = f"{type(e).__name__}: {e}"
@@ -958,18 +969,35 @@ class DataFrame:
             error = f"{type(e).__name__}: {e}"
             raise
         finally:
-            if flight is not None:
-                # wake single-flight followers even on failure — they
-                # re-probe and compute for themselves
-                cache_store.finish_flight(ckey.key, flight)
-            trace.end_query(tracer)
-            stats_mod.end_query(collector)
-            attr_mod.end_query(arec)
-            cancel_mod.finish_query(cwin)
-            self._record_query(qid, tracer, conf, profile_dir, error,
-                               qwin, rwin, cancelled=cancelled,
-                               ctoken=cwin, collector=collector,
-                               cache_info=cache_info, recorder=arec)
+            try:
+                if flight is not None:
+                    # wake single-flight followers even on failure —
+                    # they re-probe and compute for themselves
+                    cache_store.finish_flight(ckey.key, flight)
+                if root is not None:
+                    tracer.end(root)
+                trace.end_query(tracer)
+                stats_mod.end_query(collector)
+                attr_mod.end_query(arec)
+                if scoped:
+                    cancel_mod.finish_query(cwin)
+                    # the epilogue runs on the caller's clock after the
+                    # answer exists: timed (and mirrored) as its own
+                    # span, outside the wall the ledger closes on
+                    rec = (tracer.begin("Query", "record")
+                           if tracer is not None else None)
+                    book = self._record_query(
+                        qid, tracer, conf, profile_dir, error, qwin,
+                        rwin, cancelled=cancelled, ctoken=cwin,
+                        collector=collector, cache_info=cache_info,
+                        recorder=arec)
+                    if rec is not None:
+                        tracer.end(rec)
+                        if book is not None:
+                            book["record_s"] = round(rec.dur, 6)
+                # else: planning failed — nothing ran, nothing to record
+            finally:
+                stack.close()
         return out
 
     def _record_query(self, qid, tracer, conf, profile_dir, error,
@@ -978,7 +1006,8 @@ class DataFrame:
         """One event-log entry per execution: plan tree, device/fallback
         report, all metrics at their levels, span rollup, artifact
         cross-links — the reference's driver-log plan-conversion report,
-        machine-readable."""
+        machine-readable.  Returns the ledger as published to
+        ``attribution.recent()`` (None when attribution is off)."""
         import time as _time
         from spark_rapids_tpu import conf as C
         from spark_rapids_tpu.runtime import trace
@@ -1010,24 +1039,20 @@ class DataFrame:
             entry["fallback"] = override.fallback_summary()
             entry["fallback_report"] = override.fallback_report()
         if tracer is not None and conf.get(C.TRACE_ENABLED):
-            # tracing artifacts only when the user asked for tracing —
-            # an attribution-only tracer feeds the ledger below but
-            # must not start emitting rollups/chrome traces
+            # the rollup only when the user asked for tracing — an
+            # attribution-only tracer feeds the ledger below but must
+            # not start emitting rollups
             entry["wall_s"] = round(tracer.wall_s, 6)
             rollup = tracer.rollup()
             entry["op_rollup"] = rollup
             entry["dropped_spans"] = tracer.dropped
             self._last_rollup = rollup
-            tf = trace.write_chrome_trace(
-                str(conf.get(C.TRACE_PATH)), tracer)
-            if tf:
-                entry["trace_file"] = tf
         attribution = None
         if tracer is not None and conf.get(C.ATTRIBUTION_ENABLED):
             from spark_rapids_tpu.runtime import attribution as attr_mod
-            attribution = attr_mod.attribute(
+            attribution = attr_mod.publish(attr_mod.attribute(
                 tracer, tolerance=float(
-                    conf.get(C.ATTRIBUTION_CLOSE_TOLERANCE)))
+                    conf.get(C.ATTRIBUTION_CLOSE_TOLERANCE))), tracer)
             entry["attribution"] = attribution
             attr_mod.note_unaccounted(attribution["unaccounted_s"])
         if profile_dir:
@@ -1126,6 +1151,7 @@ class DataFrame:
         log_path = str(conf.get(C.QUERY_LOG_PATH))
         if log_path:
             trace.append_query_log(log_path, entry)
+        return attribution
 
     def _reassemble_structs(self, t: pa.Table) -> pa.Table:
         """Physical flattened columns → logical arrow struct columns
@@ -1188,13 +1214,17 @@ class DataFrame:
         from spark_rapids_tpu.runtime import trace as trace_mod
 
         def pump(p: int) -> List[pa.Table]:
-            # per-partition envelope span: charges iterator plumbing +
-            # the root arrow conversion (time between instrumented
-            # stages) to the pump_idle bucket — a no-op when neither
-            # tracing nor attribution is active
-            with trace_mod.span("PumpTask", "pumpTask",
-                                {"partition": p}):
-                return [H.to_arrow_table(b) for b in plan.execute(p)]
+            # per-partition envelope span: charges iterator plumbing
+            # (time between instrumented stages) to the pump_idle
+            # bucket, the root's arrow conversion to result_d2h — a
+            # no-op when neither tracing nor attribution is active
+            args = {"partition": p}
+            with trace_mod.span("PumpTask", "pumpTask", args):
+                out = []
+                for b in plan.execute(p):
+                    with trace_mod.span("Result", "resultD2H", args):
+                        out.append(H.to_arrow_table(b))
+                return out
 
         if not on_device:
             out = []

@@ -174,7 +174,6 @@ def test_attribution_closes_q3_shaped(tmp_path):
 
 def test_trace_enabled_keeps_rollup_and_attribution(tmp_path):
     s = tpu_session({"spark.rapids.sql.trace.enabled": True,
-                     "spark.rapids.sql.trace.path": str(tmp_path),
                      "spark.rapids.tpu.attribution.blackboxPath":
                      str(tmp_path)})
     # same shape as the q3-shaped test above: warm kernel cache

@@ -196,33 +196,6 @@ def test_same_op_nested_spans_do_not_double_count():
     assert abs(roll["A"]["total_s"] - round(outer.dur, 6)) < 1e-5
 
 
-def test_chrome_trace_export_well_formed(tmp_path):
-    import json
-    s = tpu_session({"spark.rapids.sql.trace.enabled": True,
-                     "spark.rapids.sql.trace.path": str(tmp_path)})
-    df = s.createDataFrame(_t(1000)).filter(F.col("v") > 0).groupBy(
-        "k").agg(F.sum("v").alias("sv"))
-    df.toArrow()
-    entry = s.query_history()[-1]
-    path = entry["trace_file"]
-    assert path.startswith(str(tmp_path))
-    with open(path) as f:
-        doc = json.load(f)
-    evs = doc["traceEvents"]
-    assert evs
-    x = [e for e in evs if e["ph"] == "X"]
-    m = [e for e in evs if e["ph"] == "M"]
-    assert x and m
-    for e in x:
-        assert isinstance(e["ts"], (int, float)) and e["ts"] >= 0
-        assert isinstance(e["dur"], (int, float)) and e["dur"] >= 0
-        assert ":" in e["name"] and e["pid"] == 1
-    # pump spans for the device execs present
-    names = {e["name"] for e in x}
-    assert any(n.endswith(":pump") for n in names), names
-    assert "Query:execute" in names
-
-
 def test_query_log_round_trip(tmp_path):
     """Query runs → JSONL entry parses; fallback report matches the
     frame's own summary; metrics match collect_metrics; rollup
@@ -230,7 +203,6 @@ def test_query_log_round_trip(tmp_path):
     import json
     log = str(tmp_path / "qlog.jsonl")
     s = tpu_session({"spark.rapids.sql.trace.enabled": True,
-                     "spark.rapids.sql.trace.path": str(tmp_path),
                      "spark.rapids.sql.queryLog.path": log})
     df = s.createDataFrame(_t(2000)).groupBy("k").agg(
         F.sum("v").alias("sv"))
@@ -305,7 +277,6 @@ def test_tracer_event_cap_counts_dropped():
             pass
     assert len(tr.events) == 5
     assert tr.dropped == 4
-    assert tr.to_chrome_trace()["otherData"]["dropped_spans"] == 4
 
 
 def test_all_metric_names_documented():
