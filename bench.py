@@ -980,7 +980,7 @@ def kernel_bench(mark) -> dict:
             return checksum(boundary) + checksum(perm)
 
         def agg_fused(bias, k1, k2, *_):
-            perm, _, boundary, ok = KNA.group_layout_fused(
+            perm, _, boundary, ok, _ = KNA.group_layout_fused(
                 [k1 + bias, k2])
             return (checksum(boundary) + checksum(perm)
                     + ok.astype(jnp.uint64))

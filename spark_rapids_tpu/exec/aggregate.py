@@ -123,7 +123,9 @@ def segment_groupby(
     compacted to the front, capacity unchanged (static shape).
     Scatter-free: one stable sort, segmented scans, and a second sort
     that compacts each group's END row (which holds the full-segment
-    scan result) to the front in group order.
+    scan result) to the front in group order.  Rows move once a
+    permutation, all columns together (``ORD.sort_rows``), never one
+    ``jnp.take`` a column.
 
     ``backend`` selects the group-layout kernel: the non-jnp rungs
     (kernels.hash_agg) sort ONE 64-bit hash limb instead of the full
@@ -135,42 +137,32 @@ def segment_groupby(
     """
     b = int(sel.shape[0])
     limbs, key_limbs = ORD.group_sort_limbs(list(key_cols), sel)
+    # everything that must follow the rows into group order
+    payload = [sel]
+    for c in key_cols:
+        payload += [c.data, c.validity, c.lengths]
+    for c, _ in value_cols:
+        payload += [c.data, c.validity]
     okf = None
     res = None
     if backend != "jnp":
         from spark_rapids_tpu.kernels import hash_agg as KNA
         res = KNA.group_layout_fused(
-            key_limbs, use_pallas=(backend == "pallas"))
+            key_limbs, use_pallas=(backend == "pallas"), payload=payload)
     if res is not None:
-        perm, sorted_limbs, boundary, okf = res
-        live_sorted = jnp.take(sel, perm)
+        _, sorted_limbs, boundary, okf, moved = res
     else:
-        sorted_limbs, perm = ORD.sort_by_keys(limbs)
-        live_sorted = jnp.take(sel, perm)
+        sorted_limbs, _, moved = ORD.sort_rows(limbs, payload)
         diff = jnp.zeros((b,), jnp.bool_)
         for l in sorted_limbs:
             diff = diff | ORD.limb_neq(
                 l, jnp.concatenate([l[:1], l[:-1]]))
         boundary = diff.at[0].set(True)  # row 0 always starts a group
+    moved = iter(moved)
+    live_sorted = next(moved)
+    keys_s = [(next(moved), next(moved), next(moved)) for _ in key_cols]
+    vals_s = [(next(moved), next(moved)) for _ in value_cols]
     num_groups = jnp.sum((boundary & live_sorted).astype(jnp.int32))
-
-    # group END rows hold the completed segment reductions
-    is_end = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
-    # compaction: ends of live groups to the front, in group order
-    rank = (~(is_end & live_sorted)).astype(jnp.uint8)
-    _, perm2 = ORD.sort_by_keys([rank])
-
-    def to_front(x_sorted):
-        return jnp.take(x_sorted, perm2, axis=0)
-
-    out_keys = []
-    for c in key_cols:
-        data_s = to_front(jnp.take(c.data, perm, axis=0))
-        validity = (to_front(jnp.take(c.validity, perm))
-                    if c.validity is not None else None)
-        lengths = (to_front(jnp.take(c.lengths, perm))
-                   if c.lengths is not None else None)
-        out_keys.append(DeviceColumn(c.dtype, data_s, validity, lengths))
 
     # Two-phase value reduction: per-column segmented scans are ENQUEUED
     # first so requests over the same logical input run ONCE (a q1-shaped
@@ -180,13 +172,17 @@ def segment_groupby(
     batcher = _ScanBatcher(boundary)
     all_valid = jnp.ones((b,), jnp.bool_)
     plans = []
-    for ci, (c, kind) in enumerate(value_cols):
-        data_s = jnp.take(c.data, perm, axis=0)
-        if c.validity is None:
+    shared = {}  # a column asked for twice (sum(x), avg(x)) is one plan
+    for ci, ((c, kind), (data_s, valid_s)) in enumerate(
+            zip(value_cols, vals_s)):
+        pkey = (id(data_s), id(valid_s), kind)
+        if pkey in shared:
+            plans.append(shared[pkey])
+            continue
+        if valid_s is None:
             valid_s, contrib = all_valid, live_sorted
             ckey = "live"  # shared count scan for all non-null inputs
         else:
-            valid_s = jnp.take(c.validity, perm)
             contrib = valid_s & live_sorted
             ckey = ("col", ci)
         e = {"c": c, "kind": kind, "data_s": data_s, "valid_s": valid_s}
@@ -242,11 +238,18 @@ def segment_groupby(
                                      key="nlive")
         else:
             raise ValueError(f"unknown reduction kind {kind}")
+        shared[pkey] = e
         plans.append(e)
     batcher.run()
 
-    out_vals = []
+    # group END rows hold the completed segment reductions.  Compaction:
+    # a second sort brings the ends of live groups to the front, in
+    # group order, and carries every output column with it.
+    outputs = [a for arrs in keys_s for a in arrs]
     for e in plans:
+        if "out" in e:  # a shared plan's columns ride once
+            outputs += e["out"]
+            continue
         c, kind = e["c"], e["kind"]
         n_contrib = batcher.get(e["n_contrib"])
         validity = n_contrib > 0
@@ -265,9 +268,16 @@ def segment_groupby(
         elif kind == "first":
             validity = (batcher.get(e["vfirst"])
                         & (batcher.get(e["nlive"]) > 0))
-        out_vals.append(DeviceColumn(c.dtype, to_front(agg),
-                                     to_front(validity), None))
+        e["out"] = [agg, validity]
+        outputs += e["out"]
 
+    is_end = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
+    rank = (~(is_end & live_sorted)).astype(jnp.uint8)
+    front = iter(ORD.sort_rows([rank], outputs)[2])
+    out_keys = [DeviceColumn(c.dtype, next(front), next(front),
+                             next(front)) for c in key_cols]
+    out_vals = [DeviceColumn(c.dtype, next(front), next(front), None)
+                for c, _ in value_cols]
     out_sel = jnp.arange(b, dtype=jnp.int32) < num_groups
     return out_keys, out_vals, out_sel, okf
 
@@ -324,14 +334,14 @@ def segment_max_group_count(key_cols, sel, contribs) -> jnp.ndarray:
     b = int(sel.shape[0])
     parts = [ORD._flag_part(~sel)] + ORD.batch_group_parts(list(key_cols))
     limbs = ORD.fuse_parts(parts)
-    sorted_limbs, perm = ORD.sort_by_keys(limbs)
+    sorted_limbs, _, contribs_s = ORD.sort_rows(
+        limbs, [contrib & sel for contrib in contribs])
     diff = jnp.zeros((b,), jnp.bool_)
     for l in sorted_limbs:
         diff = diff | ORD.limb_neq(l, jnp.concatenate([l[:1], l[:-1]]))
     boundary = diff.at[0].set(True)
     out = jnp.zeros((), jnp.int32)
-    for contrib in contribs:
-        cs = jnp.take(contrib & sel, perm)
+    for cs in contribs_s:
         n = segmented_scan(jnp.add, cs.astype(jnp.int32), boundary)
         out = jnp.maximum(out, jnp.max(n))
     return out
@@ -344,8 +354,9 @@ def _sorted_group_layout(key_cols, sel, value_col: DeviceColumn,
     counts compacted to group order via the END-rows-to-front trick.
 
     Returns (values_sorted, contrib_sorted, sorted_limbs, boundary,
-    start_scan, perm, perm2) — ``perm2`` maps compacted group g to its
-    end row (same group order as ``segment_groupby``)."""
+    start_scan, rank) — sorting by ``rank`` (``_group_rows``) maps
+    compacted group g to its end row (same group order as
+    ``segment_groupby``)."""
     b = int(sel.shape[0])
     contrib = sel & value_col.valid_mask()
     tail_parts = [ORD._flag_part(~contrib)]
@@ -354,24 +365,27 @@ def _sorted_group_layout(key_cols, sel, value_col: DeviceColumn,
             value_col, True, True, distinguish_neg_zero=False)
     limbs, key_limbs = ORD.group_sort_limbs(list(key_cols), sel,
                                             tail_parts)
-    sorted_limbs, perm = ORD.sort_by_keys(limbs)
-    live_sorted = jnp.take(sel, perm)
     # boundaries over the KEY limbs only (trailing contrib/value parts
     # must NOT split groups)
-    key_sorted = [jnp.take(l, perm) for l in key_limbs]
+    sorted_limbs, _, moved = ORD.sort_rows(
+        limbs, [sel, contrib, value_col.data] + key_limbs)
+    live_sorted, contrib_sorted, values_sorted = moved[:3]
     diff = jnp.zeros((b,), jnp.bool_)
-    for l in key_sorted:
+    for l in moved[3:]:
         diff = diff | ORD.limb_neq(l, jnp.concatenate([l[:1], l[:-1]]))
     boundary = diff.at[0].set(True)
     is_end = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
     rank = (~(is_end & live_sorted)).astype(jnp.uint8)
-    _, perm2 = ORD.sort_by_keys([rank])
     iota = jnp.arange(b, dtype=jnp.int32)
     start_scan = segmented_scan(_keep_first, iota, boundary)
-    contrib_sorted = jnp.take(contrib, perm)
-    values_sorted = jnp.take(value_col.data, perm, axis=0)
     return (values_sorted, contrib_sorted, sorted_limbs, boundary,
-            start_scan, perm, perm2)
+            start_scan, rank)
+
+
+def _group_rows(rank, per_row):
+    """Each live group's END-row entries of ``per_row``, compacted to
+    the front in group order (the compaction of ``segment_groupby``)."""
+    return ORD.sort_rows([rank], per_row)[2]
 
 
 def segment_collect(key_cols, sel, value_col: DeviceColumn, cap: int,
@@ -389,8 +403,8 @@ def segment_collect(key_cols, sel, value_col: DeviceColumn, cap: int,
     group front with one more stable sort (set order = value order)."""
     b = int(sel.shape[0])
     (values_sorted, contrib_sorted, sorted_limbs, boundary, start_scan,
-     perm, perm2) = _sorted_group_layout(key_cols, sel, value_col,
-                                         value_order=distinct)
+     rank) = _sorted_group_layout(key_cols, sel, value_col,
+                                  value_order=distinct)
     keep = contrib_sorted
     if distinct:
         full_diff = jnp.zeros((b,), jnp.bool_)
@@ -405,12 +419,10 @@ def segment_collect(key_cols, sel, value_col: DeviceColumn, cap: int,
             jnp.uint64)
         limbs3 = ORD.fuse_parts(
             [(grp_ord, 64), ORD._flag_part(~keep)])
-        _, perm3 = ORD.sort_by_keys(limbs3)
-        values_sorted = jnp.take(values_sorted, perm3, axis=0)
-        keep = jnp.take(keep, perm3)
+        _, _, (values_sorted, keep) = ORD.sort_rows(
+            limbs3, [values_sorted, keep])
     n_keep = segmented_scan(jnp.add, keep.astype(jnp.int32), boundary)
-    starts_g = jnp.take(start_scan, perm2)
-    counts_g = jnp.take(n_keep, perm2)
+    starts_g, counts_g = _group_rows(rank, [start_scan, n_keep])
     idx = starts_g[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
     mat = jnp.take(values_sorted, jnp.clip(idx, 0, b - 1).reshape(-1),
                    axis=0).reshape((b, cap) + values_sorted.shape[1:])
@@ -458,35 +470,31 @@ def segment_extreme(key_cols, sel, value_col: DeviceColumn, kind: str
         tail = [ORD._flag_part(~contrib)] + ORD.column_order_parts(
             value_col, True, True, distinguish_neg_zero=False)
     limbs, key_limbs = ORD.group_sort_limbs(list(key_cols), sel, tail)
-    sorted_limbs, perm = ORD.sort_by_keys(limbs)
-    live_sorted = jnp.take(sel, perm)
     # boundaries over the KEY limbs only (trailing null-flag/value parts
     # must NOT split groups; tail bits may share the last key limb)
-    key_sorted = [jnp.take(l, perm) for l in key_limbs]
+    moved = ORD.sort_rows(
+        limbs, [sel, contrib, value_col.data, value_col.lengths,
+                value_col.validity] + key_limbs)[2]
+    live_sorted, contrib_sorted, data_s, lengths_s, validity_s = moved[:5]
     diff = jnp.zeros((b,), jnp.bool_)
-    for l in key_sorted:
+    for l in moved[5:]:
         diff = diff | ORD.limb_neq(l, jnp.concatenate([l[:1], l[:-1]]))
     boundary = diff.at[0].set(True)
     is_end = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
     rank = (~(is_end & live_sorted)).astype(jnp.uint8)
-    _, perm2 = ORD.sort_by_keys([rank])
     iota = jnp.arange(b, dtype=jnp.int32)
     start_scan = segmented_scan(_keep_first, iota, boundary)
-    contrib_sorted = jnp.take(contrib, perm)
     n_contrib = segmented_scan(jnp.add, contrib_sorted.astype(jnp.int32),
                                boundary)
-    starts_g = jnp.take(start_scan, perm2)
-    counts_g = jnp.take(n_contrib, perm2)
+    starts_g, counts_g = _group_rows(rank, [start_scan, n_contrib])
     idx = (starts_g + counts_g - 1) if kind == "max" else starts_g
     idx = jnp.clip(idx, 0, b - 1)
-    data_s = jnp.take(value_col.data, perm, axis=0)
     row_data = jnp.take(data_s, idx, axis=0)
     lengths = None
-    if value_col.lengths is not None:
-        lengths = jnp.take(jnp.take(value_col.lengths, perm), idx)
+    if lengths_s is not None:
+        lengths = jnp.take(lengths_s, idx)
     if kind == "first":
-        base = (jnp.take(jnp.take(value_col.valid_mask(), perm), idx)
-                if value_col.validity is not None
+        base = (jnp.take(validity_s, idx) if validity_s is not None
                 else jnp.ones((b,), jnp.bool_))
         validity = base & (counts_g > 0)  # empty group → null
     else:
@@ -505,12 +513,11 @@ def segment_percentile(key_cols, sel, value_col: DeviceColumn,
     error, always an actual group element (see ApproxPercentile)."""
     b = int(sel.shape[0])
     (values_sorted, contrib_sorted, _limbs, boundary, start_scan,
-     perm, perm2) = _sorted_group_layout(key_cols, sel, value_col,
-                                         value_order=True)
+     rank) = _sorted_group_layout(key_cols, sel, value_col,
+                                  value_order=True)
     n_contrib = segmented_scan(jnp.add, contrib_sorted.astype(jnp.int32),
                                boundary)
-    starts_g = jnp.take(start_scan, perm2)
-    counts_g = jnp.take(n_contrib, perm2)
+    starts_g, counts_g = _group_rows(rank, [start_scan, n_contrib])
     nonempty = counts_g > 0
     if interpolate:
         r = jnp.float64(pct) * jnp.maximum(counts_g - 1, 0).astype(
@@ -606,17 +613,23 @@ def update_value_cols(fns: Sequence[AggregateFunction], batch: DeviceBatch
                       ) -> List[Tuple[DeviceColumn, str]]:
     """Per-batch buffer inputs for the partial (update) pass."""
     out: List[Tuple[DeviceColumn, str]] = []
+    counts = {}  # one 0/1 column a validity: equal inputs are one array
+
+    def count_col(validity) -> DeviceColumn:
+        k = id(validity)
+        if k not in counts:
+            counts[k] = DeviceColumn(T.LongT, (
+                jnp.ones((batch.capacity,), jnp.int64)
+                if validity is None else validity.astype(jnp.int64)))
+        return counts[k]
+
     for fn in fns:
         if isinstance(fn, CountStar):
-            ones = DeviceColumn(T.LongT,
-                                jnp.ones((batch.capacity,), jnp.int64))
-            out.append((ones, "sum"))
+            out.append((count_col(None), "sum"))
             continue
         c = _eval_child(fn, batch)
-        valid = c.valid_mask()
         if isinstance(fn, Count):
-            out.append((DeviceColumn(
-                T.LongT, valid.astype(jnp.int64)), "sum"))
+            out.append((count_col(c.validity), "sum"))
         elif isinstance(fn, (Sum, Average)):
             from spark_rapids_tpu.ops import decimal128 as D128
             rdt = fn.buffer_dtypes()[0]
@@ -626,8 +639,7 @@ def update_value_cols(fns: Sequence[AggregateFunction], batch: DeviceBatch
             else:
                 data = c.data.astype(T.to_numpy_dtype(rdt))
             out.append((DeviceColumn(rdt, data, c.validity), "sum"))
-            out.append((DeviceColumn(
-                T.LongT, valid.astype(jnp.int64)), "sum"))
+            out.append((count_col(c.validity), "sum"))
         elif isinstance(fn, (Min, Max)):
             out.append((c, "min" if isinstance(fn, Min) else "max"))
         elif isinstance(fn, First):
@@ -638,8 +650,7 @@ def update_value_cols(fns: Sequence[AggregateFunction], batch: DeviceBatch
             x = c.data.astype(jnp.float64)
             out.append((DeviceColumn(T.DoubleT, x, c.validity), "sum"))
             out.append((DeviceColumn(T.DoubleT, x * x, c.validity), "sum"))
-            out.append((DeviceColumn(
-                T.LongT, valid.astype(jnp.int64)), "sum"))
+            out.append((count_col(c.validity), "sum"))
         else:
             raise NotImplementedError(f"TPU aggregate {fn.name}")
     return out
@@ -901,12 +912,13 @@ class TpuHashAggregateExec(TpuExec):
             return fn(merged)
 
     def _execute_global(self, src, pre, pre_key) -> DeviceBatch:
-        """Global aggregate: per-batch masked REDUCTION (no sort — the
-        groupby path costs a full lax.sort per batch, measured 175
-        ms/Mrow on chip vs ~1 ms for the reduce), with upstream
-        filter/project fused into the kernel.  Streamed: one input batch
-        held at a time; the single-batch case fuses final projection
-        into the same kernel (one dispatch total)."""
+        """Global aggregate: per-batch masked REDUCTION (no sort, no
+        row movement — on the v5e the group-by's hash sort is 2.2 ms a
+        1 M-row batch, but every gather that brings rows into its order
+        is ~16 ms, ledger PR 26, against ~1 ms for the reduce), with
+        upstream filter/project fused into the kernel.  Streamed: one
+        input batch held at a time; the single-batch case fuses final
+        projection into the same kernel (one dispatch total)."""
         from spark_rapids_tpu.runtime.memory import (
             RetryOOM, get_manager, with_retry)
         mgr = get_manager()

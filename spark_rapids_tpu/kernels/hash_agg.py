@@ -25,20 +25,23 @@ from spark_rapids_tpu.kernels import hash_layout as HL
 
 
 def group_layout_fused(key_limbs: List[jnp.ndarray],
-                       use_pallas: bool = False
+                       use_pallas: bool = False, payload=()
                        ) -> Optional[Tuple[jnp.ndarray, List[jnp.ndarray],
-                                           jnp.ndarray, jnp.ndarray]]:
-    """(perm, sorted_key_limbs, boundary, ok) for a grouped batch, or
-    None when the key limbs are unhashable (raw-f64 limb: DoubleType
-    grouping keys stay on the exact reference; static per instance).
+                                           jnp.ndarray, jnp.ndarray,
+                                           list]]:
+    """(perm, sorted_key_limbs, boundary, ok, moved payload) for a
+    grouped batch, or None when the key limbs are unhashable (raw-f64
+    limb: DoubleType grouping keys stay on the exact reference; static
+    per instance).
 
     ``key_limbs`` is ops.ordering.group_sort_limbs' KEY limb set — the
     dead-row flag is fused into the first limb, so dead rows land in
     their own hash groups; the caller's live-row masking (num_groups,
-    compaction rank) needs no change.
+    compaction rank) needs no change.  ``payload`` is the caller's
+    columns, returned in the layout's row order.
     """
     if not HL.limbs_hashable(key_limbs):
         return None
-    perm, kl_s, boundary, _, ok = HL.hash_group_layout(
-        key_limbs, use_pallas=use_pallas)
-    return perm, kl_s, boundary, ok
+    perm, kl_s, boundary, _, ok, moved = HL.hash_group_layout(
+        key_limbs, use_pallas=use_pallas, payload=payload)
+    return perm, kl_s, boundary, ok, moved

@@ -9,9 +9,10 @@ no host materialization, no data-dependent Python control flow (the
 ``kernel-purity`` lint rule gates exactly that).  The core primitive is
 the **hash-grouped layout**: instead of stably sorting the full
 multi-limb key encoding (sort operand count is the dominant TPU compile
-AND run cost — see ops/ordering.py), rows are stably sorted by ONE
-64-bit hash limb and group boundaries are recovered by comparing the
-full key limbs of adjacent sorted rows.  A 64-bit collision between
+cost — see ops/ordering.py), rows are stably sorted by ONE 64-bit hash
+limb, the key limbs and the caller's columns follow in one row gather,
+and group boundaries are recovered by comparing the full key limbs of
+adjacent sorted rows.  A 64-bit collision between
 distinct keys in the same batch is detected exactly (any offending pair
 is adjacent after the hash sort) and surfaces as ``ok = False`` so the
 dispatcher can fall back to the exact sort-based reference — the fused
@@ -169,17 +170,21 @@ def lower_bound(sorted_limb: jnp.ndarray, queries: jnp.ndarray,
 
 
 def hash_group_layout(key_limbs: List[jnp.ndarray],
-                      use_pallas: bool = False):
+                      use_pallas: bool = False, payload=()):
     """Hash-grouped row layout: the fused group-by/build-side core.
 
-    Returns ``(perm, sorted_key_limbs, boundary, sorted_hash, ok)``:
-    rows stably ordered by the 64-bit key hash (``perm``), group starts
-    under that order (``boundary``, from FULL-key adjacent comparison),
-    and ``ok`` — False iff two adjacent sorted rows share the hash but
-    not the key, i.e. a 64-bit collision made distinct keys
-    non-contiguous.  Any such pair is adjacent after the hash sort, so
-    the detection is exact; callers must fall back to the sort-based
-    reference when ``ok`` is False (probability ~n²/2⁶⁴ per batch).
+    Returns ``(perm, sorted_key_limbs, boundary, sorted_hash, ok,
+    moved)``: rows stably ordered by the 64-bit key hash (``perm``),
+    group starts under that order (``boundary``, from FULL-key adjacent
+    comparison), ``ok`` — False iff two adjacent sorted rows share the
+    hash but not the key, i.e. a 64-bit collision made distinct keys
+    non-contiguous — and ``payload``'s arrays in the sorted order.  Any
+    colliding pair is adjacent after the hash sort, so the detection is
+    exact; callers must fall back to the sort-based reference when
+    ``ok`` is False (probability ~n²/2⁶⁴ per batch).
+
+    The key limbs and the payload come into the hash order together
+    (``ops.ordering.sort_rows``: one row gather, not a take a limb).
 
     Caller contract: ``limbs_hashable(key_limbs)`` is True, and the
     limbs encode the full grouping equivalence (nulls flagged, NaNs
@@ -187,11 +192,13 @@ def hash_group_layout(key_limbs: List[jnp.ndarray],
     """
     from spark_rapids_tpu.ops import ordering as ORD
     h = hash_limbs(key_limbs, use_pallas=use_pallas)
-    (sorted_h,), perm = ORD.sort_by_keys([h])
-    kl_s = [jnp.take(l, perm) for l in key_limbs]
+    nk = len(key_limbs)
+    (sorted_h,), perm, moved = ORD.sort_rows(
+        [h], list(key_limbs) + list(payload))
+    kl_s, moved = moved[:nk], moved[nk:]
     same_h = jnp.concatenate([jnp.zeros((1,), jnp.bool_),
                               sorted_h[1:] == sorted_h[:-1]])
     key_neq = _adjacent_neq(kl_s)
     boundary = key_neq.at[0].set(True)
     ok = ~jnp.any(same_h & key_neq)
-    return perm, kl_s, boundary, sorted_h, ok
+    return perm, kl_s, boundary, sorted_h, ok, moved

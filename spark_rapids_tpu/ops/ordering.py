@@ -293,13 +293,16 @@ def group_sort_limbs(cols: List[DeviceColumn], sel,
     return key_limbs + fuse_parts(list(tail_parts)), key_limbs
 
 
-def sort_by_keys(limbs: List[jnp.ndarray], payload=None
+def sort_by_keys(limbs: List[jnp.ndarray]
                  ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
     """Stable lexicographic sort; returns (sorted limbs, permutation).
 
-    The trailing iota doubles as stabilizer AND permutation output —
-    sort operand count is the dominant TPU compile cost (measured ~25 s
-    per u64 operand at 128k rows), so no separate payload operand.
+    The trailing iota doubles as stabilizer AND permutation output:
+    nothing but the keys rides the sort, because every operand of a
+    1 M-row ``lax.sort`` costs the TPU compiler 12 s (measured on the
+    v5e's host, docs/kernels.md "Moving rows").  Callers that want
+    whole columns in the sorted order use ``sort_rows`` — never a
+    ``jnp.take(x, perm)`` a column.
     """
     import jax
     n = limbs[0].shape[0]
@@ -307,6 +310,132 @@ def sort_by_keys(limbs: List[jnp.ndarray], payload=None
     operands = tuple(limbs) + (iota,)
     res = jax.lax.sort(operands, num_keys=len(limbs) + 1)
     return list(res[:len(limbs)]), res[-1]
+
+
+def sort_rows(limbs: List[jnp.ndarray], payload=()
+              ) -> Tuple[List[jnp.ndarray], jnp.ndarray, list]:
+    """``sort_by_keys`` that brings rows along: returns (sorted limbs,
+    permutation, moved payload), ``moved[i]`` being ``payload[i]`` in
+    the sorted order (``take_rows``)."""
+    sorted_limbs, perm = sort_by_keys(limbs)
+    return sorted_limbs, perm, take_rows(payload, perm)
+
+
+# widest row matrix one gather moves: on the v5e a uint32[1 M, K] row
+# gather takes 4.2 ms at K = 8, 7.5 at 16, 9.6 at 24 — and 39.5 at 32
+# (chip run, PR 27; a 1-D uint32[1 M] take is 8.2 ms)
+_MAX_STACK = 24
+
+
+def _take_stacked(cols: List[jnp.ndarray], perm: jnp.ndarray):
+    """``jnp.take(jnp.stack(cols, 1), perm, 0)``, in as few row gathers
+    of at most ``_MAX_STACK`` columns as ``cols`` needs."""
+    if not cols:
+        return None
+    n = -(-len(cols) // _MAX_STACK)  # gathers
+    k = -(-len(cols) // n)           # columns a gather, balanced
+    parts = [jnp.take(jnp.stack(cols[i:i + k], axis=1), perm, axis=0)
+             for i in range(0, len(cols), k)]
+    return parts[0] if n == 1 else jnp.concatenate(parts, axis=1)
+
+
+def take_rows(arrays, perm: jnp.ndarray) -> list:
+    """``[jnp.take(x, perm, axis=0) for x in arrays]``, bit for bit, in
+    two gathers (more only past ``_MAX_STACK`` columns) instead of one
+    a column.
+
+    A gather by a permutation pays per INDEX on the TPU, not per byte
+    (docs/kernels.md "Moving rows": ~16 ms a 1 M-row column whatever
+    its width), so the columns are stacked into row matrices and each
+    row moves once: every integer kind packs into the columns of ONE
+    ``uint32[B, K]`` — booleans a bit each, ``uint8[B, W]`` byte
+    matrices (strings) four bytes a column, 8/16-bit integers widened,
+    64-bit integers as two halves, ``float32`` by its bits — and the
+    ``float64`` columns stack into one ``float64[B, K]`` (no 64-bit
+    bitcast compiles on TPU, so doubles cannot join the words).  All
+    shift, convert and 32-bit bitcast: exact.  ``None`` entries pass
+    through, an array given twice moves once, other 2-D columns
+    (decimal128 ``int64[B, 2]``) go column by column.
+    """
+    import jax
+    words: List[jnp.ndarray] = []    # uint32[B] columns
+    doubles: List[jnp.ndarray] = []  # float64[B] columns
+    flags: List[jnp.ndarray] = []    # bool[B], 32 to a word
+    u32, u64 = jnp.uint32, jnp.uint64
+
+    def word(x) -> int:
+        words.append(x)
+        return len(words) - 1
+
+    def pack(x):
+        """Queue ``x``'s bits; return the recipe ``unpack`` reads."""
+        dt = x.dtype
+        if x.ndim == 2 and dt == jnp.uint8:
+            w = x.shape[1]
+            cols = []
+            for i in range(0, w, 4):
+                acc = x[:, i].astype(u32)
+                for j in range(1, min(4, w - i)):
+                    acc = acc | (x[:, i + j].astype(u32) << u32(8 * j))
+                cols.append(word(acc))
+            return ("bytes", cols, w)
+        if x.ndim == 2:
+            return ("cols", [pack(x[:, j]) for j in range(x.shape[1])])
+        if dt == jnp.bool_:
+            flags.append(x)
+            return ("flag", len(flags) - 1)
+        if dt == jnp.float64:
+            doubles.append(x)
+            return ("double", len(doubles) - 1)
+        if dt.itemsize == 8:
+            bits = x.astype(u64)
+            lo = word(bits.astype(u32))
+            return ("wide", word((bits >> u64(32)).astype(u32)), lo, dt)
+        if dt.itemsize == 4:
+            return ("word", word(jax.lax.bitcast_convert_type(x, u32)), dt)
+        if not jnp.issubdtype(dt, jnp.integer):
+            raise TypeError(f"take_rows cannot pack a {dt} column")
+        # 8/16-bit integers: sign-extend or zero-extend, narrow back
+        return ("narrow", word(x.astype(jnp.int32).astype(u32)), dt)
+
+    recipes = {}
+    for x in arrays:
+        if x is not None and id(x) not in recipes:
+            recipes[id(x)] = pack(x)
+    first_flag_word = len(words)
+    for i in range(0, len(flags), 32):
+        acc = flags[i].astype(u32)
+        for j, f in enumerate(flags[i + 1:i + 32], 1):
+            acc = acc | (f.astype(u32) << u32(j))
+        words.append(acc)
+    w_m = _take_stacked(words, perm)
+    d_m = _take_stacked(doubles, perm)
+
+    def unpack(r):
+        kind = r[0]
+        if kind == "bytes":
+            _, cols, w = r
+            return jnp.stack(
+                [(w_m[:, cols[i // 4]] >> u32(8 * (i % 4))
+                  ).astype(jnp.uint8) for i in range(w)], axis=1)
+        if kind == "cols":
+            return jnp.stack([unpack(c) for c in r[1]], axis=1)
+        if kind == "flag":
+            bits = w_m[:, first_flag_word + r[1] // 32]
+            return ((bits >> u32(r[1] % 32)) & u32(1)) != 0
+        if kind == "double":
+            return d_m[:, r[1]]
+        if kind == "wide":
+            _, hi, lo, dt = r
+            return ((w_m[:, hi].astype(u64) << u64(32))
+                    | w_m[:, lo].astype(u64)).astype(dt)
+        if kind == "word":
+            return jax.lax.bitcast_convert_type(w_m[:, r[1]], r[2])
+        assert kind == "narrow"
+        return w_m[:, r[1]].astype(jnp.int32).astype(r[2])
+
+    out = {k: unpack(r) for k, r in recipes.items()}
+    return [None if x is None else out[id(x)] for x in arrays]
 
 
 # ----------------------------------------------------------------------------

@@ -88,6 +88,18 @@ def test_group_layout_fused_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_q1_shaped_groupby_compiles(one_chip):
+    # the whole row mover under x64: strings, doubles and a long stacked
+    # into one word matrix and one double matrix a permutation
+    from test_kernels import _q1_shaped_args, _q1_shaped_groupby
+    c = _compile(_q1_shaped_groupby("pallas"), one_chip,
+                 *_q1_shaped_args(SMALL))
+    hlo = c.as_text()
+    assert "tpu_custom_call" in hlo
+    # a double is two f32 on the chip: 2 permutations x (words, hi, lo)
+    assert hlo.count(" gather(") == 6
+
+
 # on a TPU `auto` resolves the sort kernel to the tiled ("fused") form
 # (kernels.resolve with supports_pallas=False); "jnp" is its t == 1 arm
 @pytest.mark.parametrize("backend", ["fused", "jnp"])

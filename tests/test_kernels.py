@@ -162,7 +162,7 @@ def test_group_layout_matches_reference_groups(case):
     limbs = _limb_cases()[case]
     res = KNA.group_layout_fused(limbs)
     assert res is not None
-    perm, kl_s, boundary, ok = res
+    perm, kl_s, boundary, ok, _ = res
     assert bool(ok)
     keys = list(zip(*[np.asarray(l).tolist() for l in limbs]))
     # same group count, and each hash-order group is key-pure
@@ -183,7 +183,7 @@ def test_collision_detected_exactly(monkeypatch):
         lambda limbs, use_pallas=False: jnp.zeros(
             (int(limbs[0].shape[0]),), jnp.uint64))
     limbs = [_limb([1, 2, 1, 2])]
-    *_, ok = HL.hash_group_layout(limbs)
+    ok = HL.hash_group_layout(limbs)[4]
     assert not bool(ok)
     m = KNJ.match_fused(limbs, limbs, jnp.zeros((4,), jnp.bool_))
     assert not bool(m[3])
@@ -425,3 +425,242 @@ def test_kernel_backend_in_stats_and_counters():
     if prof:
         backends = [r.get("kernel_backend") for r in prof.get("ops", [])]
         assert any(b in ("fused", "mixed") for b in backends if b)
+
+
+# ---------------------------------------------------------------------------
+# kernel-level: the row mover (ops.ordering.sort_rows) and the group-by
+# through it, against the per-column jnp.take it replaced
+# ---------------------------------------------------------------------------
+
+def _take_rows_reference(limbs, payload=()):
+    """The plain reference: sort (limbs, iota), then ONE ``jnp.take`` a
+    column — what ``segment_groupby`` did before the columns rode the
+    sort."""
+    import jax
+    iota = jnp.arange(limbs[0].shape[0], dtype=jnp.int32)
+    *sorted_limbs, perm = jax.lax.sort(tuple(limbs) + (iota,),
+                                       num_keys=len(limbs) + 1)
+    return sorted_limbs, perm, [
+        None if x is None else jnp.take(x, perm, axis=0)
+        for x in payload]
+
+
+def _str_col(rng, n, vocab, validity=None):
+    from spark_rapids_tpu.columnar import dtypes as T
+    from spark_rapids_tpu.columnar.column import DeviceColumn
+    ix = rng.integers(0, len(vocab), n)
+    data = np.zeros((n, 8), np.uint8)
+    lens = np.zeros((n,), np.int32)
+    for i, v in enumerate(vocab):
+        raw = v.encode()
+        data[ix == i, :len(raw)] = np.frombuffer(raw, np.uint8)
+        lens[ix == i] = len(raw)
+    return DeviceColumn(T.StringT, jnp.asarray(data), validity,
+                        jnp.asarray(lens))
+
+
+def _q1_vals(doubles, ones):
+    """Q1's buffer inputs as ``update_value_cols`` hands them over:
+    sum(qty, price, disc_price, charge), avg(qty, price, disc), count —
+    5 distinct ``double`` columns, every count the one 0/1 column."""
+    from spark_rapids_tpu.columnar import dtypes as T
+    from spark_rapids_tpu.columnar.column import DeviceColumn as DC
+    d = [DC(T.DoubleT, x) for x in doubles]
+    cnt = DC(T.LongT, ones)
+    vals = []
+    for c in (d[0], d[1], d[2], d[3], d[0], d[1], d[4]):
+        vals += [(c, "sum"), (cnt, "sum")]
+    return vals + [(cnt, "sum")]
+
+
+def _mover_case(name):
+    """(key columns, sel, [(value column, kind)]) for one column kind."""
+    from spark_rapids_tpu.columnar import dtypes as T
+    from spark_rapids_tpu.columnar.column import DeviceColumn as DC
+    rng = np.random.default_rng(sorted(_MOVER_CASES).index(name) + 40)
+    n = 0 if name == "zero_rows" else 192
+    j = jnp.asarray
+
+    def nulls():
+        return j(rng.random(n) < 0.7)
+
+    dbl = rng.standard_normal(n) * 1e3
+    if n:
+        dbl[rng.integers(0, n, 6)] = np.nan
+        dbl[rng.integers(0, n, 3)] = -0.0
+    lng = rng.integers(-(1 << 62), 1 << 62, n)
+    i32 = rng.integers(-(1 << 31), 1 << 31, n).astype(np.int32)
+    dec = np.stack([rng.integers(-4, 4, n), rng.integers(
+        -(1 << 63), 1 << 63, n)], axis=1).astype(np.int64)
+    dec_t = T.DecimalType(38, 2)
+    sel = np.ones(n, bool)
+    k_int = DC(T.IntegerT, j(rng.integers(0, 7, n).astype(np.int32)))
+    keys = [k_int]
+    vals = [(DC(T.DoubleT, j(dbl)), "sum"), (DC(T.LongT, j(lng)), "max")]
+    if name == "double":
+        vals = [(DC(T.DoubleT, j(dbl)), k) for k in ("sum", "min", "max")]
+    elif name == "long":
+        keys = [DC(T.LongT, j(rng.integers(-3, 3, n)))]
+        vals = [(DC(T.LongT, j(lng)), k) for k in ("sum", "min", "first")]
+    elif name == "int":
+        vals = [(DC(T.IntegerT, j(i32)), k) for k in ("min", "max")]
+    elif name == "boolean":
+        keys = [DC(T.BooleanT, j(rng.random(n) < 0.5))]
+        vals = [(DC(T.BooleanT, j(rng.random(n) < 0.5)), "first"),
+                (DC(T.BooleanT, j(rng.random(n) < 0.5)), "max")]
+    elif name == "string_keys":
+        keys = [_str_col(rng, n, ["A", "N", "R", ""]),
+                _str_col(rng, n, ["F", "O", "a\x00b", "longer88"])]
+    elif name == "decimal128":
+        keys = [DC(dec_t, j(dec % np.array([2, 3])))]
+        vals = [(DC(dec_t, j(dec)), "sum"), (DC(T.LongT, j(lng)), "min")]
+    elif name == "with_validity":
+        keys = [DC(T.IntegerT, k_int.data, nulls()),
+                _str_col(rng, n, ["x", "yy"], nulls())]
+        vals = [(DC(T.DoubleT, j(dbl), nulls()), "sum"),
+                (DC(T.LongT, j(lng), nulls()), "first"),
+                (DC(T.BooleanT, j(rng.random(n) < 0.5), nulls()), "min"),
+                (DC(dec_t, j(dec), nulls()), "sum")]
+    elif name == "dead_rows":
+        sel = rng.random(n) < 0.5
+    elif name == "one_group":
+        keys = [DC(T.IntegerT, jnp.zeros((n,), jnp.int32))]
+    elif name == "all_distinct":
+        keys = [DC(T.LongT, j(rng.permutation(n).astype(np.int64)))]
+    elif name == "all_dead":
+        sel = np.zeros(n, bool)
+    elif name == "q1_shape":
+        # 2 string keys; sum(x) and avg(x) ask for one column twice and
+        # every count is one shared 0/1 column: each rides once
+        keys = [_str_col(rng, n, ["A", "N", "R"]),
+                _str_col(rng, n, ["F", "O"])]
+        vals = _q1_vals([j(rng.uniform(1, 1e5, n)) for _ in range(5)],
+                        jnp.ones((n,), jnp.int64))
+        sel = rng.random(n) < 0.9
+    return keys, j(sel), vals
+
+
+_MOVER_CASES = ("double", "long", "int", "boolean", "string_keys",
+                "decimal128", "with_validity", "dead_rows", "one_group",
+                "all_distinct", "all_dead", "zero_rows", "q1_shape")
+
+
+def _bits(x):
+    a = np.asarray(x)
+    return a.dtype, a.shape, a.tobytes()
+
+
+@pytest.mark.parametrize("backend", ["jnp", "fused"])
+@pytest.mark.parametrize("case", _MOVER_CASES)
+def test_rows_ride_the_sort(case, backend, monkeypatch):
+    from spark_rapids_tpu.exec import aggregate as AG
+    keys, sel, vals = _mover_case(case)
+    # the mover itself: every array of the case, by the case's own
+    # group-sort limbs, against numpy's fancy index and jnp.take
+    payload = [sel]
+    for c in keys + [c for c, _ in vals]:
+        payload += [c.data, c.validity, c.lengths]
+    limbs, _ = ORD.group_sort_limbs(keys, sel)
+    s_ref, p_ref, m_ref = _take_rows_reference(limbs, payload)
+    s_got, p_got, m_got = ORD.sort_rows(limbs, payload)
+    assert _bits(p_got) == _bits(p_ref)
+    for a, b in zip(s_got, s_ref):
+        assert _bits(a) == _bits(b)
+    order = np.asarray(p_ref)
+    for x, got, ref in zip(payload, m_got, m_ref):
+        if x is None:
+            assert got is None and ref is None
+            continue
+        assert _bits(got) == _bits(ref) == _bits(np.asarray(x)[order])
+    if case == "zero_rows":
+        return  # no batch has capacity 0: the group-by never sees one
+    # the group-by through it: bit-equal, column for column, with the
+    # same group-by over the per-column takes
+    got = AG.segment_groupby(keys, sel, vals, backend=backend)
+    monkeypatch.setattr(ORD, "sort_rows", _take_rows_reference)
+    ref = AG.segment_groupby(keys, sel, vals, backend=backend)
+    n_groups = int(np.asarray(ref[2]).sum())
+    live = np.asarray(sel)
+    if live.any():
+        key_rows = {tuple(
+            None if (c.validity is not None
+                     and not np.asarray(c.validity)[i])
+            else np.asarray(c.data)[i].tobytes()[
+                :None if c.lengths is None
+                else int(np.asarray(c.lengths)[i])]
+            for c in keys) for i in np.flatnonzero(live)}
+        assert n_groups == len(key_rows)
+    else:
+        assert n_groups == 0
+    assert _bits(got[2]) == _bits(ref[2])
+    assert (got[3] is None) == (ref[3] is None)
+    if got[3] is not None:
+        assert bool(got[3]) and bool(ref[3])
+    for cg, cr in zip(got[0] + got[1], ref[0] + ref[1]):
+        for a, b in ((cg.data, cr.data), (cg.validity, cr.validity),
+                     (cg.lengths, cr.lengths)):
+            assert (a is None) == (b is None)
+            if a is not None:
+                assert _bits(a) == _bits(b)
+
+
+def _q1_shaped_groupby(backend):
+    """``segment_groupby`` as a function of a flat tuple of arrays, in
+    Q1's shape: 2 string keys, 5 ``double`` + 1 ``long`` value columns
+    (``test_chip_compile.py`` compiles the same function for the
+    described v5e)."""
+    from spark_rapids_tpu.columnar import dtypes as T
+    from spark_rapids_tpu.columnar.column import DeviceColumn as DC
+    from spark_rapids_tpu.exec import aggregate as AG
+
+    def fn(sel, k1, l1, k2, l2, d0, d1, d2, d3, d4, cnt):
+        keys = [DC(T.StringT, k1, None, l1), DC(T.StringT, k2, None, l2)]
+        ok, ov, out_sel, okf = AG.segment_groupby(
+            keys, sel, _q1_vals([d0, d1, d2, d3, d4], cnt),
+            backend=backend)
+        return ([(c.data, c.lengths) for c in ok],
+                [(c.data, c.validity) for c in ov], out_sel, okf)
+    return fn
+
+
+def _q1_shaped_args(n):
+    return ([((n,), jnp.bool_), ((n, 8), jnp.uint8), ((n,), jnp.int32),
+             ((n, 8), jnp.uint8), ((n,), jnp.int32)]
+            + [((n,), jnp.float64)] * 5 + [((n,), jnp.int64)])
+
+
+@pytest.mark.parametrize("backend", ["jnp", "fused"])
+def test_groupby_moves_rows_once_a_permutation(backend):
+    """Structural guard: a Q1-shaped group-by gathers batch-width rows
+    four times — the word matrix and the double matrix, through the
+    key sort's permutation and through the compaction's — however many
+    columns it carries (it was 39 one-column takes a batch: 16 ms each
+    at 1 M rows on the v5e, 3.85 of Q1's 4.13 s)."""
+    import re
+    import jax
+    n = 384  # no other dimension of the program has this size
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in _q1_shaped_args(n)]
+    hlo = jax.jit(_q1_shaped_groupby(backend)).lower(
+        *args).compile().as_text()
+    gathers = [l for l in hlo.splitlines()
+               if re.search(rf"= \S*\[{n}[\],]\S* gather\(", l)]
+    assert len(gathers) == 4, "\n".join(gathers)
+    assert len(re.findall(r" sort\(", hlo)) == 2
+
+
+def test_take_rows_splits_wide_stacks():
+    # past _MAX_STACK word columns the row matrix is cut into balanced
+    # gathers (a 32-column gather is 4x a 24-column one on the v5e)
+    rng = np.random.default_rng(12)
+    n = 64
+    cols = [jnp.asarray(rng.integers(0, 1 << 31, n).astype(np.int32))
+            for _ in range(2 * ORD._MAX_STACK + 3)]
+    wide = jnp.asarray(rng.integers(0, 255, (n, 40)).astype(np.uint8))
+    perm = jnp.asarray(rng.permutation(n).astype(np.int32))
+    got = ORD.take_rows(cols + [wide], perm)
+    for x, g in zip(cols + [wide], got):
+        assert _bits(g) == _bits(np.asarray(x)[np.asarray(perm)])
+    import jax
+    hlo = jax.jit(lambda cs, w, p: ORD.take_rows(list(cs) + [w], p)
+                  ).lower(tuple(cols), wide, perm).compile().as_text()
+    assert hlo.count(" gather(") == 3  # 51 + 10 word columns
