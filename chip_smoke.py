@@ -258,7 +258,7 @@ def phase_served(session, names, tables, xla: XlaCounts) -> dict:
     """The same queries through the serving front door, submitted
     together under two tenants."""
     import bench
-    from spark_rapids_tpu.runtime import memory
+    from spark_rapids_tpu.runtime import attribution, memory
     from spark_rapids_tpu.sql.server import QueryServer
     server = QueryServer(session)
     c0 = counters(xla)
@@ -271,9 +271,19 @@ def phase_served(session, names, tables, xla: XlaCounts) -> dict:
     results = {}
     for name, h in handles:
         results[name] = server.result(h, timeout_s=900)
+        books = [b for b in attribution.recent()
+                 if b["query_id"] == h.query_id]
         emit("served", query=name, tenant=h.tenant, state=h.state,
-             queue_wait_s=h.queue_wait_s, wall_s=h.wall_s)
+             queue_wait_s=h.queue_wait_s, wall_s=h.wall_s,
+             books=len(books),
+             buckets=({k: v for k, v in books[0]["buckets"].items() if v}
+                      if books else None))
         require(h.state == "OK", f"served {name} ended {h.state}")
+        # the queries are submitted together and run beside each other:
+        # each has to close a ledger of its own (runtime/inflight.py)
+        require(len(books) == 1,
+                f"served {name} (query {h.query_id}) published "
+                f"{len(books)} ledgers of its own, not 1")
     wall = time.monotonic() - t0
     stats = server.stats()
     server.shutdown()

@@ -34,6 +34,7 @@ from spark_rapids_tpu.columnar import host as H
 from spark_rapids_tpu.columnar.column import DeviceBatch, host_to_device
 from spark_rapids_tpu.conf import RapidsConf
 from spark_rapids_tpu.exec.base import CpuExec, TpuExec
+from spark_rapids_tpu.runtime import inflight
 
 
 def parquet_schema(paths: Sequence[str]) -> T.StructType:
@@ -281,7 +282,10 @@ class TpuParquetScanExec(TpuExec):
         with cf.ThreadPoolExecutor(max_workers=self.num_threads) as pool:
             from spark_rapids_tpu import conf as C
             dict_dec = bool(self._cpu.conf.get(C.PARQUET_DEVICE_DICT))
-            futures = [pool.submit(self._cpu._read_file, fi, dict_dec)
+            # reader threads work for this query: what they record
+            # goes into its books, not into nobody's
+            read_file = inflight.carry(self._cpu._read_file)
+            futures = [pool.submit(read_file, fi, dict_dec)
                        for fi in idxs]
             for fut in futures:
                 with self.timer("scanTime"):

@@ -26,7 +26,9 @@ import time
 from typing import Callable, List, Optional, Sequence
 
 from spark_rapids_tpu.parallel.rendezvous import RendezvousClient
+from spark_rapids_tpu.runtime import inflight
 from spark_rapids_tpu.runtime import telemetry as TM
+from spark_rapids_tpu.runtime import trace
 
 
 class ExecutorContext:
@@ -167,8 +169,15 @@ def run_pump_tasks(fn: Callable, items: Sequence,
         if max_workers <= 1 or len(items) == 1:
             return [timed(i) for i in items]
         from concurrent.futures import ThreadPoolExecutor
-        with ThreadPoolExecutor(max_workers=max_workers) as pool:
-            return list(pool.map(timed, items))
+        # the caller waits here for the pool's threads to start, run
+        # and join: the pump's envelope, charged wherever no task's
+        # own span covers the instant (beside other queries' threads
+        # the wake-up alone can take tens of milliseconds)
+        with trace.span("PumpTask", "poolWait"):
+            with ThreadPoolExecutor(max_workers=max_workers) as pool:
+                # pool threads work for the calling thread's query:
+                # their spans, stats and events go into its books
+                return list(pool.map(inflight.carry(timed), items))
     finally:
         # tasks cancelled before starting (an earlier task raised)
         # never ran their own decrement — settle the gauge exactly

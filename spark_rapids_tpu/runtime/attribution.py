@@ -38,13 +38,14 @@ from __future__ import annotations
 
 import json
 import os
-import threading
 import time
 import uuid
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from spark_rapids_tpu.runtime import inflight
 from spark_rapids_tpu.runtime import telemetry as TM
+from spark_rapids_tpu.runtime.inflight import BOOKS
 
 # ---------------------------------------------------------------------------
 # The bucket catalog — the declared registry the ledger, the
@@ -114,9 +115,11 @@ BUCKET_VERDICTS: Dict[str, str] = {
 STAGE_BUCKETS: Dict[str, Optional[str]] = {
     "pump": "pump_idle",            # Cpu* ops -> host_fallback
     "pumpTask": "pump_idle",
+    "poolWait": "pump_idle",        # the caller waiting for the pump pool
     "optimize": "plan",
     "physicalPlan": "plan",
     "overrides": "plan",
+    "buildPlan": "plan",            # QueryServer: the submitted callable
     "kernelLaunch": "kernel_launch",
     "opTime": "kernel_dispatch",
     "transferTime": "kernel_dispatch",  # DeviceToHostExec -> result_d2h
@@ -149,6 +152,8 @@ STAGE_BUCKETS: Dict[str, Optional[str]] = {
     # charging it would absorb every uninstrumented gap and make the
     # closure check vacuous
     "execute": None,
+    # the served envelope, run slot granted to handle.done: the same
+    "serve": None,
     # the epilogue runs after the wall the ledger closes on: its span
     # is timed for ``record_s`` (and mirrored), never charged
     "record": None,
@@ -327,6 +332,7 @@ def publish(att: Dict[str, Any], tracer) -> Dict[str, Any]:
     att["t1_mono"] = tracer.t_start_mono + (tracer.wall_s or 0.0)
     att["record_s"] = None
     _RECENT.append(att)
+    TM.BOOKS_PUBLISHED.inc()
     return att
 
 
@@ -349,7 +355,8 @@ def verdict_line(att: Dict[str, Any]) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Flight recorder — one query at a time owns it (trace._ACTIVE model)
+# Flight recorder — one a query in flight, by the query's thread of
+# execution (runtime/inflight.py, the mechanism the tracer uses)
 # ---------------------------------------------------------------------------
 
 class FlightRecorder:
@@ -389,40 +396,29 @@ class FlightRecorder:
         }
 
 
-_ACTIVE: Optional[FlightRecorder] = None
-_ACTIVE_LOCK = threading.Lock()
-
-
 def current() -> Optional[FlightRecorder]:
-    return _ACTIVE
+    return BOOKS.recorder
 
 
 def start_query(query_id: int,
                 ring_size: int = 256) -> Optional[FlightRecorder]:
-    """Install a fresh recorder; None when another query owns it (a
-    nested execution rides the owner, same as tracing)."""
-    global _ACTIVE
-    with _ACTIVE_LOCK:
-        if _ACTIVE is not None:
-            return None
-        _ACTIVE = FlightRecorder(query_id, ring_size=ring_size)
-        return _ACTIVE
+    """Install a fresh recorder for the calling thread's query; None
+    when the thread already has one (a nested execution rides the
+    owner, same as tracing)."""
+    return inflight.install(inflight.RECORDER, lambda: FlightRecorder(
+        query_id, ring_size=ring_size))
 
 
 def end_query(rec: Optional[FlightRecorder]) -> None:
-    global _ACTIVE
-    if rec is None:
-        return
-    with _ACTIVE_LOCK:
-        if _ACTIVE is rec:
-            _ACTIVE = None
+    inflight.remove(inflight.RECORDER, rec)
 
 
 def record_event(kind: str, payload: dict) -> None:
-    """Event into the active query's ring, no-op otherwise — THE hook
-    free-standing producers (retry policy, health evaluator, cancel
-    path) use without carrying a recorder reference."""
-    rec = _ACTIVE
+    """Event into the ring of the calling thread's query, no-op
+    otherwise — THE hook free-standing producers (retry policy, health
+    evaluator, cancel path) use without carrying a recorder
+    reference."""
+    rec = BOOKS.recorder
     if rec is not None:
         rec.record_event(kind, payload)
 

@@ -18,9 +18,10 @@ collection plane all four consumers read from:
 
 Collection is attached at every ``ExecNode`` pump boundary by the
 ``__init_subclass__`` auto-wiring in exec/base.py (the same zero-per-op
-mechanism the tracer and the cancellation layer ride).  One collector is
-active per query (module global, like runtime/trace.py) — a nested
-execution rides the owner's collector.
+mechanism the tracer and the cancellation layer ride).  Every query in
+flight owns a collector, by its thread of execution
+(runtime/inflight.py, as for the tracer) — a nested execution rides
+the owner's collector.
 
 Cost note: observing a DeviceBatch forces one device sync per pumped
 batch (``num_rows_host``); ``level=FULL`` adds one per nullable column
@@ -34,6 +35,9 @@ import hashlib
 import json
 import threading
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+
+from spark_rapids_tpu.runtime import inflight
+from spark_rapids_tpu.runtime.inflight import BOOKS
 
 # The stats-field catalog: every key a profile record's per-op entry (or
 # exchange summary) may carry.  docs_gen.check_stats_documented asserts
@@ -443,41 +447,26 @@ class OpStatsCollector:
 
 
 # ---------------------------------------------------------------------------
-# The active collector — one query at a time owns it
+# The collector of the query in flight on this thread
+# (runtime/inflight.py, the mechanism the tracer uses)
 # ---------------------------------------------------------------------------
 
-# Checked on every pump step; a bare module global keeps the off path to
-# one attribute load (same shape as trace._ACTIVE).  A nested execution
-# (sub-query planned mid-query) rides the owner's collector.
-_ACTIVE: Optional[OpStatsCollector] = None
-_ACTIVE_LOCK = threading.Lock()
-
-
 def current() -> Optional[OpStatsCollector]:
-    return _ACTIVE
+    return BOOKS.collector
 
 
 def start_query(query_id: int, level: str = "BASIC",
                 skew_threshold: float = 2.0
                 ) -> Optional[OpStatsCollector]:
-    """Install a fresh collector; returns None when another query
-    already owns stats collection (the caller is a nested execution)."""
-    global _ACTIVE
-    with _ACTIVE_LOCK:
-        if _ACTIVE is not None:
-            return None
-        _ACTIVE = OpStatsCollector(query_id, level=level,
-                                   skew_threshold=skew_threshold)
-        return _ACTIVE
+    """Install a fresh collector for the calling thread's query;
+    returns None when the thread already has one (the caller is a
+    nested execution and rides its owner's)."""
+    return inflight.install(inflight.COLLECTOR, lambda: OpStatsCollector(
+        query_id, level=level, skew_threshold=skew_threshold))
 
 
 def end_query(collector: Optional[OpStatsCollector]) -> None:
-    global _ACTIVE
-    if collector is None:
-        return
-    with _ACTIVE_LOCK:
-        if _ACTIVE is collector:
-            _ACTIVE = None
+    inflight.remove(inflight.COLLECTOR, collector)
 
 
 # ---------------------------------------------------------------------------

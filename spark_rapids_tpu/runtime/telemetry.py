@@ -41,6 +41,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from spark_rapids_tpu.runtime import inflight
+
 # seconds-scale latency buckets (semaphore acquires, pump tasks)
 DEFAULT_BUCKETS = (0.001, 0.005, 0.025, 0.1, 0.5, 1.0, 5.0, 30.0)
 
@@ -340,7 +342,7 @@ class MetricsRegistry:
             if len(self._health) > self.HEALTH_CAP:
                 del self._health[:-self.HEALTH_CAP]
         # flight recorder (runtime/attribution.py): health verdicts
-        # join the active query's black-box ring.  Lazy import —
+        # join the ring of the calling thread's query.  Lazy import —
         # attribution imports this module at its top level.
         from spark_rapids_tpu.runtime import attribution
         attribution.record_event("health", dict(event))
@@ -357,6 +359,20 @@ _QUERIES = REGISTRY.counter(
     "tpuq_queries_total", "queries executed (toArrow/collect)")
 _HEALTH_WARNS = REGISTRY.counter(
     "tpuq_health_warn_total", "health-evaluator WARN events emitted")
+# the books of queries in flight (runtime/inflight.py): the tracer and
+# the ledger's publisher count here
+BOOKS_PUBLISHED = REGISTRY.counter(
+    "tpuq_query_books_published_total",
+    "ledgers closed and published to attribution.recent(), one a query "
+    "that owned its tracer")
+BOOKS_RIDDEN = REGISTRY.counter(
+    "tpuq_query_books_ridden_total",
+    "executions that rode another query's tracer (nested executions on "
+    "their owner's thread) and so closed no ledger of their own")
+REGISTRY.gauge(
+    "tpuq_queries_in_flight_peak",
+    "the most queries that owned a tracer at one time in this process",
+    fn=inflight.in_flight_peak)
 
 
 def ensure_producers() -> None:

@@ -36,6 +36,10 @@ from typing import Any, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
+from spark_rapids_tpu.runtime import inflight
+from spark_rapids_tpu.runtime import telemetry as TM
+from spark_rapids_tpu.runtime.inflight import BOOKS
+
 # ---------------------------------------------------------------------------
 # Spans
 # ---------------------------------------------------------------------------
@@ -215,15 +219,14 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# The active tracer — one query at a time owns it
+# The tracer of the query in flight on this thread (runtime/inflight.py)
 # ---------------------------------------------------------------------------
 
-# Checked on every pump step, so it is a bare module global (one
-# attribute load when tracing is off).  A second query starting while
-# one is active (a sub-query planned during execution) rides the owner's
-# spans instead of replacing the tracer.
-_ACTIVE: Optional[Tracer] = None
-_ACTIVE_LOCK = threading.Lock()
+# Checked on every pump step: one attribute load on the calling
+# thread's slots, whether or not other queries are in flight on other
+# threads.  A second query starting on a thread that has an owner (a
+# sub-query planned during execution) rides the owner's spans instead
+# of replacing the tracer.
 _QUERY_IDS = itertools.count(1)
 
 
@@ -232,39 +235,38 @@ def next_query_id() -> int:
 
 
 def current() -> Optional[Tracer]:
-    return _ACTIVE
+    return BOOKS.tracer
 
 
 def start_query(query_id: int, max_events: int = 100_000
                 ) -> Optional[Tracer]:
-    """Install a fresh tracer; returns None when another query already
-    owns tracing (the caller is a nested execution).  Asks the profiler
-    once, here, whether a session is recording: the tracer then mirrors
-    its spans for the whole query, and otherwise never makes one."""
-    global _ACTIVE
-    with _ACTIVE_LOCK:
-        if _ACTIVE is not None:
-            return None
-        _ACTIVE = Tracer(query_id, max_events=max_events,
-                         mirror=bool(TraceAnnotation.is_enabled()))
-        return _ACTIVE
+    """Install a fresh tracer for the calling thread's query, whatever
+    runs on other threads; returns None when the thread already has an
+    owner (the caller is a nested execution and rides it).  Asks the
+    profiler once, here, whether a session is recording: the tracer
+    then mirrors its spans for the whole query, and otherwise never
+    makes one."""
+    tracer = inflight.install(inflight.TRACER, lambda: Tracer(
+        query_id, max_events=max_events,
+        mirror=bool(TraceAnnotation.is_enabled())))
+    if tracer is None:
+        TM.BOOKS_RIDDEN.inc()
+    return tracer
 
 
 def end_query(tracer: Optional[Tracer]) -> None:
-    global _ACTIVE
     if tracer is None:
         return
     tracer.finish()
-    with _ACTIVE_LOCK:
-        if _ACTIVE is tracer:
-            _ACTIVE = None
+    inflight.remove(inflight.TRACER, tracer)
 
 
 def span(op: str, stage: str, args: Optional[dict] = None):
-    """Span on the active tracer, or a no-op when tracing is off —
-    THE hook free-standing stages (kernel compile, spill, shuffle
-    serialize) use without carrying a tracer reference."""
-    tr = _ACTIVE
+    """Span on the calling thread's tracer, or a no-op when tracing is
+    off or the thread works for no query — THE hook free-standing
+    stages (kernel compile, spill, shuffle serialize) use without
+    carrying a tracer reference."""
+    tr = BOOKS.tracer
     if tr is None:
         return _NULL
     return tr.span(op, stage, args)

@@ -20,6 +20,36 @@ from spark_rapids_tpu.plan.planner import plan_physical
 from spark_rapids_tpu.sql.column import Column, UExpr, col as _col
 
 
+def open_books(conf, qid: int):
+    """The tracer and the flight recorder of query ``qid``, installed
+    on the calling thread (runtime/inflight.py): ``(tracer, recorder)``,
+    each None where it is switched off or the thread already has an
+    owner (a nested execution rides it).  ``DataFrame.toArrow`` opens
+    them for a direct execution; ``QueryServer`` opens them on the
+    worker before the run slot is granted and hands them to
+    ``toArrow``.
+
+    The attribution plane rides the tracer: when attribution is on (the
+    default) the tracer runs even with trace.enabled off, but
+    _record_query only emits the rollup the user asked for — the spans
+    feed the ledger + flight recorder."""
+    from spark_rapids_tpu import conf as C
+    from spark_rapids_tpu.runtime import attribution as attr_mod
+    from spark_rapids_tpu.runtime import trace
+    attr_on = bool(conf.get(C.ATTRIBUTION_ENABLED))
+    tracer = None
+    if conf.get(C.TRACE_ENABLED) or attr_on:
+        tracer = trace.start_query(
+            qid, max_events=int(conf.get(C.QUERY_LOG_MAX_EVENTS)))
+    arec = None
+    if attr_on:
+        arec = attr_mod.start_query(
+            qid, ring_size=int(conf.get(C.ATTRIBUTION_RING_SIZE)))
+        if tracer is not None and arec is not None:
+            tracer.recorder = arec
+    return tracer, arec
+
+
 class Row(tuple):
     """Lightweight pyspark.Row analog: tuple + field access."""
 
@@ -788,7 +818,8 @@ class DataFrame:
     def toArrow(self, timeout_ms: Optional[float] = None,
                 query_id: Optional[int] = None,
                 cancel_token=None,
-                tenant: Optional[str] = None) -> pa.Table:
+                tenant: Optional[str] = None,
+                books=None) -> pa.Table:
         """Execute and return the result as an Arrow table.
 
         ``timeout_ms`` puts an in-process deadline on THIS execution
@@ -803,6 +834,9 @@ class DataFrame:
         *submit* time (so the query is cancellable while still queued
         for a run slot), then the admitted worker passes both here and
         the execution adopts them instead of minting fresh ones.
+        ``books`` is what ``open_books`` returned on the worker thread
+        before the query had its run slot (so that the wait is in
+        them): adopted and closed here like books opened here.
         ``tenant`` folds the tenant's conf overrides into the result
         key so tenants never share a cache slot.
 
@@ -834,22 +868,13 @@ class DataFrame:
                                        f"query-{qid:06d}")
             os.makedirs(profile_dir, exist_ok=True)
             stack.enter_context(jax.profiler.trace(profile_dir))
-        # the attribution plane rides the tracer: when attribution is on
-        # (the default) the tracer runs even with trace.enabled off, but
-        # _record_query only emits the rollup the user asked for — the
-        # spans feed the ledger + flight recorder
         from spark_rapids_tpu.runtime import attribution as attr_mod
-        attr_on = bool(conf.get(C.ATTRIBUTION_ENABLED))
-        tracer = None
-        if conf.get(C.TRACE_ENABLED) or attr_on:
-            tracer = trace.start_query(
-                qid, max_events=int(conf.get(C.QUERY_LOG_MAX_EVENTS)))
-        arec = None
-        if attr_on:
-            arec = attr_mod.start_query(
-                qid, ring_size=int(conf.get(C.ATTRIBUTION_RING_SIZE)))
-            if tracer is not None and arec is not None:
-                tracer.recorder = arec
+        if books is None:
+            tracer, arec = open_books(conf, qid)
+        else:
+            tracer, arec = books
+            if tracer is not None and profile_dir is not None:
+                tracer.mirror = True   # opened before the session above
         # the root opens before planning: the wall the books close on
         # is the caller's, plan included
         root = (tracer.begin("Query", "execute")

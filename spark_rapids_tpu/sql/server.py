@@ -212,18 +212,37 @@ class QueryServer:
         return handle
 
     def _run(self, handle: QueryHandle, query) -> None:
+        from spark_rapids_tpu.runtime import attribution
         from spark_rapids_tpu.runtime import cancel
+        from spark_rapids_tpu.runtime import trace
+        from spark_rapids_tpu.sql.dataframe import open_books
         sched = (self._scheduler if self._scheduler is not None
                  else peek_scheduler())
         t0 = time.monotonic()
+        # the query's books open here, on the thread it will run on and
+        # before it has a run slot: the wait for the slot is in them,
+        # and toArrow adopts them as it adopts the id and the token
+        tracer, arec = open_books(self.session.rapids_conf(),
+                                  handle.query_id)
+        serve = None
         df = None
         try:
-            handle.queue_wait_s = sched.acquire(handle.ticket)
+            with trace.span("QueryServer", "queueWait"):
+                handle.queue_wait_s = sched.acquire(handle.ticket)
+            if tracer is not None:
+                serve = tracer.begin("QueryServer", "serve")
             handle.state = RUNNING
-            df = query() if callable(query) else query
+            if callable(query):
+                # the plan is built here, on the admitted worker: the
+                # first of the query's plan time
+                with trace.span("QueryServer", "buildPlan"):
+                    df = query()
+            else:
+                df = query
             handle.result = df.toArrow(query_id=handle.query_id,
                                        cancel_token=handle.token,
-                                       tenant=handle.tenant)
+                                       tenant=handle.tenant,
+                                       books=(tracer, arec))
             handle.state = OK
         except cancel.QueryCancelled as e:
             handle.error = e
@@ -245,6 +264,13 @@ class QueryServer:
                 self._handles.pop(handle.query_id, None)
             if handle.state == OK:
                 self._record_latency(sched, handle, df)
+            if serve is not None:
+                tracer.end(serve)
+            if tracer is not None and trace.current() is tracer:
+                # toArrow, which closes the books it adopts, never ran
+                # (cancelled while queued, a plan that did not build)
+                trace.end_query(tracer)
+                attribution.end_query(arec)
             handle.done.set()
 
     def _record_latency(self, sched, handle: QueryHandle, df) -> None:
@@ -282,9 +308,9 @@ class QueryServer:
     def _dump_queued_blackbox(self, handle: QueryHandle, exc,
                               t0: float) -> None:
         """Black box for a query killed before admission (deadline or
-        cancel fired while QUEUED): no tracer ever ran, so the ledger
-        is built from the one fact the server owns — the whole wall
-        was queue wait."""
+        cancel fired while QUEUED): ``toArrow`` never ran and closed
+        no ledger, so one is built from the one fact the server owns —
+        the whole wall was queue wait."""
         from spark_rapids_tpu import conf as C
         from spark_rapids_tpu.runtime import attribution
         conf = self.session.rapids_conf()

@@ -267,12 +267,34 @@ def test_ring_is_bounded():
 
 
 def test_nested_query_rides_owner():
+    """Ownership is by thread of execution: a second start on the
+    owner's thread rides it; another thread owns a recorder of its own
+    meanwhile, and an event lands in the ring of the thread that
+    recorded it."""
     rec = attribution.start_query(101, ring_size=32)
     try:
         assert rec is not None
         assert attribution.start_query(102) is None
         attribution.record_event("health", {"check": "x"})
         assert len(rec.snapshot()["events"]) == 1
+        theirs = []
+
+        def other():
+            assert attribution.current() is None
+            mine = attribution.start_query(103, ring_size=32)
+            try:
+                attribution.record_event("health", {"check": "y"})
+                theirs.append(mine)
+            finally:
+                attribution.end_query(mine)
+            assert attribution.current() is None
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive() and theirs[0] is not rec
+        assert [e["check"] for e in theirs[0].snapshot()["events"]] == ["y"]
+        assert [e["check"] for e in rec.snapshot()["events"]] == ["x"]
+        assert attribution.current() is rec
     finally:
         attribution.end_query(rec)
     assert attribution.current() is None

@@ -43,6 +43,12 @@ def test_rehearse_runs_every_phase_on_cpu():
     compares = [ln for ln in lines if ln.get("phase") == "compare"]
     assert len(compares) == 6 and all(c["equal"] for c in compares)
     assert lines[0]["compile_cache_dir"] is None  # off on the CPU
+    # submitted together, each served query closed a ledger of its own
+    served = [ln for ln in lines if ln.get("phase") == "served"
+              and "query" in ln]
+    assert [ln["query"] for ln in served] == ["q6", "q1", "q12"]
+    assert all(ln["books"] == 1 and "queue_wait" in ln["buckets"]
+               for ln in served)
 
 
 def test_refuses_cpu_without_rehearse():

@@ -113,6 +113,33 @@ def test_profile_conf_yields_one_xplane_with_program_spans(tmp_path):
     assert any(n.startswith("tpuq.Kernel.") for n in names)
 
 
+def test_a_served_querys_wait_and_serve_spans_are_on_the_timeline(
+        tmp_path):
+    """``QueryServer:queueWait`` and ``QueryServer:serve`` are opened
+    on the served query's own tracer and mirrored like the rest, under
+    its id; the serve span holds the query's root."""
+    from spark_rapids_tpu.sql.server import QueryServer
+    s = tpu_session({})
+    _agg(s).toArrow()                 # compile outside the trace
+    server = QueryServer(s)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            handle = server.submit(lambda: _agg(s), tenant="t")
+            server.result(handle, timeout_s=120)
+    finally:
+        server.shutdown()
+    mine = {e[0]: e for e in _host_events(str(tmp_path))
+            if e[0].startswith("tpuq.")
+            and e[3].get("query_id") == handle.query_id}
+    assert {"tpuq.QueryServer:queueWait", "tpuq.QueryServer:serve",
+            "tpuq.QueryServer:buildPlan", "tpuq.Query:execute"} <= set(mine)
+    _, w0, w1, _, wline = mine["tpuq.QueryServer:queueWait"]
+    _, s0, s1, _, sline = mine["tpuq.QueryServer:serve"]
+    _, r0, r1, _, rline = mine["tpuq.Query:execute"]
+    assert wline == sline == rline    # the worker thread
+    assert w1 <= s0 <= r0 and r1 <= s1
+
+
 class _CountingAnnotation:
     made = 0
     enabled = False

@@ -83,9 +83,30 @@ def test_plan_signature_is_stable_and_positional():
 
 
 def test_nested_query_rides_owner_collector():
+    """Ownership is by thread of execution: nested on the owner's
+    thread rides it, another thread gets a collector of its own, an
+    unowned thread has none."""
+    import threading
     st = stats.start_query(1)
     try:
         assert stats.start_query(2) is None  # nested: owner keeps it
+        assert stats.current() is st
+        seen = []
+
+        def other():
+            seen.append(stats.current())     # nobody bound this thread
+            mine = stats.start_query(3)
+            seen.append(mine)
+            seen.append(stats.current())
+            stats.end_query(mine)
+            seen.append(stats.current())
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert seen[0] is None and seen[3] is None
+        assert seen[1] is not None and seen[1] is not st
+        assert seen[2] is seen[1] and seen[1].query_id == 3
         assert stats.current() is st
     finally:
         stats.end_query(st)
