@@ -484,8 +484,10 @@ JOIN_TARGET_ROWS = (
          "cannot be spread by any key hash; after bounded recursion "
          "such a pair joins in-core, and the build side of a broadcast "
          "join is bounded by the broadcast byte threshold rather than "
-         "this row cap — its streamed side honors the cap via bounded "
-         "groups). XLA compile cost grows "
+         "this row cap — its streamed side honors the cap by its live "
+         "rows: batches shrunk to their live buckets and joined in-core "
+         "once when the live rows fit, bounded groups only when they "
+         "exceed it). XLA compile cost grows "
          "superlinearly with bucket size, so this bounds cold-compile "
          "time as well as memory. Join outputs are also re-batched to "
          "spark.rapids.tpu.batchRows chunks so downstream kernels never "

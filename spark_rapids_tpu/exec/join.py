@@ -651,21 +651,53 @@ class TpuSortMergeJoinExec(TpuExec):
         # broadcast joins: the broadcast side is threshold-capped and
         # gathered once (re-splitting it per stream partition would
         # repeat identical work P times), but the STREAMED side still
-        # honors the row cap — it needs no hash split, since the other
+        # honors the row cap — by its LIVE rows, as above: a filtered
+        # stream keeps its scan buckets (TPC-H q14 at SF1: 6.3 M slots
+        # holding ~75 k rows), and bounded groups cut by capacity probe
+        # 24 chunks of which 18 hold no row.  Under the cap the in-core
+        # path below shrinks each batch to its live bucket and probes
+        # once; over it the stream needs no hash split, since the other
         # side is fully present: process it in bounded groups, each
         # group's rows decided independently (inner/left/semi/anti)
-        if (not nokey and self.sub_partition_rows and self.broadcast
-                and (sum(b.capacity
-                         for b in (l_list if self.broadcast == "right"
-                                   else r_list))
-                     > self.sub_partition_rows)):
-            yield from self._broadcast_streamed(l_list, r_list, jt, mgr)
-            return
+        held = total
+        if not nokey and self.sub_partition_rows and self.broadcast:
+            right = self.broadcast == "right"
+            stream, bc_list = ((l_list, r_list) if right
+                               else (r_list, l_list))
+            if sum(b.capacity for b in stream) > self.sub_partition_rows:
+                from spark_rapids_tpu.exec.basic import (
+                    _overlapped_live_counts)
+                counts = _overlapped_live_counts(stream)
+                if sum(counts) > self.sub_partition_rows:
+                    self.metric("streamedJoins").add(1)
+                    yield from self._broadcast_streamed(
+                        l_list, r_list, jt, mgr)
+                    return
+                self.metric("liveRowInCoreJoins").add(1)
+                buckets = [min(b.capacity, round_up_pow2(n, 8))
+                           for b, n in zip(stream, counts)]
+                if len(stream) == 1:
+                    # the concat hands a lone batch back as it is, at
+                    # its scan bucket: cut it to its live bucket here
+                    # (compacted, so the live rows are a prefix)
+                    from spark_rapids_tpu.parallel.shuffle import (
+                        slice_batch)
+                    stream[0] = slice_batch(stream[0], 0, buckets[0])
+                if right:
+                    l_counts = counts
+                else:
+                    r_counts = counts
+                # reserve what the concat will hold (live buckets), not
+                # scan capacity: slots that are never gathered must not
+                # send a thinly live stream to the hash split
+                held = (sum(b.nbytes() for b in bc_list)
+                        + sum(b.nbytes() * k // b.capacity
+                              for b, k in zip(stream, buckets)))
         try:
             # in-core: both sides + the expanded output live together
             # (counts, when the proactive check measured them, save the
             # concat its own sync round trip)
-            with mgr.transient(2 * total):
+            with mgr.transient(2 * held):
                 lb = _concat_or_empty(self.children[0].schema, l_list,
                                       counts=l_counts)
                 rb = _concat_or_empty(self.children[1].schema, r_list,
