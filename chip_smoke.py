@@ -16,6 +16,12 @@ op was host-degraded or a breaker tripped, no dispatch ran on the
 ``pallas`` rung, a warm run compiled anything, or a comparison
 differs.  No phase is wrapped so that the run could carry on.
 
+The tables, the queries, the compile counts and the comparison are the
+benchmark's own (``benchmark/tpch_gen.py``, ``queries/q6|q1|q12.py`` with
+the first binding each draws from ``--seed``, ``counters.py``,
+``compare.py``): bring-up is proved on the data and plans the cells of
+``BENCHMARK.json`` measure.
+
 ``--chips 4`` runs ONLY the multi-chip path (the join query under
 ``spark.rapids.shuffle.mode=ICI`` across four devices) and the same
 query on one device as what it is compared with.
@@ -33,12 +39,17 @@ import sys
 import time
 import traceback
 
+# benchmark/ is no package: its modules are found as run.py finds them
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "benchmark"))
+import compare  # noqa: E402
+import counters  # noqa: E402
+import tpch_gen  # noqa: E402
+from run import load_module  # noqa: E402
+
 RTOL = 1e-9  # README "Numerics": doubles are not bit-exact on the chip
-# The join query of the default run.  q3 (two joins + aggregate +
-# top-N) compiles 183 XLA programs on a cold v5e, which leaves the
-# 1200 s limit of the default run too little margin; q12 is the
-# one-join substitute.  ``--join-query q3`` still runs q3.
-JOIN_QUERY = "q12"
+JOIN_QUERY = "q12"  # the join server.throughput runs
+QUERIES = ("q6", "q1", JOIN_QUERY)
 TENANTS = ("tenant_a", "tenant_b")
 # the conf a user on a TPU gets: `auto` picks every backend
 DEFAULT_CONF = {"spark.rapids.sql.enabled": True}
@@ -55,64 +66,6 @@ def emit(phase: str, **kw) -> None:
 def require(cond, msg: str) -> None:
     if not cond:
         raise SmokeFailure(msg)
-
-
-# ---------------------------------------------------------------------------
-# counters: everything a phase reports is a delta of these
-# ---------------------------------------------------------------------------
-
-class XlaCounts:
-    """jax's own compile events: requests that consulted the persistent
-    cache, hits read back from it, and compiles the backend really ran."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.requests = self.hits = self.compiles = 0
-        self.compile_s = 0.0
-        mon.register_event_listener(self._on_event)
-        mon.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_event(self, name, **kw):
-        if name == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def _on_duration(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
-
-
-def counters(xla: XlaCounts) -> dict:
-    from spark_rapids_tpu.runtime import resilience
-    from spark_rapids_tpu.runtime import telemetry as TM
-    from spark_rapids_tpu.runtime.kernel_cache import compile_snapshot
-    kc, ks = compile_snapshot()
-    res = resilience.counters_snapshot()
-    return {
-        "kernel_compiles": kc, "kernel_compile_s": ks,
-        "xla_compile_requests": xla.requests,
-        "persistent_cache_hits": xla.hits,
-        "xla_compiles": xla.compiles, "xla_compile_s": xla.compile_s,
-        "dispatch_by_backend": TM.REGISTRY.labeled_counter(
-            "tpuq_kernel_dispatch_total", label="backend").child_values(),
-        "ladder_descents": TM.REGISTRY.labeled_counter(
-            "tpuq_kernel_fallback_total", label="kernel").child_values(),
-        "host_degraded_ops": res["host_degraded_ops"],
-        "breaker_trips": res["breaker_trips"],
-    }
-
-
-def delta(after: dict, before: dict) -> dict:
-    out = {}
-    for k, v in after.items():
-        if isinstance(v, dict):
-            d = {lk: lv - before[k].get(lk, 0) for lk, lv in v.items()}
-            out[k] = {lk: int(lv) for lk, lv in d.items() if lv}
-        else:
-            out[k] = v - before[k]
-    return out
 
 
 def memory_stat(devices, key: str) -> list:
@@ -132,45 +85,14 @@ def live_array_bytes(devices) -> list:
     return [held[d.id] for d in devices]
 
 
-# ---------------------------------------------------------------------------
-# comparison: keys, counts and row order exact; doubles at RTOL, ±0.0 equal
-# ---------------------------------------------------------------------------
-
-def compare_tables(got, want) -> dict:
+def bind(name: str, seed: int):
+    """``build(session, tables)`` of ``benchmark/queries/<name>.py`` with
+    the first binding it draws from the seed."""
     import numpy as np
-    import pyarrow as pa
-    diffs = []
-    max_rel = 0.0
-    if got.column_names != want.column_names:
-        diffs.append(f"columns {got.column_names} != {want.column_names}")
-    elif got.num_rows != want.num_rows:
-        diffs.append(f"rows {got.num_rows} != {want.num_rows}")
-    else:
-        for name in want.column_names:
-            g, w = got.column(name), want.column(name)
-            if not pa.types.is_floating(w.type):
-                if not g.equals(w):
-                    diffs.append(f"{name}: exact column differs")
-                continue
-            if g.is_valid().to_pylist() != w.is_valid().to_pylist():
-                diffs.append(f"{name}: null positions differ")
-                continue
-            gv = g.to_numpy(zero_copy_only=False).astype(np.float64)
-            wv = w.to_numpy(zero_copy_only=False).astype(np.float64)
-            live = ~np.isnan(wv)
-            if not np.array_equal(np.isnan(gv), ~live):
-                diffs.append(f"{name}: NaN positions differ")
-                continue
-            # -0.0 == 0.0 under plain subtraction: ±0.0 compare equal;
-            # against an exact zero the error counts absolutely
-            mag = np.abs(wv[live])
-            rel = np.abs(gv[live] - wv[live]) / np.where(mag > 0, mag, 1.0)
-            if rel.size:
-                max_rel = max(max_rel, float(rel.max()))
-                if float(rel.max()) > RTOL:
-                    diffs.append(f"{name}: max rel err {rel.max()!r}")
-    return {"equal": not diffs, "rows": want.num_rows,
-            "max_rel_err": max_rel, "diffs": diffs}
+    q = load_module("queries", name)
+    b = q.draw_bindings(np.random.default_rng(seed), 1)[0]
+    emit("binding", query=name, **b)
+    return lambda session, tables: q.build(session, tables, b)
 
 
 # ---------------------------------------------------------------------------
@@ -197,32 +119,30 @@ def phase_device(conf: dict, devices, info: dict) -> None:
 
 
 def phase_data(sf: float, seed: int) -> dict:
-    import bench
     t0 = time.monotonic()
-    tables = bench.gen_tpch(sf, seed)
+    tables = tpch_gen.gen_tables(sf, seed)
     emit("data", sf=sf, seed=seed, gen_s=time.monotonic() - t0,
          rows={k: t.num_rows for k, t in tables.items()},
          arrow_bytes=sum(t.nbytes for t in tables.values()))
     return tables
 
 
-def run_direct(session, name: str, tables, xla: XlaCounts, phase="direct"):
+def run_direct(session, name: str, build, tables, xla, phase="direct"):
     """Cold then warm ``toArrow()`` of one query; returns the cold
     result and the DataFrame (its plan stays alive with it)."""
-    import bench
-    df = bench.TPCH_BUILDERS[name](session, tables)
-    c0 = counters(xla)
+    df = build(session, tables)
+    c0 = counters.snapshot(xla)
     t0 = time.monotonic()
     cold = df.toArrow()
     cold_s = time.monotonic() - t0
-    c1 = counters(xla)
+    c1 = counters.snapshot(xla)
     t0 = time.monotonic()
     warm = df.toArrow()
     warm_s = time.monotonic() - t0
-    c2 = counters(xla)
-    dc, dw = delta(c1, c0), delta(c2, c1)
+    c2 = counters.snapshot(xla)
+    dc, dw = counters.delta(c1, c0), counters.delta(c2, c1)
     fb = df.fallback_summary()
-    both = delta(c2, c0)
+    both = counters.delta(c2, c0)
     rec = {
         "query": name, "cold_s": cold_s, "warm_s": warm_s,
         "kernel_compiles_cold": dc["kernel_compiles"],
@@ -254,20 +174,19 @@ def run_direct(session, name: str, tables, xla: XlaCounts, phase="direct"):
     return cold, df
 
 
-def phase_served(session, names, tables, xla: XlaCounts) -> dict:
+def phase_served(session, builders: dict, tables, xla) -> dict:
     """The same queries through the serving front door, submitted
     together under two tenants."""
-    import bench
     from spark_rapids_tpu.runtime import attribution, memory
     from spark_rapids_tpu.sql.server import QueryServer
     server = QueryServer(session)
-    c0 = counters(xla)
+    c0 = counters.snapshot(xla)
     t0 = time.monotonic()
     handles = [
         (name, server.submit(
-            lambda b=bench.TPCH_BUILDERS[name]: b(session, tables),
+            lambda b=build: b(session, tables),
             tenant=TENANTS[i % len(TENANTS)]))
-        for i, name in enumerate(names)]
+        for i, (name, build) in enumerate(builders.items())]
     results = {}
     for name, h in handles:
         results[name] = server.result(h, timeout_s=900)
@@ -287,7 +206,7 @@ def phase_served(session, names, tables, xla: XlaCounts) -> dict:
     wall = time.monotonic() - t0
     stats = server.stats()
     server.shutdown()
-    d = delta(counters(xla), c0)
+    d = counters.delta(counters.snapshot(xla), c0)
     leaks = memory.get_manager().report_leaks()
     emit("served", submits=len(handles), wall_s=wall, leaks=leaks,
          tenants={t: int(s.get("completed", 0)) for t, s in stats.items()},
@@ -302,39 +221,41 @@ def phase_served(session, names, tables, xla: XlaCounts) -> dict:
     return results
 
 
-def phase_compare(names, tables, direct: dict, served: dict) -> float:
+def phase_compare(builders: dict, tables, direct: dict,
+                  served: dict) -> float:
     """Outside any timed window: every result against the CPU twin."""
-    import bench
     from spark_rapids_tpu.sql.session import TpuSession
     twin = TpuSession({"spark.rapids.sql.enabled": False})
     worst = 0.0
-    for name in names:
+    for name, build in builders.items():
         t0 = time.monotonic()
-        want = bench.TPCH_BUILDERS[name](twin, tables).toArrow()
+        want = build(twin, tables).toArrow()
         ref_s = time.monotonic() - t0
         for kind, got in (("direct", direct[name]), ("served", served[name])):
-            cmp_ = compare_tables(got, want)
+            c = compare.compare_tables(got, want)
             emit("compare", query=name, against="cpu_twin", result_of=kind,
-                 reference_s=ref_s, rtol=RTOL, **cmp_)
-            require(cmp_["equal"],
-                    f"{name} ({kind}) differs from the CPU twin: "
-                    f"{cmp_['diffs']}")
-            worst = max(worst, cmp_["max_rel_err"])
+                 reference_s=ref_s, rtol=RTOL, rows=want.num_rows, **c)
+            # keys, counts, row order and null positions exact, doubles
+            # at RTOL
+            require(c["exact_mismatches"] == 0 and c["max_rel_err"] <= RTOL,
+                    f"{name} ({kind}) differs from the CPU twin: {c}")
+            worst = max(worst, c["max_rel_err"])
     return worst
 
 
 def run_one_chip(args, session, devices, tables, xla, t_start) -> None:
-    names = ["q6", "q1", args.join_query]
-    direct = {n: run_direct(session, n, tables, xla)[0] for n in names}
-    served = phase_served(session, names, tables, xla)
-    total = counters(xla)
+    builders = {n: bind(n, args.seed) for n in QUERIES}
+    direct = {n: run_direct(session, n, b, tables, xla)[0]
+              for n, b in builders.items()}
+    served = phase_served(session, builders, tables, xla)
+    total = counters.snapshot(xla)
     pallas = int(total["dispatch_by_backend"].get("pallas", 0))
     emit("kernels", rungs_used={k: int(v) for k, v in
                                 total["dispatch_by_backend"].items()},
          pallas_dispatches=pallas)
     if not args.rehearse:
         require(pallas > 0, "no dispatch ran on the pallas rung")
-    worst = phase_compare(names, tables, direct, served)
+    worst = phase_compare(builders, tables, direct, served)
     emit("summary", wall_s=time.monotonic() - t_start,
          kernel_compiles=total["kernel_compiles"],
          kernel_compile_s=total["kernel_compile_s"],
@@ -379,8 +300,8 @@ def run_multi_chip(args, devices, info, tables, xla, t_start) -> None:
     phase_device(ici_conf, devices, info)
     ici_bytes = TM.REGISTRY.counter("tpuq_ici_exchange_bytes_total")
     b0 = ici_bytes.value
-    name = args.join_query
-    got, df = run_direct(ici, name, tables, xla, phase="ici")
+    name, build = JOIN_QUERY, bind(JOIN_QUERY, args.seed)
+    got, df = run_direct(ici, name, build, tables, xla, phase="ici")
     planned, executed, kinds = ici_exchanges(df._last_plan)
     live = live_array_bytes(devices)
     emit("ici_placement", when="after_query_plan_alive",
@@ -399,19 +320,19 @@ def run_multi_chip(args, devices, info, tables, xla, t_start) -> None:
          bytes_in_use=memory_stat(devices, "bytes_in_use"),
          live_array_bytes=live_array_bytes(devices))
     one = TpuSession(DEFAULT_CONF)
-    want, _ = run_direct(one, name, tables, xla, phase="one_device")
-    cmp_ = compare_tables(got, want)
-    emit("compare", query=name, against="one_device",
-         result_of="ici", rtol=RTOL, **cmp_)
-    require(cmp_["equal"], f"ICI result differs from one device: "
-                           f"{cmp_['diffs']}")
-    total = counters(xla)
+    want, _ = run_direct(one, name, build, tables, xla, phase="one_device")
+    c = compare.compare_tables(got, want)
+    emit("compare", query=name, against="one_device", result_of="ici",
+         rtol=RTOL, rows=want.num_rows, **c)
+    require(c["exact_mismatches"] == 0 and c["max_rel_err"] <= RTOL,
+            f"ICI result differs from one device: {c}")
+    total = counters.snapshot(xla)
     emit("summary", wall_s=time.monotonic() - t_start,
          kernel_compile_s=total["kernel_compile_s"],
          xla_compile_requests=total["xla_compile_requests"],
          persistent_cache_hits=total["persistent_cache_hits"],
          xla_compiles=total["xla_compiles"],
-         max_rel_err=cmp_["max_rel_err"],
+         max_rel_err=c["max_rel_err"],
          peak_bytes_in_use=memory_stat(devices, "peak_bytes_in_use"))
 
 
@@ -437,7 +358,7 @@ def run(args) -> dict:
             f"--chips {args.chips} but jax sees {len(devices)} device(s)")
     devices = devices[:args.chips] if args.chips > 1 else devices
     from spark_rapids_tpu.sql.session import TpuSession
-    xla = XlaCounts()
+    xla = counters.XlaCounts()
     sf = args.sf if args.sf is not None else (0.01 if args.rehearse else 1.0)
     if not args.rehearse:
         require(sf >= 1.0, "the chip run is sized at SF1; --sf below 1 "
@@ -459,9 +380,6 @@ def main(argv=None) -> int:
     ap.add_argument("--sf", type=float, default=None,
                     help="TPC-H scale factor (default 1.0; 0.01 with "
                          "--rehearse)")
-    ap.add_argument("--join-query", default=JOIN_QUERY,
-                    choices=("q3", "q12"),
-                    help="the join query run after q6 and q1")
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: only the ICI path and its one-device twin")
     ap.add_argument("--rehearse", action="store_true",

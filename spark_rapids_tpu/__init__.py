@@ -17,8 +17,8 @@ Layer map (mirrors SURVEY.md §1):
 * ``runtime/``  — L2: device manager, semaphore, spill, OOM-retry
 * ``columnar/`` — L2: column/batch data model (static-shape, bucketed)
 * ``parallel/`` — mesh/collective layer (ICI/DCN)
-* ``sql/``      — L7: DataFrame/SQL user API (benchmark pipelines live
-  in ``bench.py`` at the repo root — TPC-H through the public API)
+* ``sql/``      — L7: DataFrame/SQL user API (the measured pipelines are
+  ``benchmark/queries/`` — TPC-H through the public API)
 
 Reference parity citations use the form ``[REF: <upstream path> :: <Symbol>]``
 per SURVEY.md (the reference mount was empty; citations are upstream search

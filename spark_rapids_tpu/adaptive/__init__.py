@@ -7,9 +7,9 @@ sizes with skew factors keyed by stable plan signatures), and this
 package is the half that SPENDS those stats: a cost model + replanner
 that rewrites the physical plan at stage boundaries.
 
-Three decisions, each conf-gated under ``spark.rapids.tpu.adaptive.*``:
+Three decisions under ``spark.rapids.tpu.adaptive.enabled``:
 
-* **join strategy** (``joinStrategy.enabled``) — broadcast vs
+* **join strategy** (on with the plane) — broadcast vs
   shuffled-hash per join from observed build-side cardinality:
   profile-store history for warm queries, upstream pump counts for
   cold ones.  A build side that fits the broadcast threshold
@@ -21,7 +21,7 @@ Three decisions, each conf-gated under ``spark.rapids.tpu.adaptive.*``:
   build side's matching partition (exec/join.py partitioned
   ``TpuSortMergeJoinExec``).  This spreads a SINGLE hot key — the one
   case hash sub-partitioning provably cannot.
-* **batch retargeting** (``batchRetarget.enabled``) — the AQE shuffle
+* **batch retargeting** (on with the plane) — the AQE shuffle
   read replans its coalesce/split target from observed bytes/row
   instead of the static schema estimate, snapped to the shape plane's
   bucket ladder (exec/aqe.py).
@@ -61,9 +61,9 @@ class AdaptivePolicy:
     every other planner input."""
 
     enabled: bool = False
-    join_strategy: bool = True
+    join_strategy: bool = True         # no conf key: on with the plane
     skew_split: bool = True
-    batch_retarget: bool = True
+    batch_retarget: bool = True        # no conf key: on with the plane
     skew_threshold: float = 2.0        # hottest/mean, resolved (never 0)
     max_splits: int = 8                # fan-out cap per hot partition
     target_rows: int = 1 << 18         # sub-partition row goal
@@ -92,9 +92,7 @@ def policy_from_conf(conf) -> AdaptivePolicy:
     thresh = conf.get(C.BROADCAST_THRESHOLD)
     return AdaptivePolicy(
         enabled=bool(conf.get(C.ADAPTIVE_PLANE_ENABLED)),
-        join_strategy=bool(conf.get(C.ADAPTIVE_JOIN_STRATEGY)),
         skew_split=bool(conf.get(C.ADAPTIVE_SKEW_SPLIT)),
-        batch_retarget=bool(conf.get(C.ADAPTIVE_BATCH_RETARGET)),
         skew_threshold=skew,
         max_splits=int(conf.get(C.ADAPTIVE_MAX_SPLITS)),
         target_rows=int(conf.get(C.JOIN_TARGET_ROWS)),

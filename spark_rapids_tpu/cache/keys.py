@@ -62,9 +62,8 @@ def _result_conf_entries():
         C.KERNEL_BACKEND, C.KERNEL_BUCKETING, C.KERNEL_BUCKET_LADDER,
         C.KERNEL_MAX_PAD_FRACTION,
         C.ADAPTIVE_ENABLED, C.ADAPTIVE_PLANE_ENABLED,
-        C.ADAPTIVE_JOIN_STRATEGY, C.ADAPTIVE_SKEW_SPLIT,
-        C.ADAPTIVE_SKEW_THRESHOLD, C.ADAPTIVE_MAX_SPLITS,
-        C.ADAPTIVE_BATCH_RETARGET,
+        C.ADAPTIVE_SKEW_SPLIT, C.ADAPTIVE_SKEW_THRESHOLD,
+        C.ADAPTIVE_MAX_SPLITS,
     )
 
 

@@ -317,16 +317,6 @@ RETRY_BACKOFF_MAX_MS = (
     .create_with_default(1000.0)
 )
 
-RETRY_JITTER_SEED = (
-    conf("spark.rapids.tpu.retry.jitterSeed")
-    .doc("Seed for the retry policy's backoff jitter. Jitter is a pure "
-         "function of (seed, domain, attempt), so a run is exactly "
-         "reproducible under the same seed.")
-    .category("memory")
-    .integer()
-    .create_with_default(0)
-)
-
 RETRY_BUDGET_PER_QUERY = (
     conf("spark.rapids.tpu.retry.budgetPerQuery")
     .doc("Total retries one query may spend across every failure domain "
@@ -375,19 +365,6 @@ EXCHANGE_MODE = (
     .check(lambda v: v.lower() in ("compiled", "host", "auto"),
            "one of compiled, host, auto")
     .create_with_default("auto")
-)
-
-EXCHANGE_DONATE = (
-    conf("spark.rapids.tpu.exchange.donate")
-    .doc("Donate the sharded stage-input buffers to the compiled "
-         "exchange's boundary program, so the wire consumes them "
-         "instead of holding input and output co-resident in HBM. "
-         "Disable to keep inputs alive through the collective (e.g. "
-         "when debugging a mid-collective fault, at ~2x the exchange "
-         "working set).")
-    .category("shuffle")
-    .boolean()
-    .create_with_default(True)
 )
 
 SHUFFLE_THREADS = (
@@ -651,20 +628,6 @@ ADAPTIVE_PLANE_ENABLED = (
     .create_with_default(False)
 )
 
-ADAPTIVE_JOIN_STRATEGY = (
-    conf("spark.rapids.tpu.adaptive.joinStrategy.enabled")
-    .doc("Adaptive join strategy selection: pick broadcast vs "
-         "shuffled-hash per join from OBSERVED build-side cardinality — "
-         "profile-store history for warm queries (adaptive.historyPath), "
-         "upstream pump counts for cold ones — instead of the static "
-         "planner estimate.  A build side that fits "
-         "spark.sql.autoBroadcastJoinThreshold eliminates the exchange "
-         "entirely.  Requires adaptive.enabled.")
-    .category("aqe")
-    .boolean()
-    .create_with_default(True)
-)
-
 ADAPTIVE_SKEW_SPLIT = (
     conf("spark.rapids.tpu.adaptive.skewSplit.enabled")
     .doc("Adaptive skew splitting: when a shuffle exchange's recorded "
@@ -701,19 +664,6 @@ ADAPTIVE_MAX_SPLITS = (
     .integer()
     .check(lambda v: v >= 2, "at least 2")
     .create_with_default(8)
-)
-
-ADAPTIVE_BATCH_RETARGET = (
-    conf("spark.rapids.tpu.adaptive.batchRetarget.enabled")
-    .doc("Dynamic batch retargeting: the AQE shuffle read plans its "
-         "coalesce/split row target from the OBSERVED bytes/row of the "
-         "exchange input (stats plane) instead of the static schema "
-         "estimate, then snaps it to the shape plane's bucket ladder — "
-         "variable-width columns stop under/over-filling read batches "
-         "mid-query.  Requires adaptive.enabled.")
-    .category("aqe")
-    .boolean()
-    .create_with_default(True)
 )
 
 ADAPTIVE_HISTORY_PATH = (
@@ -815,16 +765,6 @@ QUERY_LOG_PATH = (
     .create_with_default("")
 )
 
-QUERY_LOG_MAX_EVENTS = (
-    conf("spark.rapids.sql.queryLog.maxEvents")
-    .doc("Span cap per traced query; spans beyond the cap are counted as "
-         "dropped rather than recorded (bounds tracer memory on "
-         "pathological plans).")
-    .integer()
-    .check(lambda v: v > 0, "positive")
-    .create_with_default(100000)
-)
-
 STATS_ENABLED = (
     conf("spark.rapids.tpu.stats.enabled")
     .doc("Per-operator runtime statistics (the stats plane): every exec "
@@ -881,7 +821,7 @@ ATTRIBUTION_ENABLED = (
          "exclusive buckets (queue wait, semaphore wait, compile, kernel "
          "dispatch, exchange collectives, host shuffle, spill/restore "
          "I/O, cache, pump idle, host fallback) that sum to the query's "
-         "end-to-end wall time within closeTolerance, with any gap "
+         "end-to-end wall time within 10 %, with any gap "
          "reported explicitly as unaccounted. Also arms the flight "
          "recorder: a bounded ring of recent spans/health/retry/cancel "
          "events dumped atomically as query-<id>.blackbox.json when a "
@@ -893,29 +833,6 @@ ATTRIBUTION_ENABLED = (
     .create_with_default(True)
 )
 
-ATTRIBUTION_RING_SIZE = (
-    conf("spark.rapids.tpu.attribution.ringSize")
-    .doc("Flight-recorder ring capacity: the last N closed spans and the "
-         "last N health/retry/cancel events are retained per query "
-         "(oldest evicted first) and shipped in the black box.")
-    .category("observability")
-    .integer()
-    .check(lambda v: v > 0, "positive")
-    .create_with_default(256)
-)
-
-ATTRIBUTION_CLOSE_TOLERANCE = (
-    conf("spark.rapids.tpu.attribution.closeTolerance")
-    .doc("Fraction of end-to-end wall time the unaccounted remainder may "
-         "reach before the attribution is reported as NOT CLOSED (the "
-         "gap is always reported either way, never absorbed into "
-         "another bucket).")
-    .category("observability")
-    .double()
-    .check(lambda v: 0.0 < v <= 1.0, "in (0, 1]")
-    .create_with_default(0.10)
-)
-
 ATTRIBUTION_BLACKBOX_PATH = (
     conf("spark.rapids.tpu.attribution.blackboxPath")
     .doc("Directory for flight-recorder dumps "
@@ -924,16 +841,6 @@ ATTRIBUTION_BLACKBOX_PATH = (
     .category("observability")
     .string()
     .create_with_default("/tmp/tpuq-blackbox")
-)
-
-ATTRIBUTION_BLACKBOX_MAX = (
-    conf("spark.rapids.tpu.attribution.blackboxMaxDumps")
-    .doc("Cap on black-box files kept in blackboxPath; when a new dump "
-         "would exceed it the oldest files are evicted first.")
-    .category("observability")
-    .integer()
-    .check(lambda v: v > 0, "positive")
-    .create_with_default(64)
 )
 
 QUERY_TIMEOUT_MS = (
@@ -1234,7 +1141,7 @@ EXEC_PUMP_DEPTH = (
          "JAX async dispatch overlaps the producer's H2D/compute with "
          "the consumer's compute/D2H. 1 disables prefetch. Bounded "
          "small on purpose — holding all outputs alive costs ~60% "
-         "exchange bandwidth (utils/exchange_bench.py).")
+         "exchange bandwidth (utils.exchange_bench).")
     .category("kernel")
     .integer()
     .check(lambda v: 1 <= int(v) <= 8, "in [1, 8]")
@@ -1476,21 +1383,6 @@ SCHED_PREEMPT_MIN_RUN_MS = (
     .create_with_default(250)
 )
 
-SCHED_QUEUE_SHAPING = (
-    conf("spark.rapids.tpu.scheduler.queueShaping")
-    .doc("Derive each tenant's EFFECTIVE queued-query cap from its "
-         "fair-share weight (ceil(weight/totalWeight * "
-         "maxQueuedQueries), further capped by tenant.<name>.maxQueued) "
-         "instead of the static tenantMaxQueued alone. Stops one hot "
-         "tenant's standing queue from monopolising the global queue "
-         "budget and burying other tenants' latency behind it; "
-         "submissions beyond the shaped cap are rejected with "
-         "QueryRejected(reason='tenant_queue_full').")
-    .category("scheduler")
-    .boolean()
-    .create_with_default(True)
-)
-
 SCHED_TENANT_SLO_P99_MS = (
     conf("spark.rapids.tpu.scheduler.tenantSloP99Ms")
     .doc("Default per-tenant p99 submit-to-completion latency SLO in "
@@ -1539,33 +1431,6 @@ TENANCY_ENABLED = (
     .category("scheduler")
     .boolean()
     .create_with_default(False)
-)
-
-TENANCY_SUSPEND_TTL_MS = (
-    conf("spark.rapids.tpu.tenancy.suspendTtlMs")
-    .doc("Lease on a remotely-directed suspension: a suspend directive "
-         "must be renewed (re-issued by the coordinator on a later "
-         "heartbeat) within this long or the token force-resumes "
-         "itself — the wedge guard for executor loss / coordinator "
-         "restart mid-suspend. 0 derives the TTL as 2x "
-         "scheduler.preempt.graceMs.")
-    .category("scheduler")
-    .integer()
-    .check(lambda v: v >= 0, "non-negative")
-    .create_with_default(0)
-)
-
-TENANCY_DEGRADED_AFTER = (
-    conf("spark.rapids.tpu.tenancy.degradedAfterMisses")
-    .doc("After this many consecutive heartbeat failures the "
-         "TenancyAgent drops to local-only enforcement (counted in "
-         "tpuq_tenancy_degraded_total) until a heartbeat round-trips "
-         "again, at which point it re-syncs its suspended-query state "
-         "with the (possibly restarted) coordinator.")
-    .category("scheduler")
-    .integer()
-    .check(lambda v: v > 0, "positive")
-    .create_with_default(2)
 )
 
 

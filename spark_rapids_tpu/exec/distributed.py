@@ -58,15 +58,6 @@ _TM_ICI_EX_COLL_S = TM.REGISTRY.counter(
     "compiled-exchange boundary-program dispatch seconds")
 
 
-def exchange_opts(conf) -> dict:
-    """Conf-derived ICI-exchange constructor kwargs — every plan-time
-    construction site passes these through, so runtime behavior
-    (buffer donation) follows the session conf without each site
-    re-reading it."""
-    from spark_rapids_tpu import conf as C
-    return {"donate": bool(conf.get(C.EXCHANGE_DONATE))}
-
-
 def owned_partitions(plan) -> List[int]:
     """Partitions an executor process serves of ``plan``: descend the
     partition-preserving spine to the nearest ICI exchange and take its

@@ -327,12 +327,11 @@ def _tag_exchange(meta):
 def _convert_exchange(cpu, ch, conf):
     from spark_rapids_tpu import conf as C
     from spark_rapids_tpu.exec.distributed import (
-        TpuIciShuffleExchangeExec, exchange_opts, ici_active)
+        TpuIciShuffleExchangeExec, ici_active)
     if ici_active(conf) and cpu.keys:
         import jax
         if cpu.nparts == jax.device_count():
-            return TpuIciShuffleExchangeExec(ch[0], cpu.keys,
-                                             **exchange_opts(conf))
+            return TpuIciShuffleExchangeExec(ch[0], cpu.keys)
     host_pinned = (conf.shuffle_mode == "ICI"
                    and conf.exchange_mode == "host")
     if conf.shuffle_mode == "MULTITHREADED" or host_pinned:

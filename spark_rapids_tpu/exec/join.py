@@ -1290,13 +1290,12 @@ class TpuAdaptiveJoinExec(TpuExec):
             else:
                 self.metric("adaptiveShuffledJoins").add(1)
                 self._mode = "shuffled"
-                opts = getattr(self, "_exchange_opts", {})
                 lex = TpuIciShuffleExchangeExec(
                     self.children[0], self.left_keys,
-                    canon_int64=self.canon_int64, **opts)
+                    canon_int64=self.canon_int64)
                 rex = TpuIciShuffleExchangeExec(
                     replay, self.right_keys,
-                    canon_int64=self.canon_int64, **opts)
+                    canon_int64=self.canon_int64)
                 self._inner = TpuSortMergeJoinExec(
                     self.join_type, self.left_keys, self.right_keys,
                     self.condition, self.schema, lex, rex,
@@ -1536,32 +1535,26 @@ def _convert_join(cpu, ch, conf):
         # distributed: co-partition both sides through the ICI exchange
         # on the key hash, then join partition-by-partition (the
         # shuffled-hash-join plan shape [REF: GpuShuffledHashJoinExec])
-        from spark_rapids_tpu.exec.distributed import (
-            TpuIciShuffleExchangeExec, exchange_opts)
+        from spark_rapids_tpu.exec.distributed import TpuIciShuffleExchangeExec
         # both exchanges must agree on pids: widen int-family keys to 64
         # bits whenever the pair's widths differ
         canon = tuple(
             type(le.dtype) is not type(re.dtype)
             and isinstance(le.dtype, _INT_FAMILY)
             for le, re in zip(cpu.left_keys, cpu.right_keys))
-        opts = exchange_opts(conf)
         if (conf.get(C.ADAPTIVE_ENABLED) and thresh and thresh > 0
                 and not multiproc
                 and jt in ("inner", "left", "left_semi", "left_anti")):
             # the planner could not prove the build side small (else
             # the static broadcast above fired) — defer to runtime
-            aj = TpuAdaptiveJoinExec(
+            return TpuAdaptiveJoinExec(
                 jt, cpu.left_keys, cpu.right_keys, cpu.condition,
                 cpu.schema, ch[0], ch[1], thresh, canon, cpu.using,
                 bounds["sub_partition_rows"], bounds["out_batch_rows"])
-            # the runtime decision happens long after conversion: carry
-            # the conf-derived exchange kwargs on the node
-            aj._exchange_opts = opts
-            return aj
         lex = TpuIciShuffleExchangeExec(ch[0], cpu.left_keys,
-                                       canon_int64=canon, **opts)
+                                       canon_int64=canon)
         rex = TpuIciShuffleExchangeExec(ch[1], cpu.right_keys,
-                                       canon_int64=canon, **opts)
+                                       canon_int64=canon)
         return TpuSortMergeJoinExec(cpu.join_type, cpu.left_keys,
                                     cpu.right_keys, cpu.condition,
                                     cpu.schema, lex, rex,

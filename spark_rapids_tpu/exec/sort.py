@@ -270,12 +270,11 @@ def _tag_sort(meta):
 
 def _convert_sort(cpu, ch, conf):
     from spark_rapids_tpu.exec.distributed import (
-        TpuIciRangeExchangeExec, exchange_opts, ici_active)
+        TpuIciRangeExchangeExec, ici_active)
     if ici_active(conf):
         # distributed total order: range exchange (sampled boundaries)
         # + per-partition local sort; ascending partition index IS the
         # global order [REF: GpuRangePartitioning.scala]
-        ex = TpuIciRangeExchangeExec(ch[0], cpu.orders,
-                                     **exchange_opts(conf))
+        ex = TpuIciRangeExchangeExec(ch[0], cpu.orders)
         return TpuSortExec(cpu.orders, ex, partitioned=True)
     return TpuSortExec(cpu.orders, ch[0])

@@ -901,16 +901,14 @@ def _tag_window(meta):
 
 def _convert_window(cpu: CpuWindowExec, ch, conf):
     from spark_rapids_tpu.exec.distributed import (
-        TpuIciShuffleExchangeExec, exchange_opts, hashable_on_device,
-        ici_active)
+        TpuIciShuffleExchangeExec, hashable_on_device, ici_active)
     if (ici_active(conf) and cpu.partition_by
             and all(hashable_on_device(e.dtype)
                     for e in cpu.partition_by)):
         # distributed: hash-exchange on partition_by — each exchange
         # partition owns disjoint window-partition keys [REF:
         # GpuWindowExec under Spark's required ClusteredDistribution]
-        ex = TpuIciShuffleExchangeExec(ch[0], cpu.partition_by,
-                                       **exchange_opts(conf))
+        ex = TpuIciShuffleExchangeExec(ch[0], cpu.partition_by)
         return TpuWindowExec(cpu.partition_by, cpu.order_by, cpu.fns,
                              cpu.schema, ex, partitioned=True)
     return TpuWindowExec(cpu.partition_by, cpu.order_by, cpu.fns,

@@ -307,16 +307,14 @@ def _convert_aggregate(cpu, ch, conf):
     if ici_active(conf) and cpu.grouping and not has_collect:
         # distributed: {partial agg → hash exchange on keys → final agg}
         # — one SPMD all_to_all per shuffle stage (SURVEY §5.8)
-        from spark_rapids_tpu.exec.distributed import (
-            TpuIciShuffleExchangeExec, exchange_opts)
+        from spark_rapids_tpu.exec.distributed import TpuIciShuffleExchangeExec
         from spark_rapids_tpu.ops.expressions import BoundReference
         partial = TpuHashAggregateExec(cpu.grouping, cpu.fns, None, ch[0],
                                        mode="partial", **tuning)
         partial.schema = partial._buffer_schema()
         keys = [BoundReference(i, g.dtype)
                 for i, g in enumerate(cpu.grouping)]
-        exchange = TpuIciShuffleExchangeExec(partial, keys,
-                                             **exchange_opts(conf))
+        exchange = TpuIciShuffleExchangeExec(partial, keys)
         return TpuHashAggregateExec(cpu.grouping, cpu.fns, cpu.schema,
                                     exchange, mode="final", **tuning)
     return TpuHashAggregateExec(cpu.grouping, cpu.fns, cpu.schema, ch[0],

@@ -17,7 +17,7 @@ Three pieces:
   wait inside a pump task is a wait, not pump time), so the buckets
   are exclusive by construction, sum to <= e2e, and the gap is
   reported explicitly as ``unaccounted`` — never silently absorbed.
-  ``closed`` is the <= ``closeTolerance`` verdict on that gap.
+  ``closed`` is the <= ``CLOSE_TOLERANCE`` verdict on that gap.
 
 * **Flight recorder** (``FlightRecorder``): a bounded ring of the
   query's most recent spans plus health/retry/cancel events, fed from
@@ -174,6 +174,14 @@ BUCKET_PRIORITY: Tuple[str, ...] = (
 # closure slack floor: on sub-100ms queries fixed per-query overheads
 # (plan metric finalize, log append) dominate any percentage
 ABS_CLOSE_SLACK_S = 0.010
+# fraction of the wall the unaccounted remainder may reach before the
+# ledger is reported NOT CLOSED (the gap is reported either way)
+CLOSE_TOLERANCE = 0.10
+# flight-recorder ring: the last N closed spans and the last N
+# health/retry/cancel events a query, shipped in the black box
+RING_SIZE = 256
+# black-box files kept in blackboxPath; the oldest are evicted first
+BLACKBOX_MAX_DUMPS = 64
 
 _TM_UNACCOUNTED = TM.REGISTRY.counter(
     "tpuq_attribution_unaccounted_seconds_total",
@@ -240,7 +248,7 @@ def _project(intervals: List[Tuple[float, float, int]],
 
 def attribute(tracer=None, spans: Optional[Iterable] = None,
               e2e_s: Optional[float] = None,
-              tolerance: float = 0.10,
+              tolerance: float = CLOSE_TOLERANCE,
               extras: Optional[Dict[str, float]] = None
               ) -> Dict[str, Any]:
     """Fold a query's trace spans into the exclusive bucket ledger.
@@ -365,7 +373,7 @@ class FlightRecorder:
     is atomic) — the black box is cheap enough to leave on by
     default."""
 
-    def __init__(self, query_id: int, ring_size: int = 256):
+    def __init__(self, query_id: int, ring_size: int = RING_SIZE):
         self.query_id = query_id
         self.ring_size = max(8, int(ring_size))
         self.t_start = time.perf_counter()
@@ -401,7 +409,7 @@ def current() -> Optional[FlightRecorder]:
 
 
 def start_query(query_id: int,
-                ring_size: int = 256) -> Optional[FlightRecorder]:
+                ring_size: int = RING_SIZE) -> Optional[FlightRecorder]:
     """Install a fresh recorder for the calling thread's query; None
     when the thread already has one (a nested execution rides the
     owner, same as tracing)."""
@@ -454,7 +462,7 @@ def dump_blackbox(dir_path: str, query_id: int, trigger: str,
                   attribution: Optional[Dict[str, Any]] = None,
                   recorder: Optional[FlightRecorder] = None,
                   extra: Optional[Dict[str, Any]] = None,
-                  max_dumps: int = 64) -> Optional[str]:
+                  max_dumps: int = BLACKBOX_MAX_DUMPS) -> Optional[str]:
     """Atomically write ``query-<id>.blackbox.json``.
 
     tmp + ``os.replace`` in the spill-file style (runtime/memory.py,

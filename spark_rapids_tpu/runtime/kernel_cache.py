@@ -194,8 +194,9 @@ def cache_stats() -> Tuple[int,]:
 
 def compile_snapshot() -> Tuple[int, float]:
     """(compile count, compile seconds) observed so far — the
-    before/after pair bench.py and ``session.warmup`` diff to attribute
-    compiles to a phase (cold run, warm run, warmup)."""
+    before/after pair ``session.warmup``, ``chip_smoke.py`` and
+    ``benchmark/counters.py`` diff to attribute compiles to a phase
+    (warmup, cold run, warm run, a cell's window)."""
     return (int(_TM_COMPILES.value), float(_TM_COMPILE_S.value))
 
 

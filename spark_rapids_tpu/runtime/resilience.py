@@ -48,7 +48,7 @@ Three cooperating pieces, all conf-driven:
   (the old per-field fast-path reads could miss a concurrent arm).
 * ``RetryPolicy`` — ``retry.maxAttempts`` attempts with exponential
   backoff (``retry.backoffBaseMs``..``retry.backoffMaxMs``) and
-  deterministic seeded jitter (``retry.jitterSeed``), spending from a
+  deterministic seeded jitter (``JITTER_SEED``), spending from a
   per-query retry budget (``retry.budgetPerQuery``).
 * per-op **circuit breakers** — on retry exhaustion in a degradable
   domain the op's breaker trips and the step re-runs on the host path;
@@ -305,26 +305,29 @@ class _QueryState:
 _STATE = _QueryState()
 
 
+# backoff jitter is a pure function of (seed, domain, attempt): a run
+# replays exactly
+JITTER_SEED = 0
+
+
 class RetryPolicy:
     """Conf-driven retry contract every failure domain shares."""
 
     def __init__(self, max_attempts: int = 8,
                  backoff_base_ms: float = 5.0,
                  backoff_max_ms: float = 1000.0,
-                 jitter_seed: int = 0,
                  budget_per_query: int = 64,
                  host_degrade: bool = True):
         self.max_attempts = max(1, int(max_attempts))
         self.backoff_base_ms = float(backoff_base_ms)
         self.backoff_max_ms = float(backoff_max_ms)
-        self.jitter_seed = int(jitter_seed)
         self.budget_per_query = int(budget_per_query)
         self.host_degrade = bool(host_degrade)
 
     def _token(self) -> tuple:
         return (self.max_attempts, self.backoff_base_ms,
-                self.backoff_max_ms, self.jitter_seed,
-                self.budget_per_query, self.host_degrade)
+                self.backoff_max_ms, self.budget_per_query,
+                self.host_degrade)
 
     def backoff_s(self, domain: str, attempt: int) -> float:
         """Exponential backoff with deterministic seeded jitter: a pure
@@ -334,7 +337,7 @@ class RetryPolicy:
             return 0.0
         base = min(self.backoff_base_ms * (2 ** (attempt - 1)),
                    self.backoff_max_ms)
-        rnd = random.Random(f"{self.jitter_seed}:{domain}:{attempt}")
+        rnd = random.Random(f"{JITTER_SEED}:{domain}:{attempt}")
         return base * (0.5 + 0.5 * rnd.random()) / 1000.0
 
     def _retryable(self, domain: str, exc: BaseException) -> bool:
@@ -415,7 +418,6 @@ def configure_policy(conf) -> RetryPolicy:
         max_attempts=conf.get(C.RETRY_MAX),
         backoff_base_ms=conf.get(C.RETRY_BACKOFF_BASE_MS),
         backoff_max_ms=conf.get(C.RETRY_BACKOFF_MAX_MS),
-        jitter_seed=conf.get(C.RETRY_JITTER_SEED),
         budget_per_query=conf.get(C.RETRY_BUDGET_PER_QUERY),
         host_degrade=conf.get(C.RETRY_HOST_DEGRADE),
     )

@@ -111,6 +111,11 @@ CANCELLED = "CANCELLED"
 PRIORITY_MIN = -100
 PRIORITY_MAX = 100
 
+#: a tenant's EFFECTIVE queued cap is its weight share of
+#: maxQueuedQueries (never above its own static maxQueued), so one hot
+#: tenant's standing queue cannot bury the others' latency behind it
+QUEUE_SHAPING = True
+
 #: rejection reasons that mean "the service is overloaded" (counted in
 #: the shed counter + health WARN) as opposed to "this tenant hit its
 #: own quota"
@@ -300,7 +305,6 @@ class QueryScheduler:
                 conf.get(C.SCHED_PREEMPT_GRACE_MS)) / 1000.0
             self.preempt_min_run_s = float(
                 conf.get(C.SCHED_PREEMPT_MIN_RUN_MS)) / 1000.0
-            self.queue_shaping = bool(conf.get(C.SCHED_QUEUE_SHAPING))
             self._default_slo_ms = int(
                 conf.get(C.SCHED_TENANT_SLO_P99_MS))
             self.slo_window = int(conf.get(C.SCHED_SLO_WINDOW))
@@ -318,9 +322,9 @@ class QueryScheduler:
             self.preempt_grace_s = C.SCHED_PREEMPT_GRACE_MS.default / 1000.0
             self.preempt_min_run_s = (
                 C.SCHED_PREEMPT_MIN_RUN_MS.default / 1000.0)
-            self.queue_shaping = C.SCHED_QUEUE_SHAPING.default
             self._default_slo_ms = C.SCHED_TENANT_SLO_P99_MS.default
             self.slo_window = C.SCHED_SLO_WINDOW.default
+        self.queue_shaping = QUEUE_SHAPING
         self._tenants: Dict[str, TenantState] = {}
         self._rr_order: deque = deque()  # round-robin tie-break rotation
         self._tickets: Dict[int, Ticket] = {}
@@ -1016,7 +1020,6 @@ def get_scheduler(conf=None) -> QueryScheduler:
                     conf.get(C.SCHED_PREEMPT_GRACE_MS)) / 1000.0
                 s.preempt_min_run_s = float(
                     conf.get(C.SCHED_PREEMPT_MIN_RUN_MS)) / 1000.0
-                s.queue_shaping = bool(conf.get(C.SCHED_QUEUE_SHAPING))
                 s._default_slo_ms = int(
                     conf.get(C.SCHED_TENANT_SLO_P99_MS))
                 s.slo_window = int(conf.get(C.SCHED_SLO_WINDOW))

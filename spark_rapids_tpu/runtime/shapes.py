@@ -31,8 +31,8 @@ Policies (``spark.rapids.tpu.kernel.bucketing``):
 * ``off``    — pass batches through untouched.
 
 The plane is observable end-to-end: bucket hits/misses and pad-waste
-counters in the telemetry registry, per-op ``padded_rows`` in the stats
-plane, and a cold-vs-warm compile record in bench.py.
+counters in the telemetry registry and per-op ``padded_rows`` in the
+stats plane.
 """
 
 from __future__ import annotations
@@ -168,6 +168,6 @@ def retarget_bucket(rows: int) -> int:
 
 
 def snapshot() -> Tuple[int, int, int, int]:
-    """(hits, misses, pad_rows, pad_bytes) — bench cold/warm deltas."""
+    """(hits, misses, pad_rows, pad_bytes) so far."""
     return (_TM_HITS.value, _TM_MISSES.value,
             _TM_PAD_ROWS.value, _TM_PAD_BYTES.value)

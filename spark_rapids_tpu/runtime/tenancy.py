@@ -21,12 +21,12 @@ Every protocol edge is a failure domain (chaos-injectable as
   no-ops.  A directive racing a cancel always loses: the scheduler's
   ``remote_suspend`` refuses cancelled tokens.
 * **Executor loss / coordinator restart mid-suspend** — a remote
-  suspend is a LEASE (``tenancy.suspendTtlMs``, default 2x
-  ``preempt.graceMs``): the coordinator renews it every heartbeat
+  suspend is a LEASE (``SUSPEND_TTL_GRACES`` x ``preempt.graceMs``):
+  the coordinator renews it every heartbeat
   while warranted; when renewals stop, the token force-resumes itself
   (``tpuq_preempt_force_resumed_total``) and the scheduler's
   accounting follows — a directive can delay work, never wedge it.
-* **Heartbeat flaps** — after ``tenancy.degradedAfterMisses``
+* **Heartbeat flaps** — after ``DEGRADED_AFTER_MISSES``
   consecutive misses the agent drops to local-only enforcement
   (``tpuq_tenancy_degraded_total``); the first heartbeat that
   round-trips again re-syncs (``tpuq_tenancy_resyncs_total``):
@@ -63,6 +63,11 @@ _TM_RESYNC = TM.REGISTRY.counter(
 
 #: bounded memory of applied directive ids (idempotency window)
 _APPLIED_CAP = 512
+# a remotely-directed suspension must be renewed within this many
+# preempt.graceMs or the token force-resumes itself (the wedge guard)
+SUSPEND_TTL_GRACES = 2.0
+# consecutive heartbeat failures before local-only enforcement
+DEGRADED_AFTER_MISSES = 2
 
 
 class TenancyAgent:
@@ -83,15 +88,9 @@ class TenancyAgent:
         self.enabled = (bool(conf.get(C.TENANCY_ENABLED))
                         if conf is not None
                         else bool(C.TENANCY_ENABLED.default))
-        ttl_ms = (float(conf.get(C.TENANCY_SUSPEND_TTL_MS))
-                  if conf is not None
-                  else float(C.TENANCY_SUSPEND_TTL_MS.default))
-        if ttl_ms <= 0:
-            ttl_ms = 2.0 * scheduler.preempt_grace_s * 1000.0
-        self.suspend_ttl_s = max(ttl_ms / 1000.0, 0.001)
-        self.degraded_after = (int(conf.get(C.TENANCY_DEGRADED_AFTER))
-                               if conf is not None
-                               else C.TENANCY_DEGRADED_AFTER.default)
+        self.suspend_ttl_s = max(
+            SUSPEND_TTL_GRACES * scheduler.preempt_grace_s, 0.001)
+        self.degraded_after = DEGRADED_AFTER_MISSES
         self._lock = threading.Lock()
         self._applied: "OrderedDict[str, str]" = OrderedDict()
         self._holds: Dict[int, str] = {}   # query_id -> directive id

@@ -108,6 +108,9 @@ class _NullCtx:
 
 
 _NULL = _NullCtx()
+# span cap a traced query; spans past it are counted as dropped, not
+# recorded (bounds tracer memory on pathological plans)
+MAX_EVENTS = 100_000
 
 
 class Tracer:
@@ -120,7 +123,7 @@ class Tracer:
     self-time.  Spans on a pool thread with no enclosing span start a
     fresh top-level track for that thread."""
 
-    def __init__(self, query_id: int, max_events: int = 100_000,
+    def __init__(self, query_id: int, max_events: int = MAX_EVENTS,
                  mirror: bool = False):
         self.query_id = query_id
         self.max_events = max_events
@@ -238,8 +241,7 @@ def current() -> Optional[Tracer]:
     return BOOKS.tracer
 
 
-def start_query(query_id: int, max_events: int = 100_000
-                ) -> Optional[Tracer]:
+def start_query(query_id: int) -> Optional[Tracer]:
     """Install a fresh tracer for the calling thread's query, whatever
     runs on other threads; returns None when the thread already has an
     owner (the caller is a nested execution and rides it).  Asks the
@@ -247,8 +249,7 @@ def start_query(query_id: int, max_events: int = 100_000
     then mirrors its spans for the whole query, and otherwise never
     makes one."""
     tracer = inflight.install(inflight.TRACER, lambda: Tracer(
-        query_id, max_events=max_events,
-        mirror=bool(TraceAnnotation.is_enabled())))
+        query_id, mirror=bool(TraceAnnotation.is_enabled())))
     if tracer is None:
         TM.BOOKS_RIDDEN.inc()
     return tracer

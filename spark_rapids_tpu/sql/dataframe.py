@@ -39,12 +39,10 @@ def open_books(conf, qid: int):
     attr_on = bool(conf.get(C.ATTRIBUTION_ENABLED))
     tracer = None
     if conf.get(C.TRACE_ENABLED) or attr_on:
-        tracer = trace.start_query(
-            qid, max_events=int(conf.get(C.QUERY_LOG_MAX_EVENTS)))
+        tracer = trace.start_query(qid)
     arec = None
     if attr_on:
-        arec = attr_mod.start_query(
-            qid, ring_size=int(conf.get(C.ATTRIBUTION_RING_SIZE)))
+        arec = attr_mod.start_query(qid)
         if tracer is not None and arec is not None:
             tracer.recorder = arec
     return tracer, arec
@@ -1075,9 +1073,8 @@ class DataFrame:
         attribution = None
         if tracer is not None and conf.get(C.ATTRIBUTION_ENABLED):
             from spark_rapids_tpu.runtime import attribution as attr_mod
-            attribution = attr_mod.publish(attr_mod.attribute(
-                tracer, tolerance=float(
-                    conf.get(C.ATTRIBUTION_CLOSE_TOLERANCE))), tracer)
+            attribution = attr_mod.publish(attr_mod.attribute(tracer),
+                                           tracer)
             entry["attribution"] = attribution
             attr_mod.note_unaccounted(attribution["unaccounted_s"])
         if profile_dir:
@@ -1108,21 +1105,22 @@ class DataFrame:
                 entry["semaphore"] = {
                     "max_holders": sw["max_holders"],
                     "wait_s": round(sw["wait_time"], 6)}
-        if rwin is not None:
-            # retry/breaker/degradation rollup for the query's failure
-            # domains (see runtime/resilience.py)
-            from spark_rapids_tpu.runtime import resilience
-            res = resilience.finish_query(rwin)
-            if res is not None:
-                entry["resilience"] = res
-                # runtime degradations join the plan-time fallback
-                # report: the same "what did NOT run on device" story,
-                # one decided at planning, one at execution
-                if res["degraded_ops"]:
-                    entry.setdefault("fallback_report", []).extend(
-                        f"!{d['op']} degraded to the host path at "
-                        f"runtime [{d['domain']}] because {d['cause']}"
-                        for d in res["degraded_ops"])
+        # retry/breaker/degradation rollup for the query's failure
+        # domains (see runtime/resilience.py).  A query that joined
+        # another's scope (rwin None) closes its share of it too, or
+        # the scope's depth never returns to 0
+        from spark_rapids_tpu.runtime import resilience
+        res = resilience.finish_query(rwin)
+        if res is not None:
+            entry["resilience"] = res
+            # runtime degradations join the plan-time fallback
+            # report: the same "what did NOT run on device" story,
+            # one decided at planning, one at execution
+            if res["degraded_ops"]:
+                entry.setdefault("fallback_report", []).extend(
+                    f"!{d['op']} degraded to the host path at "
+                    f"runtime [{d['domain']}] because {d['cause']}"
+                    for d in res["degraded_ops"])
         if collector is not None:
             # the stats plane's profile record: per-op observed stats
             # keyed by stable plan-node signatures + exchange skew
@@ -1167,8 +1165,7 @@ class DataFrame:
                          if entry.get(k)}
                 path = attr_mod.dump_blackbox(
                     bb_dir, qid, trigger, attribution=attribution,
-                    recorder=recorder, extra=extra,
-                    max_dumps=int(conf.get(C.ATTRIBUTION_BLACKBOX_MAX)))
+                    recorder=recorder, extra=extra)
                 if path:
                     entry["blackbox"] = path
         self._last_query_entry = entry

@@ -302,8 +302,7 @@ class QueryServer:
             bb_dir, handle.query_id, "slo",
             attribution=att or None,
             extra={"status": "ok", "tenant": handle.tenant,
-                   "slo_breach": breach},
-            max_dumps=int(conf.get(C.ATTRIBUTION_BLACKBOX_MAX)))
+                   "slo_breach": breach})
 
     def _dump_queued_blackbox(self, handle: QueryHandle, exc,
                               t0: float) -> None:
@@ -321,17 +320,14 @@ class QueryServer:
             return
         waited = time.monotonic() - t0
         att = attribution.attribute(
-            spans=(), e2e_s=0.0,
-            tolerance=float(conf.get(C.ATTRIBUTION_CLOSE_TOLERANCE)),
-            extras={"queue_wait": waited})
+            spans=(), e2e_s=0.0, extras={"queue_wait": waited})
         trigger = ("timeout" if getattr(exc, "reason", "") == "deadline"
                    else "cancel")
         attribution.dump_blackbox(
             bb_dir, handle.query_id, trigger, attribution=att,
             extra={"status": "cancelled", "tenant": handle.tenant,
                    "cancel": {"reason": getattr(exc, "reason", "user"),
-                              "while": "QUEUED"}},
-            max_dumps=int(conf.get(C.ATTRIBUTION_BLACKBOX_MAX)))
+                              "while": "QUEUED"}})
 
     # -- observation -------------------------------------------------------
 

@@ -1,11 +1,11 @@
-"""Exchange-bandwidth microbench, shared by bench.py and the multichip
-dry-run.
+"""Exchange-bandwidth microbench of the multichip dry-run
+(``__graft_entry__.py``).
 
 Measures the SAME programs the engine's compiled exchange runs
 (parallel/shuffle.py ``build_prepare_program`` / ``build_boundary_program``)
 plus an in-memory floor for the host transport, per partition count —
-so ``BENCH_*`` and ``MULTICHIP_*`` report one consistent trajectory for
-the 0.05 GB/s → compiled-collective gap.
+so every record of it reports one consistent trajectory for the
+0.05 GB/s → compiled-collective gap.
 
 Three numbers per partition count:
 

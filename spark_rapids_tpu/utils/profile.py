@@ -12,10 +12,6 @@ per file:
 * **query event log** (``spark.rapids.sql.queryLog``): JSONL entries
   whose ``op_rollup``/``op_stats``/``telemetry`` fields carry the same
   signals (plus compile counters for the storm report);
-* **bench scoreboard** (``BENCH_*.json``): one JSON object whose
-  ``tpch_sf1_op_rollup``/``tpch_sf1_stats`` maps key per-op records by
-  query name, plus the ``tpch_sf1_compile`` cold-vs-warm compile split
-  the ``storms`` report reads;
 * **black box** (``query-<id>.blackbox.json``): a single flight-
   recorder dump left by a query that died (timeout/cancel/error) —
   ``why`` renders its ledger, verdict and final ring events.
@@ -34,7 +30,7 @@ Usage::
 ``why`` answers "where did this query's wall time go": the attribution
 plane's exclusive bucket ledger rendered as a ranked table with the
 one-line verdict ("exchange-bound: 71% of 23.3 s in
-exchange_collective"), over any of the four inputs — and for a
+exchange_collective"), over any of the three inputs — and for a
 timed-out query, the black box's last spans and cancel/health events.
 
 ``top --adaptive`` additionally lists each query's adaptive-plane
@@ -43,8 +39,7 @@ triggering stat.  ``top --cache`` adds the result-cache report:
 per-signature hit rate, bytes saved, and device-seconds avoided from
 the event log's ``cache`` records.  ``diff`` compares per-op self-times of two runs
 (keys matched by plan signature when both sides have one) and exits
-nonzero when any op regressed by >= the threshold ratio — the bench
-gate's verdict; joins whose adaptive strategy flipped between the two
+nonzero when any op regressed by >= the threshold ratio; joins whose adaptive strategy flipped between the two
 inputs are flagged as ``DECISION FLIP`` (informational).
 """
 
@@ -81,22 +76,16 @@ def _load_json_lines(path: str) -> List[dict]:
 
 
 def detect_kind(records: List[dict]) -> str:
-    """profile-store | event-log | bench | blackbox, from record shape
-    alone."""
+    """profile-store | event-log | blackbox, from record shape alone."""
     if any(r.get("record") == "blackbox" for r in records):
         return "blackbox"
-    if len(records) == 1 and ("tpch_sf1_op_rollup" in records[0]
-                              or "tpch_sf1_stats" in records[0]
-                              or "metric" in records[0]):
-        return "bench"
     if any(r.get("record") == "profile" for r in records):
         return "profile-store"
     if any("op_rollup" in r or "op_stats" in r or "plan" in r
            for r in records):
         return "event-log"
     raise ValueError("unrecognized input: neither a profile store, a "
-                     "query event log, a BENCH_*.json scoreboard, nor "
-                     "a query-*.blackbox.json dump")
+                     "query event log, nor a query-*.blackbox.json dump")
 
 
 def _op_key(rec: dict) -> str:
@@ -122,7 +111,7 @@ def _norm_op(rec: dict) -> dict:
 def load_runs(path: str) -> List[dict]:
     """Normalize any input into runs of shape
     ``{label, ops: {key: oprec}, exchanges: [..], compiles, wall_s}``.
-    One run per query (profile store / event log) or per bench query."""
+    One run per query."""
     if path.endswith(".json"):
         try:
             with open(path) as f:
@@ -145,32 +134,6 @@ def load_runs(path: str) -> List[dict]:
                          "attribution": r.get("attribution"),
                          "blackbox": r,
                          "status": r.get("status")})
-        return runs
-    if kind == "bench":
-        b = records[0]
-        rollups = b.get("tpch_sf1_op_rollup") or {}
-        statses = b.get("tpch_sf1_stats") or {}
-        compile_recs = b.get("tpch_sf1_compile") or {}
-        atts = b.get("tpch_sf1_attribution") or {}
-        boxes = b.get("tpch_sf1_blackbox") or {}
-        for q in sorted(set(rollups) | set(statses) | set(compile_recs)
-                        | set(atts) | set(boxes)):
-            ops: Dict[str, dict] = {}
-            for op, r in (rollups.get(q) or {}).items():
-                ops[f"{q}/{op}"] = {"op": op, "sig": None,
-                                    "self_s": r.get("self_s"),
-                                    "total_s": r.get("total_s")}
-            st = statses.get(q) or {}
-            for rec in st.get("ops") or []:
-                ops[f"{q}/{_op_key(rec)}"] = _norm_op(rec)
-            crec = compile_recs.get(q)
-            runs.append({"label": q, "ops": ops,
-                         "exchanges": (st.get("exchanges") or []),
-                         "compiles": (crec or {}).get("cold_compiles"),
-                         "compile_rec": crec, "wall_s": None,
-                         "decisions": st.get("adaptive_decisions") or [],
-                         "attribution": atts.get(q),
-                         "blackbox": boxes.get(q)})
         return runs
     for r in records:
         if kind == "profile-store":
@@ -451,20 +414,6 @@ def report_storms(runs: List[dict]) -> List[str]:
     lines = [f"compile activity over {len(runs)} run(s):"]
     found = False
     for run in runs:
-        rec = run.get("compile_rec")
-        if rec:
-            # bench scoreboard: cold-vs-warm split from the shape plane
-            found = True
-            warm = rec.get("warm_compiles") or 0
-            flag = "  WARM-PATH COMPILES" if warm else ""
-            lines.append(
-                f"  {run['label']}: cold {rec.get('cold_compiles', 0)} "
-                f"compiles ({rec.get('cold_compile_s', 0.0):.1f}s), "
-                f"warm {warm}, bucketing={rec.get('bucketing')} "
-                f"hits/misses {rec.get('bucket_hits', 0)}/"
-                f"{rec.get('bucket_misses', 0)}, "
-                f"pad {rec.get('pad_rows', 0)} rows{flag}")
-            continue
         storms = [h for h in run.get("health", [])
                   if h.get("check") == "compile_storm"]
         if run.get("compiles") or storms:
@@ -534,7 +483,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m spark_rapids_tpu.utils.profile",
         description="profile reports + regression diff over profile "
-                    "stores, query event logs, and bench scoreboards")
+                    "stores and query event logs")
     sub = p.add_subparsers(dest="cmd", required=True)
     for name, help_ in (("top", "slowest ops by traced self time"),
                         ("why", "attribution verdict: where the wall "

@@ -423,27 +423,22 @@ def test_profile_why_blackbox_of_timed_out_query(tmp_path, capsys):
     assert "collectiveTime" in out
 
 
-def test_profile_why_bench_scoreboard(tmp_path, capsys):
+def test_profile_knows_three_kinds_not_the_old_scoreboard(tmp_path, capsys):
+    """The fourth kind went with the file that wrote it (PR 31): a
+    scoreboard-shaped object is unrecognized input, like any other."""
     from spark_rapids_tpu.utils import profile as P
-    bench = {"metric": "tpch_sf1",
-             "tpch_sf1_attribution": {"q3": _att_fixture()},
-             "tpch_sf1_blackbox": {"q9": {
-                 "record": "blackbox", "trigger": "timeout",
-                 "attribution": _att_fixture(dom="unaccounted"),
-                 "flight_recorder": {"events": [
-                     {"kind": "cancel", "t_s": 1.0,
-                      "reason": "deadline"}]}}}}
-    p = tmp_path / "BENCH_r06.json"
-    p.write_text(json.dumps(bench))
-    rc = P.main(["why", str(p)])
-    out = capsys.readouterr().out
-    assert rc == P.EXIT_OK
-    assert "q3" in out and "q9" in out
-    assert "trigger=timeout" in out
-    # --query filter narrows to one
-    rc = P.main(["why", str(p), "--query", "q3"])
-    out = capsys.readouterr().out
-    assert "q3" in out and "q9" not in out
+    old = {"metric": "tpch_sf1",
+           "tpch_sf1_attribution": {"q3": _att_fixture()},
+           "tpch_sf1_op_rollup": {"q3": {"TpuScanExec": {"self_s": 1.0}}}}
+    with pytest.raises(ValueError, match="unrecognized input"):
+        P.detect_kind([old])
+    p = tmp_path / "scoreboard.json"
+    p.write_text(json.dumps(old))
+    for cmd in ("why", "top", "storms"):
+        with pytest.raises(SystemExit) as e:
+            P.main([cmd, str(p)])
+        assert e.value.code == P.EXIT_BAD_INPUT
+    assert "unrecognized input" in capsys.readouterr().err
 
 
 def test_profile_why_no_attribution_is_bad_input(tmp_path, capsys):

@@ -160,6 +160,26 @@ def test_retry_budget_caps_retries_per_query():
     assert res["retry_exhausted"] >= 1
 
 
+def test_a_query_that_joins_a_resilience_scope_closes_its_share():
+    """A query that runs while another holds the scope (a served
+    neighbour, a nested execution) joins it; when both are done the
+    depth is 0 again, or no later query of the process would own a
+    scope, reset its budget or record its retries (what made the
+    tests above depend on the files run before them)."""
+    from spark_rapids_tpu.runtime import resilience as R
+    from spark_rapids_tpu.utils.harness import tpu_session
+    assert R._STATE.depth == 0
+    outer = R.begin_query(-1)            # the neighbour in flight
+    try:
+        q_agg(tpu_session({})).toArrow()  # joins: its begin returns None
+        assert R._STATE.depth == 1
+    finally:
+        R.finish_query(outer)
+    assert R._STATE.depth == 0
+    rec = run_chaos(q_agg, {"execute": (1, 1)})
+    assert rec["entry"]["resilience"]["retries_total"] == 1
+
+
 # ---------------------------------------------------------------------------
 # distributed domains: rendezvous / peer_loss over the thread-level
 # rendezvous harness (N client threads + a real coordinator)

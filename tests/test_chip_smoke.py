@@ -1,7 +1,7 @@
 """chip_smoke.py on the CPU, and the pieces it leans on that must not
 hide the device: compile-cache placement, HBM budget detection, the
-native library build, the multi-device concat, bench.py's child
-accounting."""
+native library build, the multi-device concat; and that the smoke
+stands on benchmark/'s generator, builders, counters and comparison."""
 
 import json
 import os
@@ -23,8 +23,20 @@ def _run_smoke(*args, **env_extra):
     return r, lines
 
 
-def test_rehearse_runs_every_phase_on_cpu():
-    r, lines = _run_smoke("--rehearse")
+def _same(cmp_line):
+    """A ``compare`` line carries ``compare.compare_tables``' own
+    numbers; the smoke holds them to 0 and to its ``rtol``."""
+    return (cmp_line["exact_mismatches"] == 0
+            and cmp_line["max_rel_err"] <= cmp_line["rtol"])
+
+
+@pytest.fixture(scope="module")
+def rehearsal():
+    return _run_smoke("--rehearse")
+
+
+def test_rehearse_runs_every_phase_on_cpu(rehearsal):
+    r, lines = rehearsal
     assert r.returncode == 0, r.stderr[-2000:]
     assert lines[-1] == {"ok": True, "device": lines[-1]["device"]}
     assert set(lines[-1]["device"]) == {"platform", "kind", "count"}
@@ -41,7 +53,7 @@ def test_rehearse_runs_every_phase_on_cpu():
         assert rec["kernel_compiles_warm"] == 0
         assert rec["xla_compiles_warm"] == 0
     compares = [ln for ln in lines if ln.get("phase") == "compare"]
-    assert len(compares) == 6 and all(c["equal"] for c in compares)
+    assert len(compares) == 6 and all(_same(c) for c in compares)
     assert lines[0]["compile_cache_dir"] is None  # off on the CPU
     # submitted together, each served query closed a ledger of its own
     served = [ln for ln in lines if ln.get("phase") == "served"
@@ -49,6 +61,60 @@ def test_rehearse_runs_every_phase_on_cpu():
     assert [ln["query"] for ln in served] == ["q6", "q1", "q12"]
     assert all(ln["books"] == 1 and "queue_wait" in ln["buckets"]
                for ln in served)
+
+
+def test_rehearsal_runs_the_cells_data_and_queries(rehearsal):
+    """The smoke and the cells of BENCHMARK.json share one generator and
+    one set of builders: the relations are ``tpch_gen.RELATIONS`` and
+    every query phase names q6, q1, q12 with a binding its module drew."""
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import tpch_gen
+    _, lines = rehearsal
+    data = next(ln for ln in lines if ln.get("phase") == "data")
+    assert list(data["rows"]) == list(tpch_gen.RELATIONS)
+    assert data["rows"] == tpch_gen.cardinalities(data["sf"])
+    for phase, n in (("binding", 1), ("direct", 1), ("compare", 2)):
+        assert [ln["query"] for ln in lines if ln.get("phase") == phase
+                ] == [q for q in ("q6", "q1", "q12") for _ in range(n)]
+    bound = {ln["query"]: ln for ln in lines if ln.get("phase") == "binding"}
+    assert set(bound["q6"]) >= {"year", "discount", "quantity"}
+    assert set(bound["q12"]) >= {"shipmode1", "shipmode2", "year"}
+
+
+def test_smoke_defines_no_copy_of_the_benchmarks_code():
+    sys.path.insert(0, REPO)
+    import chip_smoke
+    for name in ("XlaCounts", "compare_tables", "delta", "gen_tpch",
+                 "TPCH_BUILDERS"):
+        assert not hasattr(chip_smoke, name), name
+    bench_dir = os.path.join(REPO, "benchmark")
+    for mod in (chip_smoke.counters, chip_smoke.compare, chip_smoke.tpch_gen):
+        assert os.path.dirname(mod.__file__) == bench_dir
+
+
+def test_nothing_sends_a_reader_to_the_old_scoreboard():
+    """The old scoreboard and its records are gone (PR 31): no tracked
+    source file, doc or README names them; the history (CHANGES,
+    ROADMAP, PERF) and the benchmark's own files may."""
+    r = subprocess.run(["git", "ls-files", "-z"], cwd=REPO,
+                       capture_output=True, text=True)
+    if r.returncode != 0:
+        pytest.skip("not a git checkout: no list of tracked files")
+    gone = ("bench" + ".py", "BENCH" + "_r", "import " + "bench")
+    hits = []
+    for rel in filter(None, r.stdout.split("\0")):
+        if rel.startswith("benchmark/") or not (
+                rel.endswith(".py") or rel == "README.md"
+                or (rel.startswith("docs/") and rel.endswith(".md"))):
+            continue
+        path = os.path.join(REPO, rel)
+        if not os.path.exists(path):  # deleted, not yet committed
+            continue
+        with open(path, errors="replace") as fh:
+            text = fh.read()
+        hits += [f"{rel}: {g}" for g in gone if g in text]
+    assert not hits, hits
+    assert not os.path.exists(os.path.join(REPO, "bench" + ".py"))
 
 
 def test_refuses_cpu_without_rehearse():
@@ -75,7 +141,7 @@ def test_rehearse_four_devices_runs_only_the_ici_path():
     assert placed["ici_exchanges_executed"] >= 1
     assert sum(1 for b in placed["live_array_bytes"] if b) == 4
     cmp_ = next(ln for ln in lines if ln.get("phase") == "compare")
-    assert cmp_["against"] == "one_device" and cmp_["equal"]
+    assert cmp_["against"] == "one_device" and _same(cmp_)
 
 
 # -- compile-cache placement: one function decides ---------------------------
@@ -210,24 +276,3 @@ def test_concat_colocates_batches_of_different_devices():
         got = device_to_host(out)
         assert got.column("k").to_pylist() == [
             k for t in parts[:n] for k in t.column("k").to_pylist()]
-
-
-# -- bench.py: one process per chip, and failures are loud -------------------
-
-def test_bench_child_failures_and_devices_are_recorded(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-    monkeypatch.setattr(bench, "FAILURES", [])
-    monkeypatch.setattr(bench, "CHILD_DEVICES", {})
-    r = bench.run_child("bad", ["--sf1-query", "no_such_query"], 120)
-    assert r.returncode != 0
-    assert bench.FAILURES == [f"bad: rc={r.returncode}"]
-    assert bench.CHILD_DEVICES["bad"]["platform"] == "cpu"
-
-
-def test_bench_parent_imports_no_jax_and_roofline_is_keyed():
-    code = ("import sys, bench; assert 'jax' not in sys.modules; "
-            "print(sorted(bench.HBM_GB_PER_S))")
-    r = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                       capture_output=True, text=True, check=True)
-    assert r.stdout.strip() == "['TPU v5 lite']"

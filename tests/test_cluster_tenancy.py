@@ -396,8 +396,7 @@ def test_cluster_tenancy_soak_smoke():
 @pytest.mark.slow
 def test_cluster_tenancy_soak_sustained():
     """The long-soak shape: more executors, deeper in-flight, minutes
-    of wall — the hour-class form runs through ``bench.py
-    --cluster-tenancy-soak --soak-minutes``."""
+    of wall."""
     rec = run_cluster_tenancy_soak(
         duration_s=30.0, executors=3, in_flight=18, seed=17,
         timeout_s=300.0, heartbeat_s=0.05)

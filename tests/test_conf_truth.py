@@ -252,3 +252,53 @@ def test_shape_conf_defaults_and_wiring():
 def test_shape_conf_validation_rejects(key, bad):
     with pytest.raises(ValueError, match="invalid value"):
         tpu_session({key: bad})
+
+
+# keys whose every use passed the default became module constants
+# (PR 31): the key, and where its value now lives
+_REMOVED_KEYS = [
+    ("spark.rapids.tpu.retry.jitterSeed",
+     "runtime.resilience", "JITTER_SEED"),
+    ("spark.rapids.tpu.exchange.donate",
+     "exec.distributed", "TpuIciShuffleExchangeExec"),   # donate=True
+    ("spark.rapids.sql.queryLog.maxEvents", "runtime.trace", "MAX_EVENTS"),
+    ("spark.rapids.tpu.attribution.ringSize",
+     "runtime.attribution", "RING_SIZE"),
+    ("spark.rapids.tpu.attribution.closeTolerance",
+     "runtime.attribution", "CLOSE_TOLERANCE"),
+    ("spark.rapids.tpu.attribution.blackboxMaxDumps",
+     "runtime.attribution", "BLACKBOX_MAX_DUMPS"),
+    ("spark.rapids.tpu.scheduler.queueShaping",
+     "runtime.scheduler", "QUEUE_SHAPING"),
+    ("spark.rapids.tpu.tenancy.suspendTtlMs",
+     "runtime.tenancy", "SUSPEND_TTL_GRACES"),
+    ("spark.rapids.tpu.tenancy.degradedAfterMisses",
+     "runtime.tenancy", "DEGRADED_AFTER_MISSES"),
+    ("spark.rapids.tpu.adaptive.joinStrategy.enabled",
+     "adaptive", "AdaptivePolicy"),                      # join_strategy
+    ("spark.rapids.tpu.adaptive.batchRetarget.enabled",
+     "adaptive", "AdaptivePolicy"),                      # batch_retarget
+]
+
+
+@pytest.mark.parametrize("key,module,holder", _REMOVED_KEYS,
+                         ids=[k[0].rsplit(".tpu.", 1)[-1]
+                              for k in _REMOVED_KEYS])
+def test_removed_key_is_unknown_and_undocumented(key, module, holder):
+    """Setting one raises like any other unknown ``spark.rapids.*`` key,
+    the generated docs do not list it, and what holds its value is
+    there."""
+    import importlib
+    import os
+
+    from spark_rapids_tpu import conf as C
+    with pytest.raises(ValueError, match="unknown spark.rapids"):
+        tpu_session({key: "1"})
+    assert key not in {e.key for e in vars(C).values()
+                       if isinstance(e, C.ConfEntry)}
+    docs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "configs.md")
+    with open(docs) as f:
+        assert key not in f.read()
+    assert hasattr(
+        importlib.import_module(f"spark_rapids_tpu.{module}"), holder)
