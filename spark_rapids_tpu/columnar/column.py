@@ -83,7 +83,8 @@ class DeviceColumn:
                             self.evalid)
 
     def gather(self, idx: jax.Array) -> "DeviceColumn":
-        """Row gather (used by compaction, sort, join)."""
+        """Row gather, one take a leaf (sort, window, shuffle; compaction
+        moves its rows through ``ops.ordering.take_rows``)."""
         data = jnp.take(self.data, idx, axis=0)
         validity = None if self.validity is None else jnp.take(self.validity, idx)
         lengths = None if self.lengths is None else jnp.take(self.lengths, idx)
@@ -176,23 +177,56 @@ jax.tree_util.register_pytree_node(DeviceBatch, _batch_flatten, _batch_unflatten
 # dynamic→static boundary; called before shuffle/join-build/host transfer.
 # ---------------------------------------------------------------------------
 
-def _compact_impl(batch: DeviceBatch) -> DeviceBatch:
-    # Stable argsort on "dead" flag moves live rows to the front preserving
-    # order.  One lax.sort; vectorizes fine on TPU.
-    order = jnp.argsort((~batch.sel).astype(jnp.int8), stable=True)
-    cols = tuple(c.gather(order) for c in batch.columns)
-    count = jnp.sum(batch.sel.astype(jnp.int32))
-    sel = jnp.arange(batch.capacity, dtype=jnp.int32) < count
-    return DeviceBatch(batch.schema, cols, sel, compacted=True)
+def live_bucket(rows: int, capacity: int) -> int:
+    """Slots a compaction keeps for ``rows`` live rows of a
+    ``capacity``-slot batch: their pow-2 bucket, floored at 8 — what
+    ``_concat_compacted_fast`` would cut the batch to anyway."""
+    return min(capacity, max(8, round_up_pow2(max(rows, 1), 8)))
 
 
-def compact(batch: DeviceBatch) -> DeviceBatch:
+def _compact_order(sel: jax.Array):
+    # Stable argsort on "dead" flag: live rows first, in order, then
+    # the dead ones.  One lax.sort, in a program of its own keyed by
+    # capacity alone: a 1 M-row sort compiles for tens of seconds, so
+    # no schema and no bucket may multiply it.
+    order = jnp.argsort((~sel).astype(jnp.int8), stable=True)
+    return order, jnp.sum(sel.astype(jnp.int32))
+
+
+def _compact_take(bucket: int):
+    def run(batch: DeviceBatch, order, count) -> DeviceBatch:
+        from spark_rapids_tpu.ops.ordering import take_rows
+        leaves, columns = jax.tree_util.tree_flatten(batch.columns)
+        # a gather pays per index: only the bucket's, and every leaf
+        # in one packed row gather (docs/kernels.md "Moving rows")
+        cols = jax.tree_util.tree_unflatten(
+            columns, take_rows(leaves, order[:bucket]))
+        sel = jnp.arange(bucket, dtype=jnp.int32) < count
+        return DeviceBatch(batch.schema, cols, sel, compacted=True)
+    return run
+
+
+def compact(batch: DeviceBatch, rows: Optional[int] = None) -> DeviceBatch:
+    """Live rows to the front, stable.  ``rows`` is the batch's live
+    count where the caller has measured it: the result then keeps their
+    bucket (``live_bucket``) of the capacity, and only that many rows
+    move.  Rows past the count hold what the order names there (dead
+    rows, in order), masked by ``sel``."""
     if batch.compacted:
         return batch
+    cap = batch.capacity
+    if rows is not None and rows >= cap:
+        # every slot live: compacted by definition, nothing to launch
+        return DeviceBatch(batch.schema, batch.columns, batch.sel,
+                           compacted=True)
+    bucket = cap if rows is None else live_bucket(rows, cap)
     from spark_rapids_tpu.runtime.kernel_cache import (
         cached_kernel, fingerprint)
-    return cached_kernel(("compact", fingerprint(batch.schema)),
-                         lambda: _compact_impl)(batch)
+    order, count = cached_kernel(
+        ("compact_order",), lambda: _compact_order)(batch.sel)
+    return cached_kernel(
+        ("compact_take", fingerprint(batch.schema), bucket),
+        lambda: _compact_take(bucket))(batch, order, count)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,7 @@ import pyarrow as pa
 from spark_rapids_tpu.columnar import dtypes as T
 from spark_rapids_tpu.columnar import host as H
 from spark_rapids_tpu.columnar.column import (
-    DeviceBatch, DeviceColumn, host_to_device, round_up_pow2)
+    DeviceBatch, DeviceColumn, compact, host_to_device, round_up_pow2)
 from spark_rapids_tpu.exec.base import CpuExec, ExecNode, TpuExec
 from spark_rapids_tpu.ops.expressions import Expression
 from spark_rapids_tpu.runtime import telemetry as TM
@@ -93,6 +93,14 @@ _TM_SCAN_MISSES = TM.REGISTRY.counter(
 _TM_H2D_BYTES = TM.REGISTRY.counter(
     "tpuq_h2d_bytes_total",
     "arrow bytes TpuScanExec copied to the device on a miss")
+_TM_COMPACT_IN = TM.REGISTRY.counter(
+    "tpuq_compact_slots_in_total",
+    "slots of the batches a join gathered, at the capacities they "
+    "came in")
+_TM_COMPACT_MOVED = TM.REGISTRY.counter(
+    "tpuq_compact_slots_moved_total",
+    "slots those batches' compactions gathered: a live bucket each, "
+    "0 for a batch that came compacted or fully live")
 
 
 def _scan_cache_get(table: pa.Table, key):
@@ -269,9 +277,10 @@ class TpuProjectExec(TpuExec):
         exprs, schema = self.exprs, self.schema
 
         def run(batch):
+            # sel passes through untouched, and so does its promise
             return DeviceBatch(
                 schema, tuple(e.eval_tpu(batch) for e in exprs),
-                batch.sel)
+                batch.sel, compacted=batch.compacted)
 
         return run, ("project", fingerprint(exprs), fingerprint(schema))
 
@@ -555,7 +564,6 @@ class TpuCoalesceBatchesExec(TpuExec):
         return f"TpuCoalesceBatches [{goal}]"
 
     def execute(self, partition: int) -> Iterator[DeviceBatch]:
-        from spark_rapids_tpu.columnar.column import compact
         pending: List[DeviceBatch] = []
         pending_rows = 0
         for b in self.children[0].execute(partition):
@@ -608,6 +616,28 @@ def _overlapped_live_counts(batches) -> List[int]:
     return [int(np.asarray(s_)) for s_ in sums]
 
 
+def _compact_counted(batches: List[DeviceBatch], node=None):
+    """Count first, then compact each batch at its live bucket.
+
+    Every live count comes in ONE overlapped round trip, ahead of the
+    compaction it sizes (a gather pays per index, so only the live
+    bucket's move: docs/kernels.md "Moving rows"), and goes back to the
+    caller with the batches, so nobody pulls it again.  Returns
+    ``(compacted batches, live counts, capacities as they came)``."""
+    counts = _overlapped_live_counts(batches)
+    caps = [b.capacity for b in batches]
+    out = [compact(b, rows=n) for b, n in zip(batches, counts)]
+    # a batch that came compacted, or with every slot live, moves none
+    moved = sum(c.capacity for b, n, c in zip(batches, counts, out)
+                if not b.compacted and n < b.capacity)
+    _TM_COMPACT_IN.inc(sum(caps))
+    _TM_COMPACT_MOVED.inc(moved)
+    if node is not None:
+        node.metric("compactSlotsIn").add(sum(caps))
+        node.metric("compactSlotsMoved").add(moved)
+    return out, counts, caps
+
+
 def _concat_compacted_fast(schema: T.StructType,
                            batches: List[DeviceBatch],
                            counts: Optional[List[int]] = None
@@ -624,8 +654,7 @@ def _concat_compacted_fast(schema: T.StructType,
     3. one eager ``jnp.concatenate`` per leaf, then a single stable
        compact moves the per-batch live prefixes together.
     """
-    from spark_rapids_tpu.columnar.column import compact as _compact
-    from spark_rapids_tpu.columnar.column import empty_batch
+    from spark_rapids_tpu.columnar.column import empty_batch, live_bucket
     from spark_rapids_tpu.runtime.kernel_cache import (
         cached_kernel, fingerprint)
     if not batches:
@@ -697,7 +726,7 @@ def _concat_compacted_fast(schema: T.StructType,
     norm = []
     all_full = True
     for b, n in zip(batches, counts):
-        out_cap = min(b.capacity, max(8, round_up_pow2(max(n, 1), 8)))
+        out_cap = live_bucket(n, b.capacity)
         needs = out_cap < b.capacity or any(
             (is_str[ci] and b.columns[ci].data.shape[1] < widths[ci])
             or (has_val[ci] and b.columns[ci].validity is None)
@@ -734,7 +763,7 @@ def _concat_compacted_fast(schema: T.StructType,
         cat = DeviceBatch(schema, padded.columns, padded.sel,
                           compacted=all_full)
     if not all_full:
-        cat = _compact(cat)
+        cat = compact(cat)
     if out_bucket < cat.capacity:
         fn = cached_kernel(
             ("concat_trim", out_bucket, sfp),

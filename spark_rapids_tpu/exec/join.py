@@ -36,9 +36,10 @@ import numpy as np
 from spark_rapids_tpu.columnar import dtypes as T
 from spark_rapids_tpu.columnar import host as H
 from spark_rapids_tpu.columnar.column import (
-    DeviceBatch, DeviceColumn, compact, round_up_pow2)
+    DeviceBatch, DeviceColumn, live_bucket, round_up_pow2)
 from spark_rapids_tpu.exec.base import CpuExec, TpuExec
-from spark_rapids_tpu.exec.basic import concat_device_batches
+from spark_rapids_tpu.exec.basic import (
+    _compact_counted, concat_device_batches)
 from spark_rapids_tpu.ops import ordering as ORD
 from spark_rapids_tpu.ops.expressions import Expression
 
@@ -47,11 +48,17 @@ from spark_rapids_tpu.ops.expressions import Expression
 # helpers shared by both paths
 # ---------------------------------------------------------------------------
 
-def _gather_list(child, partition=None):
-    """Child batches as a compacted list (all partitions or one)."""
+def _pump_list(child, partition=None) -> List[DeviceBatch]:
+    """Child batches as they come (all partitions or one)."""
     parts = (range(child.num_partitions()) if partition is None
              else [partition])
-    return [compact(b) for p in parts for b in child.execute(p)]
+    return [b for p in parts for b in child.execute(p)]
+
+
+def _gather_list(child, partition=None, node=None):
+    """Child batches compacted at their live buckets, with their live
+    counts and the capacities they came at (all partitions or one)."""
+    return _compact_counted(_pump_list(child, partition), node)
 
 
 def _concat_or_empty(schema, batches, counts=None):
@@ -64,13 +71,11 @@ def _concat_or_empty(schema, batches, counts=None):
 def _gather_all(child, schema, device: bool, partition=None):
     """Concat child batches to one batch — all partitions, or just one
     (the co-partitioned path downstream of a key-hash exchange)."""
-    parts = (range(child.num_partitions()) if partition is None
-             else [partition])
     if device:
-        return _concat_or_empty(
-            schema, [compact(b) for p in parts for b in child.execute(p)])
+        batches, counts, _ = _gather_list(child, partition)
+        return _concat_or_empty(schema, batches, counts=counts)
     from spark_rapids_tpu.exec.sort import _concat_host
-    batches = [b for p in parts for b in child.execute(p)]
+    batches = _pump_list(child, partition)
     if not batches:
         return H.HostBatch(schema, [
             H.HostCol(f.dtype,
@@ -556,7 +561,9 @@ class TpuSortMergeJoinExec(TpuExec):
         if jt == "right":
             yield from self._execute_swapped(partition)
             return
-        l_list = r_list = None
+        # each side: (batches compacted at their live buckets, their
+        # live counts, the capacities they came at)
+        left = right = None
         if self.broadcast == "right":
             lpart, rpart = partition, None
         elif self.broadcast == "left":
@@ -571,24 +578,30 @@ class TpuSortMergeJoinExec(TpuExec):
                     # hot partition: rank-interleaved stream slice
                     # joined against the replicated build partition
                     with self.timer("gatherTime"):
-                        l_list = [compact(b) for b in
-                                  self.children[0].execute_split(p, j, k)]
+                        left = _compact_counted(
+                            list(self.children[0].execute_split(p, j, k)),
+                            self)
                         with self._split_lock:
-                            r_cached = self._split_build_cache.get(p)
-                            if r_cached is None:
-                                r_cached = _gather_list(
-                                    self.children[1], rpart)
-                                self._split_build_cache[p] = r_cached
-                        # shallow copy: the sub-partition path drains
-                        # its input lists in place; the cache must keep
-                        # its references for the next slice
-                        r_list = list(r_cached)
+                            right = self._split_build_cache.get(p)
+                            if right is None:
+                                right = _gather_list(
+                                    self.children[1], rpart, self)
+                                self._split_build_cache[p] = right
         else:
             lpart = rpart = None
-        if l_list is None:
+        if left is None:
             with self.timer("gatherTime"):
-                l_list = _gather_list(self.children[0], lpart)
-                r_list = _gather_list(self.children[1], rpart)
+                # both children's live counts in one round trip
+                l_raw = _pump_list(self.children[0], lpart)
+                r_raw = _pump_list(self.children[1], rpart)
+                both = _compact_counted(l_raw + r_raw, self)
+                left = tuple(x[:len(l_raw)] for x in both)
+                right = tuple(x[len(l_raw):] for x in both)
+        # copies: the sub-partition path drains its input lists in
+        # place, and the split cache must keep its references for the
+        # next slice
+        l_list, l_counts, l_caps = map(list, left)
+        r_list, r_counts, r_caps = map(list, right)
         nokey = jt == "cross" or not self.left_keys
         mgr = get_manager()
         total = (sum(b.nbytes() for b in l_list)
@@ -598,20 +611,15 @@ class TpuSortMergeJoinExec(TpuExec):
         # side's gathered LIVE rows exceed the row cap, sub-partition
         # up front — an in-core attempt would compile sort/search
         # kernels at a bucket whose cold compile alone can exceed any
-        # query budget.  Live counts (ONE overlapped round trip
-        # for both sides) rather than capacities: a filtered side keeps
-        # its scan bucket but holds few live rows, and a capacity
+        # query budget.  Live counts (the gather's own, one round trip
+        # for both sides) rather than capacities: a filtered side holds
+        # few live rows in its scan buckets, and a capacity
         # trigger would sub-partition 3-23x more finely than the data
         # warrants (measured on TPC-H q10: 6M-capacity / 2M-live
-        # lineitem).  The concat the in-core path runs shrinks each
-        # batch to its live bucket anyway, so live rows — not
-        # capacities — decide every downstream kernel's shape.
-        l_counts = r_counts = side_live = None
+        # lineitem).  Live rows — not capacities — decide every
+        # downstream kernel's shape.
+        side_live = None
         if not nokey and self.sub_partition_rows and not self.broadcast:
-            from spark_rapids_tpu.exec.basic import _overlapped_live_counts
-            counts = _overlapped_live_counts(l_list + r_list)
-            l_counts = counts[:len(l_list)]
-            r_counts = counts[len(l_list):]
             l_live = sum(l_counts) or 1
             r_live = sum(r_counts) or 1
             side_live = max(l_live, r_live)
@@ -652,44 +660,43 @@ class TpuSortMergeJoinExec(TpuExec):
         # gathered once (re-splitting it per stream partition would
         # repeat identical work P times), but the STREAMED side still
         # honors the row cap — by its LIVE rows, as above: a filtered
-        # stream keeps its scan buckets (TPC-H q14 at SF1: 6.3 M slots
-        # holding ~75 k rows), and bounded groups cut by capacity probe
-        # 24 chunks of which 18 hold no row.  Under the cap the in-core
-        # path below shrinks each batch to its live bucket and probes
-        # once; over it the stream needs no hash split, since the other
-        # side is fully present: process it in bounded groups, each
-        # group's rows decided independently (inner/left/semi/anti)
+        # stream comes in its scan buckets (TPC-H q14 at SF1: 6.3 M
+        # slots holding ~75 k rows), and bounded groups cut by capacity
+        # probe 24 chunks of which 18 hold no row.  Under the cap the
+        # gather has shrunk each batch to its live bucket and the
+        # in-core path below probes once; over it the stream needs no
+        # hash split, since the other side is fully present: process it
+        # in bounded groups, each group's rows decided independently
+        # (inner/left/semi/anti)
         held = total
         if not nokey and self.sub_partition_rows and self.broadcast:
-            right = self.broadcast == "right"
-            stream, bc_list = ((l_list, r_list) if right
-                               else (r_list, l_list))
-            if sum(b.capacity for b in stream) > self.sub_partition_rows:
-                from spark_rapids_tpu.exec.basic import (
-                    _overlapped_live_counts)
-                counts = _overlapped_live_counts(stream)
+            right_bc = self.broadcast == "right"
+            stream, counts, caps = ((l_list, l_counts, l_caps) if right_bc
+                                    else (r_list, r_counts, r_caps))
+            bc_list = r_list if right_bc else l_list
+            # by the capacities the stream CAME at: what it holds now
+            # are live buckets
+            if sum(caps) > self.sub_partition_rows:
                 if sum(counts) > self.sub_partition_rows:
                     self.metric("streamedJoins").add(1)
                     yield from self._broadcast_streamed(
                         l_list, r_list, jt, mgr)
                     return
                 self.metric("liveRowInCoreJoins").add(1)
-                buckets = [min(b.capacity, round_up_pow2(n, 8))
+                buckets = [live_bucket(n, b.capacity)
                            for b, n in zip(stream, counts)]
-                if len(stream) == 1:
-                    # the concat hands a lone batch back as it is, at
-                    # its scan bucket: cut it to its live bucket here
-                    # (compacted, so the live rows are a prefix)
+                if len(stream) == 1 and buckets[0] < stream[0].capacity:
+                    # a lone batch that CAME compacted is still at the
+                    # capacity it came at, and the concat hands a lone
+                    # batch back as it is: cut it to its live bucket
+                    # here (the live rows are a prefix)
                     from spark_rapids_tpu.parallel.shuffle import (
                         slice_batch)
                     stream[0] = slice_batch(stream[0], 0, buckets[0])
-                if right:
-                    l_counts = counts
-                else:
-                    r_counts = counts
                 # reserve what the concat will hold (live buckets), not
-                # scan capacity: slots that are never gathered must not
-                # send a thinly live stream to the hash split
+                # the capacity of a batch that came compacted: slots
+                # that are never gathered must not send a thinly live
+                # stream to the hash split
                 held = (sum(b.nbytes() for b in bc_list)
                         + sum(b.nbytes() * k // b.capacity
                               for b, k in zip(stream, buckets)))
@@ -1267,12 +1274,9 @@ class TpuAdaptiveJoinExec(TpuExec):
             from spark_rapids_tpu.exec.distributed import (
                 TpuIciShuffleExchangeExec)
             with self.timer("measureTime"):
-                r_list = _gather_list(self.children[1])
                 # LIVE bytes, not pow-2 bucket capacity: a filtered
-                # side keeps its input bucket but holds few live rows
-                from spark_rapids_tpu.exec.basic import (
-                    _overlapped_live_counts)
-                counts = _overlapped_live_counts(r_list)
+                # side's live bucket still rounds its rows up
+                r_list, counts, _ = _gather_list(self.children[1])
             rbytes = sum(
                 n * max(1, b.nbytes() // max(b.capacity, 1))
                 for n, b in zip(counts, r_list))
@@ -1401,13 +1405,10 @@ class TpuAdaptiveLocalJoinExec(TpuExec):
             if (decided is None and pol.wants_join
                     and pol.broadcast_threshold > 0):
                 # cold query: measure the build side off its own pump.
-                # LIVE bytes, not bucket capacity (a filtered side
-                # keeps its scan bucket but holds few live rows)
-                from spark_rapids_tpu.exec.basic import (
-                    _overlapped_live_counts)
+                # LIVE bytes, not bucket capacity (a filtered side's
+                # live bucket still rounds its rows up)
                 with self.timer("measureTime"):
-                    r_list = _gather_list(self.children[1])
-                    counts = _overlapped_live_counts(r_list)
+                    r_list, counts, _ = _gather_list(self.children[1])
                 rbytes = sum(
                     n * max(1, b.nbytes() // max(b.capacity, 1))
                     for n, b in zip(counts, r_list))

@@ -100,6 +100,37 @@ def test_q1_shaped_groupby_compiles(one_chip):
     assert hlo.count(" gather(") == 6
 
 
+def test_compact_order_compiles(one_chip):
+    # the sort of the dead flag alone: one program a capacity
+    from spark_rapids_tpu.columnar import column as C
+    _compile(C._compact_order, one_chip, ((SMALL,), jnp.bool_))
+
+
+@pytest.mark.parametrize("bucket", [1 << 14, BATCH_ROWS])
+def test_compact_take_compiles(one_chip, bucket):
+    # Q14's filtered scan batch (a long, two doubles, a date) gathered
+    # at its live bucket and, as every other caller has it, at capacity
+    from spark_rapids_tpu.columnar import column as C
+    from spark_rapids_tpu.columnar import dtypes as T
+
+    def leaf(dt):
+        return jax.ShapeDtypeStruct((BATCH_ROWS,), dt, sharding=one_chip)
+
+    kinds = [("l_partkey", T.LongT, jnp.int64),
+             ("l_extendedprice", T.DoubleT, jnp.float64),
+             ("l_discount", T.DoubleT, jnp.float64),
+             ("l_shipdate", T.DateT, jnp.int32)]
+    batch = C.DeviceBatch(
+        T.StructType(tuple(T.StructField(n, t, True) for n, t, _ in kinds)),
+        tuple(C.DeviceColumn(t, leaf(d)) for _, t, d in kinds),
+        leaf(jnp.bool_))
+    c = jax.jit(C._compact_take(bucket)).lower(
+        batch, leaf(jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    # one word matrix, and a double is two f32 on the chip
+    assert c.as_text().count(" gather(") == 3
+
+
 # on a TPU `auto` resolves the sort kernel to the tiled ("fused") form
 # (kernels.resolve with supports_pallas=False); "jnp" is its t == 1 arm
 @pytest.mark.parametrize("backend", ["fused", "jnp"])

@@ -16,6 +16,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from spark_rapids_tpu.columnar import column as C
 from spark_rapids_tpu.exec import join as J
 from spark_rapids_tpu.sql.column import col
 from spark_rapids_tpu.utils.harness import (
@@ -68,6 +69,19 @@ def _find(node, name="TpuSortMergeJoinExec"):
         if got is not None:
             return got
     return None
+
+
+def _group_slots(buckets):
+    """Slots a group of ``_broadcast_streamed`` holds: the pow-2 bucket
+    of its batches' capacities' sum, greedily under the cap."""
+    out, acc = [], 0
+    for k in buckets:
+        if acc and acc + k > CAP:
+            out.append(acc)
+            acc = 0
+        acc += k
+    out.append(acc)
+    return [C.round_up_pow2(a) for a in out]
 
 
 def _counters(j):
@@ -129,8 +143,18 @@ def test_stream_live_over_the_cap_still_streams(how, side, probes):
             for b in j.execute(p)]
     assert _counters(j) == {"liveRowInCoreJoins": 0, "streamedJoins": 1,
                             "subPartitionJoins": 0}
-    # the groups of the capacity rule: ten 4096-slot batches, one each
-    assert len(probes) == 10, probes
+    # the groups of the capacity rule, cut from what the gather hands
+    # on since PR 32: ten batches at their live buckets (2048 or 4096
+    # slots for ~2 000 rows of 4 096), a group closed where the next
+    # batch would pass the cap
+    keep = fact.column("tag").to_numpy() < 50
+    buckets = [C.live_bucket(int(keep[lo:lo + 4096].sum()), 4096)
+               for lo in range(0, N, 4096)]
+    groups = len(_group_slots(buckets))
+    assert 5 < groups < 10 and set(buckets) == {2048, 4096}, buckets
+    assert len(probes) == groups, probes
+    assert sorted(p[0 if side == "right" else 1] for p in probes) == \
+        sorted(_group_slots(buckets)), probes
     assert max(max(p) for p in probes) <= CAP, probes
     if how == "inner":
         # as test_broadcast_streamed_output_capacities_capped pins
