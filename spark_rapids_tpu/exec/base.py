@@ -260,6 +260,13 @@ class ExecNode:
     def timer(self, name: str = "opTime") -> MetricTimer:
         return MetricTimer(self.metric(name), op=self.name)
 
+    def count(self, name: str, value: int) -> None:
+        """A count of this operator's work the host knows: into the
+        node's metric and the books of the query in flight (the
+        ledger's ``counts``)."""
+        self.metric(name).add(value)
+        trace.count(name, value)
+
     def num_partitions(self) -> int:
         if self._children:
             return self._children[0].num_partitions()

@@ -42,6 +42,12 @@ from spark_rapids_tpu.exec.basic import (
     _compact_counted, concat_device_batches)
 from spark_rapids_tpu.ops import ordering as ORD
 from spark_rapids_tpu.ops.expressions import Expression
+from spark_rapids_tpu.runtime import telemetry as TM
+
+_TM_PROBE_GROUPS = TM.REGISTRY.counter(
+    "tpuq_join_probe_groups_total",
+    "probe groups device joins ran: calls of the match kernel with a "
+    "probe (the sum of joinProbeGroups)")
 
 
 # ---------------------------------------------------------------------------
@@ -638,18 +644,18 @@ class TpuSortMergeJoinExec(TpuExec):
                     # each decided independently against it
                     self.metric("streamedJoins").add(1)
                     yield from self._broadcast_streamed(
-                        l_list, r_list, jt, mgr, side="right")
+                        l_list, r_list, jt, mgr, l_counts, side="right")
                     return
                 if l_live <= cap and jt == "inner":
                     self.metric("streamedJoins").add(1)
                     yield from self._broadcast_streamed(
-                        l_list, r_list, jt, mgr, side="left")
+                        l_list, r_list, jt, mgr, r_counts, side="left")
                     return
                 if (l_live <= cap
                         and jt in ("left_semi", "left_anti")):
                     self.metric("streamedJoins").add(1)
                     yield from self._semi_stream_right(
-                        l_list, l_counts, r_list, jt, mgr)
+                        l_list, l_counts, r_list, r_counts, jt, mgr)
                     return
                 self.metric("subPartitionJoins").add(1)
                 yield from self._sub_partition_join(
@@ -680,7 +686,7 @@ class TpuSortMergeJoinExec(TpuExec):
                 if sum(counts) > self.sub_partition_rows:
                     self.metric("streamedJoins").add(1)
                     yield from self._broadcast_streamed(
-                        l_list, r_list, jt, mgr)
+                        l_list, r_list, jt, mgr, counts)
                     return
                 self.metric("liveRowInCoreJoins").add(1)
                 buckets = [live_bucket(n, b.capacity)
@@ -715,7 +721,12 @@ class TpuSortMergeJoinExec(TpuExec):
                         cb = self._apply_condition(cb)
                         yield from self._rebatch(cb, ctotal)
                     else:
-                        yield from self._merge_join(lb, rb, jt)
+                        # in-core: the side that is not broadcast is
+                        # the one that streams (the left, unplanned)
+                        probe = (("right", sum(r_counts))
+                                 if self.broadcast == "left"
+                                 else ("left", sum(l_counts)))
+                        yield from self._merge_join(lb, rb, jt, probe)
                 return
         except RetryOOM:
             if nokey:
@@ -724,7 +735,31 @@ class TpuSortMergeJoinExec(TpuExec):
         yield from self._sub_partition_join(l_list, r_list, jt, total,
                                             mgr, live_rows=side_live)
 
-    def _broadcast_streamed(self, l_list, r_list, jt, mgr,
+    def _bounded_groups(self, stream, counts):
+        """Cut a streamed side into groups of at most the row cap's
+        slots: ``(batches, live rows)`` a group.  A single gathered
+        batch can itself exceed the cap (the default batchRows bucket
+        is larger than targetRows): it is row-sliced — batches here are
+        compacted, so each pow-2 chunk keeps a contiguous live prefix,
+        and ``counts`` (the gather's) says how long."""
+        from spark_rapids_tpu.parallel.shuffle import slice_batch
+        cap = self.sub_partition_rows
+        groups: List[List[DeviceBatch]] = [[]]
+        lives = [0]
+        acc = 0
+        for b, n in zip(stream, counts):
+            for lo in range(0, max(b.capacity, 1), cap):
+                c = b if b.capacity <= cap else slice_batch(b, lo, cap)
+                if groups[-1] and acc + c.capacity > cap:
+                    groups.append([])
+                    lives.append(0)
+                    acc = 0
+                groups[-1].append(c)
+                lives[-1] += min(max(n - lo, 0), c.capacity)
+                acc += c.capacity
+        return list(zip(groups, lives))
+
+    def _broadcast_streamed(self, l_list, r_list, jt, mgr, counts,
                             side: Optional[str] = None
                             ) -> Iterator[DeviceBatch]:
         """Row-cap the streamed side of a broadcast join by joining it
@@ -734,27 +769,11 @@ class TpuSortMergeJoinExec(TpuExec):
         broadcast=left): each streamed row's output depends only on the
         broadcast side, so groups are independent.  ``side`` overrides
         ``self.broadcast`` — the runtime strategy pick reuses this for
-        non-broadcast plans whose measured small side fits in-core."""
-        from spark_rapids_tpu.parallel.shuffle import slice_batch
-        cap = self.sub_partition_rows
+        non-broadcast plans whose measured small side fits in-core.
+        ``counts`` are the streamed batches' live rows, as the gather
+        counted them."""
         side = side or self.broadcast
-        stream = l_list if side == "right" else r_list
-        groups: List[List[DeviceBatch]] = [[]]
-        acc = 0
-        for b in stream:
-            # a single gathered batch can itself exceed the cap (the
-            # default batchRows bucket is larger than targetRows):
-            # row-slice it — batches here are compacted, so each pow-2
-            # chunk keeps a contiguous live prefix
-            chunks = ([b] if b.capacity <= cap else
-                      [slice_batch(b, lo, cap)
-                       for lo in range(0, b.capacity, cap)])
-            for c in chunks:
-                if groups[-1] and acc + c.capacity > cap:
-                    groups.append([])
-                    acc = 0
-                groups[-1].append(c)
-                acc += c.capacity
+        streamed = "left" if side == "right" else "right"
         # NOTE: side, not self.broadcast — the runtime strategy pick
         # passes side="right"/"left" on plans with broadcast=None, and
         # consulting self.broadcast here built the broadcast batch from
@@ -762,16 +781,18 @@ class TpuSortMergeJoinExec(TpuExec):
         bc = _concat_or_empty(
             self.children[1 if side == "right" else 0].schema,
             r_list if side == "right" else l_list)
-        for g in groups:
+        for g, live in self._bounded_groups(
+                l_list if side == "right" else r_list, counts):
             gb = _concat_or_empty(
                 self.children[0 if side == "right" else 1].schema, g)
             lb, rb = (gb, bc) if side == "right" else (bc, gb)
             with mgr.transient(2 * (gb.nbytes() + bc.nbytes())):
                 with self.timer():
-                    yield from self._merge_join(lb, rb, jt)
+                    yield from self._merge_join(lb, rb, jt,
+                                                (streamed, live))
 
-    def _semi_stream_right(self, l_list, l_counts, r_list, jt, mgr
-                           ) -> Iterator[DeviceBatch]:
+    def _semi_stream_right(self, l_list, l_counts, r_list, r_counts, jt,
+                           mgr) -> Iterator[DeviceBatch]:
         """semi/anti with the LEFT side in-core and an oversized RIGHT:
         stream the right side in bounded groups, OR-accumulating the
         per-row match flag across groups.  Correct because a semi/anti
@@ -779,30 +800,17 @@ class TpuSortMergeJoinExec(TpuExec):
         membership never changes it; null-key and dead left rows get
         m == 0 from every group, matching _merge_join's in-core
         semantics exactly."""
-        from spark_rapids_tpu.parallel.shuffle import slice_batch
-        cap = self.sub_partition_rows
         lb = _concat_or_empty(self.children[0].schema, l_list,
                               counts=l_counts)
-        groups: List[List[DeviceBatch]] = [[]]
-        acc = 0
-        for b in r_list:
-            chunks = ([b] if b.capacity <= cap else
-                      [slice_batch(b, lo, cap)
-                       for lo in range(0, b.capacity, cap)])
-            for c in chunks:
-                if groups[-1] and acc + c.capacity > cap:
-                    groups.append([])
-                    acc = 0
-                groups[-1].append(c)
-                acc += c.capacity
         matched = jnp.zeros((lb.capacity,), jnp.bool_)
-        for g in groups:
+        for g, live in self._bounded_groups(r_list, r_counts):
             if not g:
                 continue
             rb = _concat_or_empty(self.children[1].schema, g)
             with mgr.transient(2 * (lb.nbytes() + rb.nbytes())):
                 with self.timer():
-                    m, lo, perm, l_null = self._match_ranges(lb, rb)
+                    m, lo, perm, l_null = self._match_ranges(
+                        lb, rb, ("right", live))
                     matched = matched | (m > 0)
         keep = matched if jt == "left_semi" else ~matched
         out = lb.with_sel(lb.sel & keep)
@@ -901,7 +909,9 @@ class TpuSortMergeJoinExec(TpuExec):
                     [s.get() for s in r_slices[i]],
                     counts=[s.live_rows for s in r_slices[i]])
                 with self.timer():
-                    yield from self._merge_join(lb, rb, jt)
+                    yield from self._merge_join(
+                        lb, rb, jt,
+                        ("left", sum(s.live_rows for s in l_slices[i])))
                 for s in l_slices[i] + r_slices[i]:
                     s.close()
 
@@ -929,8 +939,14 @@ class TpuSortMergeJoinExec(TpuExec):
         return fn(batch)
 
     # -- core ---------------------------------------------------------------
-    def _match_ranges(self, lb, rb):
+    def _match_ranges(self, lb, rb, probe):
         """Sort right side; binary-search match ranges for left rows.
+
+        ``probe`` is ``(side, live rows)``: which of the two the join
+        streams through this call ("left" or "right") and how many of
+        its rows are live.  Every call is one probe group of the
+        ledger's ``counts``, counted here once the kernel has returned,
+        so a call that never ran (a refused reservation) counts nothing.
 
         One cached jitted kernel per (keys, schemas, backend) triple.
         The fused/pallas rungs route through kernels.hash_join (one
@@ -993,10 +1009,17 @@ class TpuSortMergeJoinExec(TpuExec):
             fn = cached_kernel(key, lambda: build(backend))
             return lambda: fn(lb, rb)
 
-        return KN.dispatch("join", be, runner, node=self)
+        out = KN.dispatch("join", be, runner, node=self)
+        side, live = probe
+        self.count("joinProbeGroups", 1)
+        self.count("joinSlotsProbed",
+                   (lb if side == "left" else rb).capacity)
+        self.count("joinLiveRowsStreamed", live)
+        _TM_PROBE_GROUPS.inc()
+        return out
 
-    def _merge_join(self, lb, rb, jt):
-        m, lo, perm, l_null = self._match_ranges(lb, rb)
+    def _merge_join(self, lb, rb, jt, probe):
+        m, lo, perm, l_null = self._match_ranges(lb, rb, probe)
 
         if jt in ("left_semi", "left_anti"):
             keep = (m > 0) if jt == "left_semi" else (m == 0)

@@ -536,13 +536,18 @@ class TpuTopNExec(TpuExec):
     def _local_topn(self, p: int) -> Optional[DeviceBatch]:
         from spark_rapids_tpu.exec.sort import sort_batch
         child = self.children[0]
-        batches = [compact(b) for b in child.execute(p)]
-        batches = [b for b in batches if b is not None]
+        batches = []
+        for b in child.execute(p):
+            with self.timer("concatTime"):
+                b = compact(b)
+            if b is not None:
+                batches.append(b)
         if not batches:
             return None
-        merged = concat_device_batches(self.schema, batches)
+        with self.timer("concatTime"):
+            merged = concat_device_batches(self.schema, batches)
         with self.timer():
-            s = sort_batch(merged, self.orders)
+            s = sort_batch(merged, self.orders, node=self)
             keep = s.sel & (jnp.arange(s.capacity, dtype=jnp.int32) < self.n)
             return compact(s.with_sel(keep))
 
@@ -564,9 +569,10 @@ class TpuTopNExec(TpuExec):
                 return
         if not winners:
             return
-        merged = concat_device_batches(self.schema, winners)
+        with self.timer("concatTime"):
+            merged = concat_device_batches(self.schema, winners)
         with self.timer():
-            s = sort_batch(merged, self.orders)
+            s = sort_batch(merged, self.orders, node=self)
             keep = s.sel & (jnp.arange(s.capacity, dtype=jnp.int32) < self.n)
             out = s.with_sel(keep)
         self.metric("numOutputBatches").add(1)

@@ -36,6 +36,7 @@ from spark_rapids_tpu.exec.base import CpuExec, TpuExec
 from spark_rapids_tpu.exec.basic import concat_device_batches
 from spark_rapids_tpu.ops import ordering as ORD
 from spark_rapids_tpu.plan.logical import SortOrder
+from spark_rapids_tpu.runtime import trace
 
 
 class CpuSortExec(CpuExec):
@@ -248,6 +249,8 @@ def sort_batch(batch: DeviceBatch, orders: Sequence[SortOrder],
         lambda: (lambda b: _sort_batch_impl(b, orders, backend=be)))
     out = fn(batch)
     KN.count("sort", be, node)
+    # the rung that ran, in the books of the query in flight
+    trace.count(f"sortBackend.{be}", 1)
     return out
 
 

@@ -207,43 +207,52 @@ def span_bucket(op: str, stage: str) -> Optional[str]:
 # The ledger fold
 # ---------------------------------------------------------------------------
 
-def _project(intervals: List[Tuple[float, float, int]],
-             t0: float, t1: float) -> List[float]:
+def _project(intervals: List[Tuple[float, float, int, str]],
+             t0: float, t1: float) -> Tuple[List[float], Dict[str, float]]:
     """Charge the [t0, t1] timeline to buckets by priority sweep.
 
-    ``intervals`` is (start, end, priority_index); returns seconds per
-    ``BUCKET_PRIORITY`` index.  At each elementary segment between
-    boundary points the highest-priority active bucket (lowest index)
-    wins, so the result is exclusive by construction and sums to at
-    most (t1 - t0)."""
+    ``intervals`` is (start, end, priority_index, stage_key); returns
+    seconds per ``BUCKET_PRIORITY`` index and, from the same sweep,
+    seconds per stage key.  At each elementary segment between boundary
+    points the highest-priority active bucket (lowest index) wins, and
+    within it the span opened last (the innermost on its thread), so
+    both results are exclusive by construction, sum to at most
+    (t1 - t0), and the stages of one bucket add up to the bucket."""
     n = len(BUCKET_PRIORITY)
     out = [0.0] * n
+    stages: Dict[str, float] = {}
     if t1 <= t0 or not intervals:
-        return out
+        return out, stages
     events: List[Tuple[float, int, int]] = []
-    for s, e, pri in intervals:
+    for i, (s, e, _pri, _key) in enumerate(intervals):
         s, e = max(s, t0), min(e, t1)
         if e > s:
-            events.append((s, 1, pri))
-            events.append((e, -1, pri))
+            events.append((s, 1, i))
+            events.append((e, 0, i))
     if not events:
-        return out
-    events.sort(key=lambda ev: ev[0])
-    active = [0] * n
+        return out, stages
+    events.sort()
+    # the open spans of each priority in the order they opened (events
+    # come in time order), so the innermost is the last
+    active: List[List[int]] = [[] for _ in range(n)]
     prev = events[0][0]
-    i = 0
-    while i < len(events):
-        t = events[i][0]
+    for t, opens, idx in events:
         if t > prev:
-            for pri in range(n):
-                if active[pri]:
+            for pri, open_here in enumerate(active):
+                if open_here:
                     out[pri] += t - prev
+                    key = intervals[open_here[-1]][3]
+                    stages[key] = stages.get(key, 0.0) + (t - prev)
                     break
             prev = t
-        while i < len(events) and events[i][0] == t:
-            active[events[i][2]] += events[i][1]
-            i += 1
-    return out
+        open_here = active[intervals[idx][2]]
+        if opens:
+            open_here.append(idx)
+        elif open_here[-1] == idx:
+            open_here.pop()
+        else:
+            open_here.remove(idx)
+    return out, stages
 
 
 def attribute(tracer=None, spans: Optional[Iterable] = None,
@@ -259,11 +268,17 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     scalar seconds measured outside the trace window (the server's
     queue wait) — they extend e2e rather than competing for it.
 
-    Returns ``{"buckets", "e2e_s", "unaccounted_s", "closed",
-    "tolerance", "verdict", "dominant", "dominant_share", "launches"}``
-    with buckets rounded, exclusive, and summing (with ``unaccounted``)
-    to ``e2e_s`` exactly; ``launches`` counts the cached-kernel calls
-    (the ``Kernel.<label>`` spans)."""
+    Returns ``{"buckets", "stages_s", "counts", "e2e_s",
+    "unaccounted_s", "closed", "tolerance", "verdict", "dominant",
+    "dominant_share", "launches"}`` with buckets rounded, exclusive,
+    and summing (with ``unaccounted``) to ``e2e_s`` exactly;
+    ``stages_s`` is the same sweep keyed ``<op>:<stage>`` before it
+    collapses into buckets (the books by operator: the stages that map
+    to a bucket add up to it; an extra is the stage ``extra:<bucket>``);
+    ``counts`` is what the query's operators counted on the host
+    (``Tracer.counts``: probe groups, slots probed, sorts a rung);
+    ``launches`` counts the cached-kernel calls (the ``Kernel.<label>``
+    spans)."""
     if tracer is not None:
         spans = list(tracer.events)
         t0 = tracer.t_start
@@ -282,7 +297,7 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
             t1 = t0 + e2e_s
     e2e = max(t1 - t0, 0.0)
     pri_index = {b: i for i, b in enumerate(BUCKET_PRIORITY)}
-    intervals: List[Tuple[float, float, int]] = []
+    intervals: List[Tuple[float, float, int, str]] = []
     launches = 0
     for sp in spans:
         if sp.op.startswith("Kernel."):
@@ -290,14 +305,16 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
         b = span_bucket(sp.op, sp.stage)
         if b is None:
             continue
-        intervals.append((sp.t0, sp.t1, pri_index[b]))
-    per_pri = _project(intervals, t0, t1)
+        intervals.append((sp.t0, sp.t1, pri_index[b],
+                          f"{sp.op}:{sp.stage}"))
+    per_pri, stages = _project(intervals, t0, t1)
     buckets = {b: per_pri[i] for i, b in enumerate(BUCKET_PRIORITY)}
     covered = sum(per_pri)
     unaccounted = max(e2e - covered, 0.0)
     for name, secs in (extras or {}).items():
         if name in buckets and secs:
             buckets[name] += float(secs)
+            stages[f"extra:{name}"] = float(secs)
             e2e += float(secs)
     buckets["unaccounted"] = unaccounted
     tol = float(tolerance)
@@ -307,6 +324,8 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     share = (dom_s / e2e) if e2e > 0 else 0.0
     att = {
         "buckets": {b: round(s, 6) for b, s in buckets.items()},
+        "stages_s": {k: round(s, 6) for k, s in stages.items()},
+        "counts": dict(tracer.counts) if tracer is not None else {},
         "e2e_s": round(e2e, 6),
         "unaccounted_s": round(unaccounted, 6),
         "closed": closed,

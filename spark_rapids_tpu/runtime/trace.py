@@ -135,6 +135,9 @@ class Tracer:
         self.wall_s: Optional[float] = None
         self.dropped = 0
         self.events: List[Span] = []
+        # what the query's operators counted on the host (probe groups,
+        # slots probed, sorts a rung): the ledger's ``counts``
+        self.counts: Dict[str, int] = {}
         # duck-typed flight-recorder hook (runtime/attribution.py):
         # when set, every closed span also lands in the recorder's
         # bounded ring — one extra deque append, no new timers
@@ -191,6 +194,12 @@ class Tracer:
 
     def finish(self) -> None:
         self.wall_s = time.perf_counter() - self.t_start
+
+    # -- counts -------------------------------------------------------------
+    def count(self, name: str, value: int) -> None:
+        """Add to the query's count ``name``."""
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + value
 
     # -- export -------------------------------------------------------------
     def rollup(self) -> Dict[str, Dict[str, Any]]:
@@ -271,6 +280,14 @@ def span(op: str, stage: str, args: Optional[dict] = None):
     if tr is None:
         return _NULL
     return tr.span(op, stage, args)
+
+
+def count(name: str, value: int) -> None:
+    """``Tracer.count`` on the calling thread's query; nothing where
+    the thread works for no query."""
+    tr = BOOKS.tracer
+    if tr is not None:
+        tr.count(name, value)
 
 
 # ---------------------------------------------------------------------------

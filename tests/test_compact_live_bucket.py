@@ -356,9 +356,9 @@ def test_a_lone_batch_that_came_compacted_is_still_cut():
     seen = []
     real = J.TpuSortMergeJoinExec._merge_join
 
-    def spy(self, lb, rb, jt):
+    def spy(self, lb, rb, jt, probe):
         seen.append((lb.capacity, rb.capacity))
-        return real(self, lb, rb, jt)
+        return real(self, lb, rb, jt, probe)
 
     J.TpuSortMergeJoinExec._merge_join = spy
     try:

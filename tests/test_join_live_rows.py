@@ -96,9 +96,9 @@ def probes(monkeypatch):
     seen = []
     real = J.TpuSortMergeJoinExec._merge_join
 
-    def spy(self, lb, rb, jt):
+    def spy(self, lb, rb, jt, probe):
         seen.append((lb.capacity, rb.capacity))
-        return real(self, lb, rb, jt)
+        return real(self, lb, rb, jt, probe)
 
     monkeypatch.setattr(J.TpuSortMergeJoinExec, "_merge_join", spy)
     return seen
