@@ -40,6 +40,7 @@ from spark_rapids_tpu.columnar.column import (
 from spark_rapids_tpu.exec.base import CpuExec, TpuExec
 from spark_rapids_tpu.exec.basic import (
     _compact_counted, concat_device_batches)
+from spark_rapids_tpu.kernels import hash_layout as HL
 from spark_rapids_tpu.ops import ordering as ORD
 from spark_rapids_tpu.ops.expressions import Expression
 from spark_rapids_tpu.runtime import telemetry as TM
@@ -303,6 +304,16 @@ def _lex_search(sorted_limbs: List[jnp.ndarray],
     return lo
 
 
+def _slot_rows(cum: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
+    """The row of each output slot ``j``: its rank among the rows'
+    running totals ``cum`` — ``searchsorted(cum, j, side="right")`` by
+    the kernel plane's merge rank.  A total past the bucket ranks like
+    the bucket, so 32-bit keys do: half the sort's operands."""
+    bucket = int(j.shape[0])
+    return HL.rank_sorted(jnp.minimum(cum, bucket).astype(jnp.int32),
+                          j.astype(jnp.int32), side="right")
+
+
 def _expand_counts(counts: jnp.ndarray) -> Tuple[int, jnp.ndarray,
                                                  jnp.ndarray, int]:
     """counts[B] → (bucket, row_idx[bucket], offset[bucket], total).
@@ -316,7 +327,8 @@ def _expand_counts(counts: jnp.ndarray) -> Tuple[int, jnp.ndarray,
     from spark_rapids_tpu.exec.basic import warn_big_bucket
     warn_big_bucket("join expansion", bucket)
     j = jnp.arange(bucket, dtype=jnp.int64)
-    i = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+    from spark_rapids_tpu.runtime.kernel_cache import cached_kernel
+    i = cached_kernel(("join_expand_rank",), lambda: _slot_rows)(cum, j)
     i_c = jnp.clip(i, 0, max(counts.shape[0] - 1, 0))
     start = jnp.take(cum, i_c) - jnp.take(counts.astype(jnp.int64), i_c)
     off = (j - start).astype(jnp.int32)
@@ -950,8 +962,8 @@ class TpuSortMergeJoinExec(TpuExec):
 
         One cached jitted kernel per (keys, schemas, backend) triple.
         The fused/pallas rungs route through kernels.hash_join (one
-        hash limb sorted + one single-limb bisection) and fall back to
-        the exact lexicographic reference on a detected 64-bit
+        hash limb sorted, the probe merged into its order: no gather)
+        and fall back to the exact lexicographic reference on a 64-bit
         collision; the (m, lo, perm, l_null) contract is unchanged —
         within a match range both layouts enumerate the same right rows
         in the same (original-index) order, so _merge_join's output is

@@ -26,7 +26,7 @@ pallas_backend.py a line-for-line transcription.
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,31 +108,18 @@ def hash_limbs(limbs: List[jnp.ndarray],
         jnp.uint64)
 
 
-def seg_scan(values: jnp.ndarray, boundary: jnp.ndarray,
-             op) -> jnp.ndarray:
-    """Inclusive segmented scan (same combiner shape as
-    exec.aggregate.segmented_scan, local so the kernel plane stays a
-    leaf below the exec layer)."""
+def fill_next(cols, is_src: jnp.ndarray) -> tuple:
+    """Per slot, each of ``cols`` at the first slot at or after it where
+    ``is_src`` holds: a reversed keep-first scan, one pass for all the
+    columns — gather- and scatter-free (XLA scatter lowers to a serial
+    loop on TPU).  Slots past the last source get the LAST slot's values:
+    the caller masks them (``match_fused``'s count is 0 there)."""
     def comb(a, b):
-        av, af = a
-        bv, bf = b
-        return jnp.where(bf, bv, op(av, bv)), af | bf
-    v, _ = jax.lax.associative_scan(comb, (values, boundary))
-    return v
-
-
-def run_lengths(boundary: jnp.ndarray) -> jnp.ndarray:
-    """Per-row length of the row's run (``boundary`` marks run starts).
-
-    Forward segmented count, then a reversed keep-first scan broadcasts
-    each run's final count back over the whole run — scatter-free (XLA
-    scatter lowers to a serial loop on TPU).
-    """
-    n = boundary.shape[0]
-    rn = seg_scan(jnp.ones((n,), jnp.int32), boundary, jnp.add)
-    is_end = jnp.concatenate([boundary[1:], jnp.ones((1,), jnp.bool_)])
-    filled = seg_scan(rn[::-1], is_end[::-1], lambda a, b: a)
-    return filled[::-1]
+        *av, af = a
+        *bv, bf = b
+        return (*[jnp.where(bf, y, x) for x, y in zip(av, bv)], af | bf)
+    return jax.lax.associative_scan(
+        comb, (*cols, is_src), reverse=True)[:-1]
 
 
 def _adjacent_neq(limbs: List[jnp.ndarray]) -> jnp.ndarray:
@@ -145,28 +132,60 @@ def _adjacent_neq(limbs: List[jnp.ndarray]) -> jnp.ndarray:
     return neq
 
 
-def lower_bound(sorted_limb: jnp.ndarray, queries: jnp.ndarray,
-                le: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """First index whose entry is >= the query (or > when ``le[q]``).
+def merge_sorted(sorted_limb: jnp.ndarray, queries: jnp.ndarray,
+                 side: str = "left", riders=()):
+    """Table and queries stably sorted TOGETHER, once: ``(keys, perm,
+    is_tab, before, moved)`` in the merged order — the keys, each slot's
+    place in the concatenation, whether it is a table slot, the table
+    slots ahead of it (a query's RANK in the table: a prefix count, no
+    gather) and ``riders``, (query column, table column) pairs that
+    come along as payload operands.
 
-    Fixed-step branchless bisection (same shape as exec.join._lex_search
-    but over ONE limb — the whole point of the hash layout).  ``le`` is
-    a per-query flag switching to upper-bound counting.
+    Equals go by their place in the concatenation: queries first for
+    ``"left"`` (a query sorts ahead of its equals in the table: the
+    strict lower bound), table first for ``"right"``.
     """
-    import math
-    n = int(sorted_limb.shape[0])
-    steps = max(1, int(math.ceil(math.log2(max(n, 2)))) + 1)
-    lo = jnp.zeros(queries.shape, jnp.int32)
-    hi = jnp.full(queries.shape, n, jnp.int32)
-    for _ in range(steps):
-        mid = (lo + hi) >> 1
-        v = jnp.take(sorted_limb, jnp.clip(mid, 0, n - 1))
-        go_right = v < queries
-        if le is not None:
-            go_right = go_right | (le & (v == queries))
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-    return lo
+    if queries.dtype != sorted_limb.dtype:  # concatenate would promote
+        raise TypeError(f"merge_sorted: {queries.dtype} queries against "
+                        f"a {sorted_limb.dtype} table")
+    n, q = int(sorted_limb.shape[0]), int(queries.shape[0])
+    left = side == "left"
+
+    def cat(of_queries, of_table):
+        return jnp.concatenate([of_queries, of_table] if left
+                               else [of_table, of_queries])
+
+    keys, perm, *moved = jax.lax.sort(
+        (cat(queries, sorted_limb), jnp.arange(n + q, dtype=jnp.int32))
+        + tuple(cat(a, b) for a, b in riders), num_keys=1, is_stable=True)
+    is_tab = (perm >= q) if left else (perm < n)
+    tab = is_tab.astype(jnp.int32)
+    return keys, perm, is_tab, jnp.cumsum(tab) - tab, moved
+
+
+def unmerge(perm: jnp.ndarray, cols, q: int, side: str = "left") -> list:
+    """The queries' slots of merged-order ``cols``, back in query order:
+    one sort on ``merge_sorted``'s permutation (distinct keys, so no
+    stability to pay for) — the scatter-free way to invert it."""
+    n = int(perm.shape[0]) - q
+    res = jax.lax.sort((perm, *cols), num_keys=1, is_stable=False)
+    return [c[:q] if side == "left" else c[n:] for c in res[1:]]
+
+
+def rank_sorted(sorted_limb: jnp.ndarray, queries: jnp.ndarray,
+                side: str = "left") -> jnp.ndarray:
+    """``int32[q]``: per query, the table entries below it (``"left"``:
+    the lower bound) or not above it (``"right"``:
+    ``jnp.searchsorted(..., side="right")``'s integer).
+
+    A lower bound is a rank, and a rank needs no gather: ``merge_sorted``
+    counts it and ``unmerge`` brings it back to query order — two sorts
+    of n + q slots where a bisection takes ⌈log2 n⌉ + 1 dependent takes
+    of q indices (the chip's prices: docs/kernels.md, "Fused hash join").
+    """
+    q = int(queries.shape[0])
+    _, perm, _, before, _ = merge_sorted(sorted_limb, queries, side)
+    return unmerge(perm, [before], q, side)[0]
 
 
 def hash_group_layout(key_limbs: List[jnp.ndarray],

@@ -66,14 +66,25 @@ def test_hash_limbs_pallas_compiles(one_chip, limbs):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_match_fused_compiles(one_chip):
+# (key limbs L, build slots n, probe slots q): small, then a Q3 probe
+# group's shape (262 144 streamed slots against join 1's 262 144-slot
+# output) at one limb, as every cell's keys fuse, and at the two and
+# three limbs of multi-column and string keys: each limb is one more
+# payload operand of both sorts, which the TPU compiler pays for in
+# seconds (docs/kernels.md "Fused hash join" has each case's)
+@pytest.mark.parametrize("limbs,n,q", [
+    (1, SMALL, SMALL), (1, 1 << 18, 1 << 18), (2, 1 << 18, 1 << 18),
+    (3, 1 << 18, 1 << 18)])
+def test_match_fused_compiles(one_chip, limbs, n, q):
     from spark_rapids_tpu.kernels import hash_join as KNJ
 
-    def fn(l0, r0, excl):
-        return KNJ.match_fused([l0], [r0], excl, use_pallas=True)
+    def fn(excl, *ls):
+        return KNJ.match_fused(list(ls[:limbs]), list(ls[limbs:]), excl,
+                               use_pallas=True)
 
-    c = _compile(fn, one_chip, ((SMALL,), jnp.uint64),
-                 ((SMALL,), jnp.uint64), ((SMALL,), jnp.bool_))
+    c = _compile(fn, one_chip, ((n,), jnp.bool_),
+                 *[((q,), jnp.uint64)] * limbs,
+                 *[((n,), jnp.uint64)] * limbs)
     assert "tpu_custom_call" in c.as_text()
 
 

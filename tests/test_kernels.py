@@ -154,6 +154,175 @@ def test_join_unhashable_f64_returns_none():
 
 
 # ---------------------------------------------------------------------------
+# kernel-level: the probe's search is a merge rank
+# ---------------------------------------------------------------------------
+
+_TOPBIT = np.uint64(1 << 63)
+
+
+def _rank_cases():
+    """name → (sorted table, queries), both uint64: the cells' six
+    (n, q) shapes scaled down by 256, then the corners."""
+    rng = np.random.default_rng(34)
+
+    def table(n, hi=1 << 62):
+        return np.sort(rng.integers(0, hi, n, dtype=np.uint64))
+
+    def mixed(t, q):  # half the queries hit an entry, half fall between
+        return np.concatenate(
+            [rng.choice(t, q - q // 2),
+             rng.integers(0, 1 << 62, q // 2, dtype=np.uint64)])
+
+    cases = {}
+    for n, q in [(1024, 1024), (1024, 512), (1024, 256), (128, 1024),
+                 (1024, 8), (512, 512)]:
+        t = table(n)
+        cases[f"n{n}_q{q}"] = (t, mixed(t, q))
+    t = table(64)
+    cases["n1"] = (t[:1], np.array([0, t[0], t[0] + 1, 1 << 62], np.uint64))
+    cases["q1"] = (t, t[5:6])
+    cases["all_equal"] = (np.full(96, 7, np.uint64),
+                          np.array([6, 7, 7, 8], np.uint64))
+    cases["duplicates"] = (table(256, hi=16), mixed(table(8, hi=16), 300))
+    cases["queries_below"] = (t + np.uint64(10),
+                              np.arange(10, dtype=np.uint64))
+    cases["queries_above"] = (t, t[-1] + np.arange(1, 33, dtype=np.uint64))
+    # excluded build rows carry the top bit and sort after every query
+    top = np.sort(np.where(rng.random(200) < 0.4, _TOPBIT, np.uint64(0))
+                  | rng.integers(0, 1 << 62, 200, dtype=np.uint64))
+    cases["top_bit_entries"] = (top, mixed(top & ~_TOPBIT, 128))
+    return cases
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+@pytest.mark.parametrize("case", sorted(_rank_cases()))
+def test_rank_sorted_matches_bisection(case, side):
+    t, qs = _rank_cases()[case]
+    got = np.asarray(HL.rank_sorted(jnp.asarray(t), jnp.asarray(qs), side))
+    assert got.dtype == np.int32
+    assert np.array_equal(got, np.searchsorted(t, qs, side=side))
+
+
+def test_fill_next_reads_the_next_source_and_the_last_slot_past_it():
+    vals = jnp.arange(10, 18, dtype=jnp.int32)
+    src = jnp.asarray([0, 1, 0, 0, 1, 1, 0, 0], jnp.bool_)
+    (got,) = HL.fill_next([vals], src)
+    # past the last source: the LAST slot's value, the caller's to mask
+    assert np.asarray(got).tolist() == [11, 11, 14, 14, 14, 15, 17, 17]
+
+
+def test_rank_sorted_refuses_mixed_dtypes():
+    with pytest.raises(TypeError):
+        HL.rank_sorted(jnp.zeros((4,), jnp.uint64),
+                       jnp.zeros((4,), jnp.int64))
+
+
+def _jnp_rung(l_limbs, r_limbs, r_excl):
+    """exec.join._match_ranges' reference rung, outside a session."""
+    from spark_rapids_tpu.exec.join import _lex_search
+    flag = [jnp.asarray(r_excl).astype(jnp.uint64)]
+    sorted_limbs, perm = ORD.sort_by_keys(flag + list(r_limbs))
+    q = [jnp.zeros_like(l_limbs[0])] + list(l_limbs)
+    lo = _lex_search(sorted_limbs, q, "left")
+    return _lex_search(sorted_limbs, q, "right") - lo, lo, perm
+
+
+def _match_by_numpy(l_limbs, r_limbs, r_excl):
+    """``match_fused``'s (m, lo, perm) as the kernel gave them while its
+    search was a bisection and its reads were takes: the build side
+    stably sorted by the hash limb, the lower bound, the run's length
+    where hash and key limbs agree at the run start."""
+    ll = [np.asarray(l) for l in l_limbs]
+    rl = [np.asarray(l) for l in r_limbs]
+    n = rl[0].shape[0]
+    h_r = np.asarray(HL.hash_limbs(r_limbs)) >> np.uint64(1)
+    h_q = np.asarray(HL.hash_limbs(l_limbs)) >> np.uint64(1)
+    build = np.where(r_excl, h_r | _TOPBIT, h_r)
+    perm = np.argsort(build, kind="stable").astype(np.int32)
+    sorted_h = build[perm]
+    lo = np.searchsorted(sorted_h, h_q, side="left").astype(np.int32)
+    loc = np.clip(lo, 0, n - 1)
+    hit = (sorted_h[loc] == h_q) & (lo < n)
+    for r, l in zip(rl, ll):
+        hit &= r[perm][loc] == l
+    rlen = np.searchsorted(sorted_h, h_q, side="right") - lo
+    return np.where(hit, rlen, 0).astype(np.int32), lo, perm
+
+
+@pytest.mark.parametrize("q_of_n", [1, 0.5, 8], ids=["q=n", "q=n/2", "q=8n"])
+def test_match_fused_against_bisection_and_jnp_rung(q_of_n):
+    n = 384
+    q = int(n * q_of_n)
+    rng = np.random.default_rng(q)
+    r_limbs = [_limb(rng.integers(0, 90, n)), _limb(rng.integers(0, 3, n))]
+    l_limbs = [_limb(rng.integers(0, 100, q)), _limb(rng.integers(0, 3, q))]
+    r_excl = rng.random(n) < 0.3
+    m, lo, perm, ok = KNJ.match_fused(l_limbs, r_limbs, jnp.asarray(r_excl))
+    assert bool(ok)
+    mm, ll, pp = np.asarray(m), np.asarray(lo), np.asarray(perm)
+    # the kernel as it was: element for element
+    m0, lo0, perm0 = _match_by_numpy(l_limbs, r_limbs, r_excl)
+    assert mm.dtype == m0.dtype and ll.dtype == lo0.dtype
+    assert np.array_equal(mm, m0)
+    assert np.array_equal(ll, lo0)
+    assert np.array_equal(pp, perm0)
+    # the jnp rung orders the build side by key, not by hash: the same
+    # counts, and under each range the same right rows in the same order
+    mj, loj, permj = (np.asarray(x)
+                      for x in _jnp_rung(l_limbs, r_limbs, r_excl))
+    assert np.array_equal(mm, mj)
+    for i in np.flatnonzero(mm):
+        assert np.array_equal(pp[ll[i] + np.arange(mm[i])],
+                              permj[loj[i] + np.arange(mm[i])]), i
+
+
+def test_match_fused_rejects_a_hash_only_hit(monkeypatch):
+    # every row hashes alike: only the key limbs brought along the
+    # merged order tell a match from a collision (the build side's two
+    # distinct keys under one hash also turn `ok` off)
+    monkeypatch.setattr(
+        HL, "hash_limbs",
+        lambda limbs, use_pallas=False: jnp.zeros(
+            (int(limbs[0].shape[0]),), jnp.uint64))
+    m, lo, perm, ok = KNJ.match_fused(
+        [_limb([5, 7, 9, 7])], [_limb([7, 7, 7])],
+        jnp.zeros((3,), jnp.bool_))
+    assert np.asarray(m).tolist() == [0, 3, 0, 3]
+    assert np.asarray(lo).tolist() == [0, 0, 0, 0]
+    assert bool(ok)
+
+
+def _count_gathers(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "gather"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _count_gathers(inner)
+    return n
+
+
+@pytest.mark.parametrize("limbs", [1, 2])
+def test_match_fused_gathers_do_not_grow_with_n(limbs):
+    """The 19 dependent takes of the bisection are gone: the probe's
+    gather count is the same at 2^10 and 2^18 slots (traced only)."""
+    import jax
+
+    def gathers(n):
+        u64 = jax.ShapeDtypeStruct((n,), jnp.uint64)
+        args = ([u64] * limbs, [u64] * limbs,
+                jax.ShapeDtypeStruct((n,), jnp.bool_))
+        return _count_gathers(jax.make_jaxpr(KNJ.match_fused)(*args).jaxpr)
+
+    small, large = gathers(1 << 10), gathers(1 << 18)
+    assert small == large
+    assert large <= 2 * limbs + 4
+    assert large == 0  # since the probe's reads ride the merged order
+
+
+# ---------------------------------------------------------------------------
 # kernel-level: hash agg layout + collision detection
 # ---------------------------------------------------------------------------
 

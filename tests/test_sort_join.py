@@ -212,3 +212,47 @@ def test_join_skewed_duplicate_keys():
     assert_tpu_and_cpu_are_equal_collect(
         lambda s: s.createDataFrame(l).join(s.createDataFrame(r), "k"),
         ignore_order=True)
+
+
+def _expand_counts_bisected(counts):
+    """``exec.join._expand_counts`` as it stood before the merge rank:
+    the row of every output slot by ``jnp.searchsorted``."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.basic import round_up_pow2
+    cum = jnp.cumsum(counts.astype(jnp.int64))
+    total = int(cum[-1])
+    bucket = round_up_pow2(max(total, 1))
+    j = jnp.arange(bucket, dtype=jnp.int64)
+    i = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
+    i_c = jnp.clip(i, 0, max(counts.shape[0] - 1, 0))
+    start = jnp.take(cum, i_c) - jnp.take(counts.astype(jnp.int64), i_c)
+    return bucket, i_c, (j - start).astype(jnp.int32), total
+
+
+def _count_vectors():
+    rng = np.random.default_rng(34)
+    over = np.zeros(50, np.int32)
+    over[[3, 17, 40]] = [100, 20, 9]  # total 129: just over 128
+    return {
+        "random_with_zeros": rng.poisson(0.8, 300).astype(np.int32),
+        "all_zero": np.zeros(64, np.int32),
+        "one_row": np.array([5], np.int32),
+        "one_row_no_match": np.array([0], np.int32),
+        "total_just_over_pow2": over,
+        "selective": (rng.random(1024) < 0.01).astype(np.int32),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_count_vectors()))
+def test_expand_counts_equals_bisection(case):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.join import _expand_counts
+    from spark_rapids_tpu.runtime.device import ensure_initialized
+    ensure_initialized()  # x64 on, as every session has it
+    counts = jnp.asarray(_count_vectors()[case])
+    bucket, i_c, off, total = _expand_counts(counts)
+    bucket0, i_c0, off0, total0 = _expand_counts_bisected(counts)
+    assert (bucket, total) == (bucket0, total0)
+    assert i_c.dtype == i_c0.dtype and off.dtype == off0.dtype
+    assert np.array_equal(np.asarray(i_c), np.asarray(i_c0))
+    assert np.array_equal(np.asarray(off), np.asarray(off0))
