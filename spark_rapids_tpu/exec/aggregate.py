@@ -39,6 +39,12 @@ from spark_rapids_tpu.ops.aggregates import (
     _VarianceBase)
 from spark_rapids_tpu.ops.expressions import Expression
 from spark_rapids_tpu.plan import logical as L
+from spark_rapids_tpu.runtime import telemetry as TM
+
+_TM_REPART_BUCKETS = TM.REGISTRY.counter(
+    "tpuq_agg_repartition_buckets_total",
+    "buckets the aggregates' repartition merges cut their partials "
+    "into (the sum of aggRepartitionBuckets)")
 
 
 # ---------------------------------------------------------------------------
@@ -1049,7 +1055,7 @@ class TpuHashAggregateExec(TpuExec):
                 max_attempts=mgr.retry_max_attempts, manager=mgr))
             skip = self._decide_skip(outs1, n_in)
         if skip:
-            self.metric("skippedAggPasses").add(1)
+            self.count("skippedAggPasses", 1)
 
         def closure(b):
             with mgr.transient(b.nbytes()):
@@ -1122,6 +1128,7 @@ class TpuHashAggregateExec(TpuExec):
         from spark_rapids_tpu.columnar.column import compact
         from spark_rapids_tpu.exec.basic import _overlapped_live_counts
         partials = [compact(p) for p in partials]
+        self.count("aggPartials", len(partials))
         if len(partials) == 1:
             return [merge_fn(partials[0])]
         schema = self._buffer_schema()
@@ -1129,6 +1136,7 @@ class TpuHashAggregateExec(TpuExec):
             return [merge_fn(concat_device_batches(schema, partials))]
         counts = _overlapped_live_counts(partials)
         total = sum(counts)
+        self.count("aggPartialRows", total)
         cap = max(b.capacity for b in partials)
         if total <= 2 * cap:
             return [merge_fn(concat_device_batches(schema, partials,
@@ -1151,10 +1159,13 @@ class TpuHashAggregateExec(TpuExec):
         # path uses its own SUB_SEED.
         AGG_SEED = 0x41475242
         pid_fn = make_pid_fn(keys, k, seed=AGG_SEED)
-        slices = split_to_spillables(
-            partials, lambda b, aux: pid_fn(b), k, mgr,
-            ("aggrepart", k, AGG_SEED, fingerprint(keys),
-             fingerprint(schema)))
+        self.count("aggRepartitionBuckets", k)
+        _TM_REPART_BUCKETS.inc(k)
+        with self.timer("repartitionTime"):
+            slices = split_to_spillables(
+                partials, lambda b, aux: pid_fn(b), k, mgr,
+                ("aggrepart", k, AGG_SEED, fingerprint(keys),
+                 fingerprint(schema)))
         out = []
         for i in range(k):
             if not slices[i]:

@@ -55,6 +55,7 @@ from spark_rapids_tpu.columnar.column import (
 from spark_rapids_tpu.ops import hashing as HH
 from spark_rapids_tpu.ops.expressions import Expression
 from spark_rapids_tpu.runtime import telemetry as TM
+from spark_rapids_tpu.runtime import trace
 
 # one increment per SPMD program *build* — each build is a fresh XLA
 # compilation candidate, so a growing rate flags shape-bucket churn
@@ -64,6 +65,10 @@ _TM_ICI_PROGRAMS = TM.REGISTRY.counter(
 _TM_ICI_EX_PROGRAMS = TM.REGISTRY.counter(
     "tpuq_ici_exchange_programs_built_total",
     "compiled-exchange SPMD programs constructed (prepare + boundary)")
+_TM_SPILLABLE_BYTES = TM.REGISTRY.counter(
+    "tpuq_spillable_bytes_total",
+    "bytes of the slices the out-of-core split registered as "
+    "spillable (the sum of spillableBytes)")
 
 
 def _hash_f64_tpu_safe(data: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
@@ -460,6 +465,33 @@ def shard_batch(mesh: jax.sharding.Mesh, batch: DeviceBatch) -> DeviceBatch:
     return jax.device_put(batch, named_sharding(mesh))
 
 
+def _split_sort(ids_fn, nbuckets: int):
+    """The split's counting sort of one chunk: rows grouped by bucket
+    id, and the live rows of every bucket."""
+    def run(m, aux):
+        pid = ids_fn(m, aux)
+        pid_s, perm = _sorted_pids(m, pid, nbuckets)
+        bounds = _partition_bounds(pid_s, nbuckets)
+        cols = tuple(c.gather(perm) for c in m.columns)
+        sel = (jnp.arange(m.capacity, dtype=jnp.int32)
+               < bounds[-1])
+        counts = bounds[1:] - bounds[:-1]
+        return DeviceBatch(m.schema, cols, sel,
+                           compacted=True), counts
+    return run
+
+
+def _split_cut(size: int):
+    """One bucket's run of a sorted chunk, at its pow-2 slice size."""
+    def run(m, lo, count):
+        idx = jnp.clip(lo + jnp.arange(size, dtype=jnp.int32),
+                       0, m.capacity - 1)
+        cols = tuple(c.gather(idx) for c in m.columns)
+        sel = jnp.arange(size, dtype=jnp.int32) < count
+        return DeviceBatch(m.schema, cols, sel, compacted=True)
+    return run
+
+
 def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
                         aux=None, chunk_rows: int = 1 << 20):
     """Bucket-split batches and register each slice as an unreserved
@@ -486,7 +518,7 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
     Chunk coalescing keeps concat order identical to the in-core path
     (the counting sort is stable, so intra-bucket order is input
     order)."""
-    from spark_rapids_tpu.columnar.column import DeviceBatch, compact
+    from spark_rapids_tpu.columnar.column import compact
     from spark_rapids_tpu.exec.basic import concat_device_batches
     from spark_rapids_tpu.runtime.kernel_cache import (
         cached_kernel, fingerprint)
@@ -504,28 +536,6 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
     chunk_rows = min(chunk_rows,
                      1 << max(10, budget_rows.bit_length() - 1))
 
-    def build_sort():
-        def run(m, aux):
-            pid = ids_fn(m, aux)
-            pid_s, perm = _sorted_pids(m, pid, nbuckets)
-            bounds = _partition_bounds(pid_s, nbuckets)
-            cols = tuple(c.gather(perm) for c in m.columns)
-            sel = (jnp.arange(m.capacity, dtype=jnp.int32)
-                   < bounds[-1])
-            counts = bounds[1:] - bounds[:-1]
-            return DeviceBatch(m.schema, cols, sel,
-                               compacted=True), counts
-        return run
-
-    def build_cut(size):
-        def run(m, lo, count):
-            idx = jnp.clip(lo + jnp.arange(size, dtype=jnp.int32),
-                           0, m.capacity - 1)
-            cols = tuple(c.gather(idx) for c in m.columns)
-            sel = jnp.arange(size, dtype=jnp.int32) < count
-            return DeviceBatch(m.schema, cols, sel, compacted=True)
-        return run
-
     while batches:
         chunk, acc = [], 0
         while batches and (not chunk
@@ -536,9 +546,11 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
         merged = (chunk[0] if len(chunk) == 1 else
                   concat_device_batches(schema, chunk))
         del chunk
-        sort_fn = cached_kernel(("split_sort",) + base_key, build_sort)
+        sort_fn = cached_kernel(("split_sort",) + base_key,
+                                lambda: _split_sort(ids_fn, nbuckets))
         laid, counts = sort_fn(merged, aux)
         counts = np.asarray(counts)  # the chunk's ONE host sync
+        trace.count("splitChunks", 1)
         offs = np.concatenate([[0], np.cumsum(counts)])
         for i in range(nbuckets):
             n = int(counts[i])
@@ -547,13 +559,16 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
             size = max(8, 1 << (n - 1).bit_length())
             cut_fn = cached_kernel(
                 ("split_cut", size) + base_key,
-                lambda s=size: build_cut(s))
+                lambda s=size: _split_cut(s))
             part = cut_fn(laid, int(offs[i]), n)
             sp = SpillableBatch(part, mgr, reserve=False)
             # the split KNOWS each slice's live count — downstream
             # concats read it instead of paying a device round trip
             sp.live_rows = n
             out[i].append(sp)
+            trace.count("spillableSlices", 1)
+            trace.count("spillableBytes", sp.nbytes)
+            _TM_SPILLABLE_BYTES.inc(sp.nbytes)
         del laid, merged
     return out
 

@@ -132,6 +132,7 @@ STAGE_BUCKETS: Dict[str, Optional[str]] = {
     "broadcastTime": "kernel_dispatch",
     "partialTime": "kernel_dispatch",
     "mergeTime": "kernel_dispatch",
+    "repartitionTime": "kernel_dispatch",
     "measureTime": "kernel_dispatch",
     "decideTime": "kernel_dispatch",
     "compile": "compile",

@@ -659,6 +659,7 @@ class DeviceMemoryManager:
             self._host_used += nbytes
             self.metrics["spillToHostBytes"] += nbytes
             _TM_SPILL_HOST.inc(nbytes)
+            trace.count("spilledBytes", nbytes)
             while self._host_used > self.host_limit:
                 victim = next(
                     (v for v in self._spillables.values()

@@ -159,3 +159,47 @@ def test_q6_step_compiles(one_chip):
     fn, args = G.entry()
     shapes = [((BATCH_ROWS,), np.asarray(a).dtype) for a in args]
     _compile(fn, one_chip, *shapes)
+
+
+def _q18_partials(one_chip, rows):
+    """The buffer batch of Q18's sub-aggregate: the order's key, the
+    sum's double and its count of non-null addends."""
+    from spark_rapids_tpu.columnar import column as C
+    from spark_rapids_tpu.columnar import dtypes as T
+
+    def leaf(dt):
+        return jax.ShapeDtypeStruct((rows,), dt, sharding=one_chip)
+
+    kinds = [("k0", T.LongT, jnp.int64), ("b0", T.DoubleT, jnp.float64),
+             ("b1", T.LongT, jnp.int64)]
+    batch = C.DeviceBatch(
+        T.StructType(tuple(T.StructField(n, t, True) for n, t, _ in kinds)),
+        tuple(C.DeviceColumn(t, leaf(d), leaf(jnp.bool_))
+              for _, t, d in kinds),
+        leaf(jnp.bool_), compacted=True)
+    return batch, leaf
+
+
+def test_split_sort_compiles(one_chip):
+    # the repartition merge's split of one 1 M-slot partial into Q18's
+    # five buckets: murmur3 of the key under x64, the 2-operand sort,
+    # the bounds' search and one take a leaf
+    from spark_rapids_tpu.columnar import dtypes as T
+    from spark_rapids_tpu.ops.expressions import BoundReference
+    from spark_rapids_tpu.parallel import shuffle as S
+    batch, leaf = _q18_partials(one_chip, BATCH_ROWS)
+    pid_fn = S.make_pid_fn([BoundReference(0, T.LongT)], 5,
+                           seed=0x41475242)
+    c = jax.jit(S._split_sort(lambda b, aux: pid_fn(b), 5)).lower(
+        batch, None).compile()
+    assert " sort(" in c.as_text()
+
+
+@pytest.mark.parametrize("size", [1 << 17, 1 << 18])
+def test_split_cut_compiles(one_chip, size):
+    # a bucket's run of the sorted chunk at its power-of-two slice: a
+    # fifth of 754 k live rows (262 144) and of the last batch's 594 k
+    from spark_rapids_tpu.parallel import shuffle as S
+    batch, leaf = _q18_partials(one_chip, BATCH_ROWS)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    jax.jit(S._split_cut(size)).lower(batch, scalar, scalar).compile()

@@ -265,3 +265,85 @@ def test_skewed_sub_partition_recurses_and_matches():
             lambda s: s.createDataFrame(left).join(
                 s.createDataFrame(right), "k", "inner"),
             conf=conf, ignore_order=True, approx_float=True)
+
+
+def _merge_tables(kind, n=40_000, seed=71):
+    rng = np.random.default_rng(seed)
+    k = rng.permutation(n) // 2                  # two rows a key
+    cols = {"v": pa.array(rng.uniform(1, 50, n)),
+            "w": pa.array(rng.integers(0, 100, n))}
+    if kind == "long":
+        return pa.table({"k": pa.array(k), **cols}), ["k"]
+    if kind == "string":
+        names = np.char.add("Customer#", np.char.zfill(k.astype(str), 9))
+        return pa.table({"name": pa.array(names.tolist()), **cols}), ["name"]
+    return pa.table({"name": pa.array([f"c{i % 7}" for i in k]),
+                     "k": pa.array(k), **cols}), ["name", "k"]
+
+
+@pytest.mark.parametrize("kind", ["long", "string", "string_and_long"])
+def test_merge_bounded_one_concat_equals_the_repartition_fallback(
+        kind, monkeypatch):
+    """``_merge_bounded``'s two branches on the same partials: merged by
+    one concat (what it does while they fit twice a batch) and by the
+    repartition fallback (what it does here, over that), they give the
+    same groups, sums and counts; the fallback's books say how it cut
+    them."""
+    from spark_rapids_tpu.columnar.column import device_to_host
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.basic import concat_device_batches
+    from spark_rapids_tpu.runtime import attribution
+    t, keys = _merge_tables(kind)
+    real = TpuHashAggregateExec._merge_bounded
+    seen = {}
+
+    def both(self, partials, merge_fn):
+        seen["partials"] = len(partials)
+        seen["one"] = device_to_host(merge_fn(concat_device_batches(
+            self._buffer_schema(), list(partials))))
+        out = real(self, partials, merge_fn)
+        seen["many"] = pa.concat_tables([device_to_host(b) for b in out])
+        return out
+
+    monkeypatch.setattr(TpuHashAggregateExec, "_merge_bounded", both)
+    s = tpu_session({"spark.rapids.tpu.batchRows": 4096})
+    df = (s.createDataFrame(t).groupBy(*keys)
+          .agg(F.sum("v").alias("sv"), F.sum("w").alias("sw"),
+               F.count("*").alias("c")))
+    out = df.toArrow()
+    agg = _find(df._last_plan, "TpuHashAggregateExec")
+    counts = attribution.recent()[-1]["counts"]
+    # 40 000 rows in ten batches: the first keeps 0.95 groups a row,
+    # over the ratio at which passes are skipped, so the nine after it
+    # hand the merge their rows as they are
+    assert seen["partials"] == counts["aggPartials"] == 10
+    assert counts["skippedAggPasses"] == 1
+    first = len(set(t.column(keys[-1]).to_pylist()[:4096]))
+    assert 0.9 * 4096 < first < 4096
+    assert counts["aggPartialRows"] == first + 40_000 - 4096
+    k = -(-counts["aggPartialRows"] // 4096)
+    assert agg.metric("repartitionMerges").value == 1
+    assert counts["aggRepartitionBuckets"] == k
+    assert counts["splitChunks"] == 1
+    assert counts["spillableSlices"] == k
+    order = [(name, "ascending") for name in keys]
+    one, many = seen["one"].sort_by(order), seen["many"].sort_by(order)
+    assert one.num_rows == many.num_rows == out.num_rows == 20_000
+    for name in one.column_names:
+        if name == "sv":        # two addends a group, in either order
+            np.testing.assert_allclose(one.column(name).to_numpy(),
+                                       many.column(name).to_numpy(),
+                                       rtol=1e-15)
+        else:
+            assert one.column(name).equals(many.column(name)), name
+
+
+def test_what_the_arbiter_spills_inside_a_query_is_in_its_books():
+    from spark_rapids_tpu.runtime import attribution
+    t = _sort_table()
+    s = tpu_session({"spark.rapids.tpu.memory.poolSize": 400 << 10,
+                     "spark.rapids.tpu.batchRows": 8192})
+    assert s.createDataFrame(t).orderBy("a", "b").toArrow().num_rows == 60_000
+    spilled = M.get_manager().metrics["spillToHostBytes"]
+    assert spilled > 0
+    assert attribution.recent()[-1]["counts"]["spilledBytes"] == spilled
