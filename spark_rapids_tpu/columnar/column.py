@@ -83,8 +83,11 @@ class DeviceColumn:
                             self.evalid)
 
     def gather(self, idx: jax.Array) -> "DeviceColumn":
-        """Row gather, one take a leaf (sort, window, shuffle; compaction
-        moves its rows through ``ops.ordering.take_rows``)."""
+        """Row gather, one take a leaf: what ``sort_batch``, the window's
+        partition sort, ``explode``'s row index, the join's output
+        gather and ``partition_layout`` still move rows by (compaction
+        and the out-of-core split go through ``ops.ordering.take_rows``:
+        docs/kernels.md "Moving rows")."""
         data = jnp.take(self.data, idx, axis=0)
         validity = None if self.validity is None else jnp.take(self.validity, idx)
         lengths = None if self.lengths is None else jnp.take(self.lengths, idx)

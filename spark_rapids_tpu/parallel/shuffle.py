@@ -54,6 +54,7 @@ from spark_rapids_tpu.columnar.column import (
     DeviceBatch, DeviceColumn, round_up_pow2)
 from spark_rapids_tpu.ops import hashing as HH
 from spark_rapids_tpu.ops.expressions import Expression
+from spark_rapids_tpu.ops.ordering import take_rows
 from spark_rapids_tpu.runtime import telemetry as TM
 from spark_rapids_tpu.runtime import trace
 
@@ -472,7 +473,9 @@ def _split_sort(ids_fn, nbuckets: int):
         pid = ids_fn(m, aux)
         pid_s, perm = _sorted_pids(m, pid, nbuckets)
         bounds = _partition_bounds(pid_s, nbuckets)
-        cols = tuple(c.gather(perm) for c in m.columns)
+        # every leaf in ONE packed row gather (it pays per index)
+        leaves, columns = jax.tree_util.tree_flatten(m.columns)
+        cols = columns.unflatten(take_rows(leaves, perm))
         sel = (jnp.arange(m.capacity, dtype=jnp.int32)
                < bounds[-1])
         counts = bounds[1:] - bounds[:-1]
@@ -484,9 +487,10 @@ def _split_sort(ids_fn, nbuckets: int):
 def _split_cut(size: int):
     """One bucket's run of a sorted chunk, at its pow-2 slice size."""
     def run(m, lo, count):
-        idx = jnp.clip(lo + jnp.arange(size, dtype=jnp.int32),
-                       0, m.capacity - 1)
-        cols = tuple(c.gather(idx) for c in m.columns)
+        # a slice, not a gather; padded as ``lo + size`` may pass the end
+        cols = jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(
+            jnp.pad(x, ((0, size),) + ((0, 0),) * (x.ndim - 1)), lo, size),
+            m.columns)
         sel = jnp.arange(size, dtype=jnp.int32) < count
         return DeviceBatch(m.schema, cols, sel, compacted=True)
     return run
@@ -503,7 +507,7 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
     killer.  Instead the batches coalesce into ≤``chunk_rows`` chunks
     and each chunk runs ONE cached counting-sort kernel (rows grouped
     by bucket id + per-bucket counts), ONE [nbuckets] host sync, and
-    one cached gather per non-empty bucket (cut kernels cached per
+    one cached slice per non-empty bucket (cut kernels cached per
     pow-2 slice size, so the compile set is tiny and shared).
 
     ``key`` must fingerprint ``ids_fn``'s behavior (the kernels are
@@ -528,9 +532,9 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
         return out
     schema = batches[0].schema
     base_key = ("split", nbuckets, fingerprint(schema)) + tuple(key)
-    # this path usually runs AFTER a RetryOOM: the chunk (plus its
-    # sorted copy) must fit the arbiter budget, so cap chunk rows by
-    # the estimated row width
+    # this path usually runs AFTER a RetryOOM: the chunk, its sorted
+    # copy and the packed matrices between (2.5-3.6 x the chunk on a
+    # v5e) must fit the arbiter budget, so cap chunk rows by row width
     row_b = max(1, batches[0].nbytes() // max(batches[0].capacity, 1))
     budget_rows = max(1024, int(mgr.budget) // (4 * row_b))
     chunk_rows = min(chunk_rows,
@@ -551,6 +555,8 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
         laid, counts = sort_fn(merged, aux)
         counts = np.asarray(counts)  # the chunk's ONE host sync
         trace.count("splitChunks", 1)
+        # the chunk's capacity by construction, not an observation
+        trace.count("splitSlotsGathered", merged.capacity)
         offs = np.concatenate([[0], np.cumsum(counts)])
         for i in range(nbuckets):
             n = int(counts[i])

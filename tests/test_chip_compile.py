@@ -161,45 +161,88 @@ def test_q6_step_compiles(one_chip):
     _compile(fn, one_chip, *shapes)
 
 
-def _q18_partials(one_chip, rows):
-    """The buffer batch of Q18's sub-aggregate: the order's key, the
-    sum's double and its count of non-null addends."""
+def _nullable_batch(one_chip, rows, kinds):
+    """A compacted batch of nullable columns, (name, type, leaf dtype or
+    a string's byte width) each."""
     from spark_rapids_tpu.columnar import column as C
     from spark_rapids_tpu.columnar import dtypes as T
 
-    def leaf(dt):
-        return jax.ShapeDtypeStruct((rows,), dt, sharding=one_chip)
+    def leaf(dt, *width):
+        return jax.ShapeDtypeStruct((rows,) + width, dt, sharding=one_chip)
 
-    kinds = [("k0", T.LongT, jnp.int64), ("b0", T.DoubleT, jnp.float64),
-             ("b1", T.LongT, jnp.int64)]
-    batch = C.DeviceBatch(
+    def column(t, d):
+        if isinstance(d, int):
+            return C.DeviceColumn(t, leaf(jnp.uint8, d), leaf(jnp.bool_),
+                                  leaf(jnp.int32))
+        return C.DeviceColumn(t, leaf(d), leaf(jnp.bool_))
+
+    return C.DeviceBatch(
         T.StructType(tuple(T.StructField(n, t, True) for n, t, _ in kinds)),
-        tuple(C.DeviceColumn(t, leaf(d), leaf(jnp.bool_))
-              for _, t, d in kinds),
+        tuple(column(t, d) for _, t, d in kinds),
         leaf(jnp.bool_), compacted=True)
-    return batch, leaf
 
 
-def test_split_sort_compiles(one_chip):
-    # the repartition merge's split of one 1 M-slot partial into Q18's
-    # five buckets: murmur3 of the key under x64, the 2-operand sort,
-    # the bounds' search and one take a leaf
+def _q18_partials(one_chip, rows):
+    """The buffer batch of Q18's sub-aggregate: the order's key, the
+    sum's double and its count of non-null addends."""
+    from spark_rapids_tpu.columnar import dtypes as T
+    return _nullable_batch(one_chip, rows, [
+        ("k0", T.LongT, jnp.int64), ("b0", T.DoubleT, jnp.float64),
+        ("b1", T.LongT, jnp.int64)])
+
+
+def _q18_final_partials(one_chip, rows):
+    """The buffer batch of Q18's final aggregate: five keys, the
+    customer's name (18 bytes at a width of 32) among them."""
+    from spark_rapids_tpu.columnar import dtypes as T
+    return _nullable_batch(one_chip, rows, [
+        ("k0", T.StringT, 32), ("k1", T.LongT, jnp.int64),
+        ("k2", T.LongT, jnp.int64), ("k3", T.DateT, jnp.int32),
+        ("k4", T.DoubleT, jnp.float64), ("b0", T.DoubleT, jnp.float64),
+        ("b1", T.LongT, jnp.int64)])
+
+
+def _row_gathers(compiled, rows):
+    """Gathers of the compiled text that move ``rows`` rows."""
+    return [line for line in compiled.as_text().splitlines()
+            if " gather(" in line
+            and f"[{rows}" in line.split(" gather(")[0]]
+
+
+# a 1 M-slot partial of the sub-aggregate into Q18's five buckets, and
+# the final aggregate's 24 partials of 2 048 slots coalesced into one
+# chunk of 32 768
+@pytest.mark.parametrize("partials,rows,key", [
+    (_q18_partials, BATCH_ROWS, 0), (_q18_final_partials, 1 << 15, 2)])
+def test_split_sort_compiles(one_chip, partials, rows, key):
+    # the repartition merge's split: murmur3 of the key under x64, the
+    # 2-operand sort, the bounds' search and one packed row gather
     from spark_rapids_tpu.columnar import dtypes as T
     from spark_rapids_tpu.ops.expressions import BoundReference
     from spark_rapids_tpu.parallel import shuffle as S
-    batch, leaf = _q18_partials(one_chip, BATCH_ROWS)
-    pid_fn = S.make_pid_fn([BoundReference(0, T.LongT)], 5,
+    batch = partials(one_chip, rows)
+    pid_fn = S.make_pid_fn([BoundReference(key, T.LongT)], 5,
                            seed=0x41475242)
     c = jax.jit(S._split_sort(lambda b, aux: pid_fn(b), 5)).lower(
         batch, None).compile()
     assert " sort(" in c.as_text()
+    # what take_rows needs: one word matrix, and the doubles' matrix is
+    # two f32 on the chip; no leaf moves by itself
+    assert len(_row_gathers(c, rows)) == 3
 
 
-@pytest.mark.parametrize("size", [1 << 17, 1 << 18])
-def test_split_cut_compiles(one_chip, size):
-    # a bucket's run of the sorted chunk at its power-of-two slice: a
-    # fifth of 754 k live rows (262 144) and of the last batch's 594 k
+# a bucket's run of the sorted chunk at its power-of-two slice: a fifth
+# of 754 k live rows (262 144) and of the last batch's 594 k; a twelfth
+# of the final aggregate's ≈ 23 k rows
+@pytest.mark.parametrize("partials,rows,size", [
+    (_q18_partials, BATCH_ROWS, 1 << 17),
+    (_q18_partials, BATCH_ROWS, 1 << 18),
+    (_q18_final_partials, 1 << 15, 1 << 12)])
+def test_split_cut_compiles(one_chip, partials, rows, size):
     from spark_rapids_tpu.parallel import shuffle as S
-    batch, leaf = _q18_partials(one_chip, BATCH_ROWS)
+    batch = partials(one_chip, rows)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    jax.jit(S._split_cut(size)).lower(batch, scalar, scalar).compile()
+    c = jax.jit(S._split_cut(size)).lower(batch, scalar, scalar).compile()
+    # a contiguous run is sliced out of the chunk, never gathered
+    assert " gather(" not in c.as_text()
+    assert "dynamic-slice(" in c.as_text()
