@@ -65,16 +65,17 @@ class Metric:
 
 class MetricTimer:
     """Times into a Metric and, when a query tracer is active, opens a
-    span (op=owning exec, stage=metric name) — every existing timer site
-    (opTime, transferTime, collectiveTime, ...) becomes a trace range
-    with zero per-site changes, the NVTX-with-metrics pairing of the
-    reference."""
+    span (op=owning exec, stage=metric name, booked under the exec's
+    plan node) — every existing timer site (opTime, transferTime,
+    collectiveTime, ...) becomes a trace range with zero per-site
+    changes, the NVTX-with-metrics pairing of the reference."""
 
-    __slots__ = ("metric", "op", "_t0", "_tr", "_span")
+    __slots__ = ("metric", "op", "node", "_t0", "_tr", "_span")
 
-    def __init__(self, metric: Metric, op: str = None):
+    def __init__(self, metric: Metric, op: str = None, node=None):
         self.metric = metric
         self.op = op
+        self.node = node
         self._tr = None
         self._span = None
 
@@ -83,7 +84,8 @@ class MetricTimer:
             tr = trace.current()
             if tr is not None:
                 self._tr = tr
-                self._span = tr.begin(self.op, self.metric.name)
+                self._span = tr.begin(self.op, self.metric.name,
+                                      node=self.node)
         self._t0 = time.perf_counter()
         return self
 
@@ -106,7 +108,7 @@ def _traced_pump(node: "ExecNode", partition: int, it: Iterator) -> Iterator:
         if tr is None:  # tracer closed mid-pump (leaked iterator)
             yield from it
             return
-        sp = tr.begin(op, "pump", {"partition": partition})
+        sp = tr.begin(op, "pump", {"partition": partition}, node)
         try:
             batch = next(it)
         except StopIteration:
@@ -258,14 +260,14 @@ class ExecNode:
         return m
 
     def timer(self, name: str = "opTime") -> MetricTimer:
-        return MetricTimer(self.metric(name), op=self.name)
+        return MetricTimer(self.metric(name), op=self.name, node=self)
 
     def count(self, name: str, value: int) -> None:
         """A count of this operator's work the host knows: into the
         node's metric and the books of the query in flight (the
-        ledger's ``counts``)."""
+        ledger's ``counts``, and this node's in its ``nodes``)."""
         self.metric(name).add(value)
-        trace.count(name, value)
+        trace.count(name, value, self)
 
     def num_partitions(self) -> int:
         if self._children:

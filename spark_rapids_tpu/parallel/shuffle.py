@@ -555,8 +555,6 @@ def split_to_spillables(batches, ids_fn, nbuckets: int, mgr, key: tuple,
         laid, counts = sort_fn(merged, aux)
         counts = np.asarray(counts)  # the chunk's ONE host sync
         trace.count("splitChunks", 1)
-        # the chunk's capacity by construction, not an observation
-        trace.count("splitSlotsGathered", merged.capacity)
         offs = np.concatenate([[0], np.cumsum(counts)])
         for i in range(nbuckets):
             n = int(counts[i])

@@ -208,20 +208,21 @@ def span_bucket(op: str, stage: str) -> Optional[str]:
 # The ledger fold
 # ---------------------------------------------------------------------------
 
-def _project(intervals: List[Tuple[float, float, int, str]],
-             t0: float, t1: float) -> Tuple[List[float], Dict[str, float]]:
+def _project(intervals: List[Tuple[float, float, int, int]], nkeys: int,
+             t0: float, t1: float) -> Tuple[List[float], List[float]]:
     """Charge the [t0, t1] timeline to buckets by priority sweep.
 
-    ``intervals`` is (start, end, priority_index, stage_key); returns
+    ``intervals`` is (start, end, priority_index, key_index); returns
     seconds per ``BUCKET_PRIORITY`` index and, from the same sweep,
-    seconds per stage key.  At each elementary segment between boundary
-    points the highest-priority active bucket (lowest index) wins, and
-    within it the span opened last (the innermost on its thread), so
-    both results are exclusive by construction, sum to at most
-    (t1 - t0), and the stages of one bucket add up to the bucket."""
+    seconds per key index (``nkeys`` of them).  At each elementary
+    segment between boundary points the highest-priority active bucket
+    (lowest index) wins, and within it the span opened last (the
+    innermost on its thread), so both results are exclusive by
+    construction, sum to at most (t1 - t0), and the keys of one bucket
+    add up to the bucket."""
     n = len(BUCKET_PRIORITY)
     out = [0.0] * n
-    stages: Dict[str, float] = {}
+    stages = [0.0] * nkeys
     if t1 <= t0 or not intervals:
         return out, stages
     events: List[Tuple[float, int, int]] = []
@@ -242,8 +243,7 @@ def _project(intervals: List[Tuple[float, float, int, str]],
             for pri, open_here in enumerate(active):
                 if open_here:
                     out[pri] += t - prev
-                    key = intervals[open_here[-1]][3]
-                    stages[key] = stages.get(key, 0.0) + (t - prev)
+                    stages[intervals[open_here[-1]][3]] += t - prev
                     break
             prev = t
         open_here = active[intervals[idx][2]]
@@ -254,6 +254,42 @@ def _project(intervals: List[Tuple[float, float, int, str]],
         else:
             open_here.remove(idx)
     return out, stages
+
+
+_PRI_INDEX = {b: i for i, b in enumerate(BUCKET_PRIORITY)}
+# (op, stage) -> (a launch?, priority index or None, "<op>:<stage>"):
+# what the fold needs of a span, worked out once a process per pair
+_SPAN_KINDS: Dict[Tuple[str, str], Tuple[bool, Optional[int], str]] = {}
+
+
+def _span_kind(op: str, stage: str) -> Tuple[bool, Optional[int], str]:
+    kind = _SPAN_KINDS.get((op, stage))
+    if kind is None:
+        b = span_bucket(op, stage)
+        kind = _SPAN_KINDS[(op, stage)] = (
+            op.startswith("Kernel."), None if b is None else _PRI_INDEX[b],
+            f"{op}:{stage}")
+    return kind
+
+
+def _nodes(by_node: Dict[Optional[str], Dict[str, float]],
+           tracer) -> Dict[str, Dict[str, Any]]:
+    """The ledger's books by plan node: ``{path: {"op", "stages_s",
+    "counts"}}``, the query's own under ``"query"`` (its ``op`` None).
+    A node's seconds are not rounded: summed over the nodes they give
+    each key of the flat ``stages_s`` to its six places."""
+    ops = tracer.node_ops if tracer is not None else {}
+    counts = tracer.node_counts if tracer is not None else {}
+    out: Dict[str, Dict[str, Any]] = {}
+    for node, mine in by_node.items():
+        out["query" if node is None else node] = {
+            "op": ops.get(node), "stages_s": mine,
+            "counts": dict(counts.get(node, ()))}
+    for node, mine in counts.items():
+        if node not in by_node:
+            out["query" if node is None else node] = {
+                "op": ops.get(node), "stages_s": {}, "counts": dict(mine)}
+    return out
 
 
 def attribute(tracer=None, spans: Optional[Iterable] = None,
@@ -269,7 +305,7 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     scalar seconds measured outside the trace window (the server's
     queue wait) — they extend e2e rather than competing for it.
 
-    Returns ``{"buckets", "stages_s", "counts", "e2e_s",
+    Returns ``{"buckets", "stages_s", "counts", "nodes", "e2e_s",
     "unaccounted_s", "closed", "tolerance", "verdict", "dominant",
     "dominant_share", "launches"}`` with buckets rounded, exclusive,
     and summing (with ``unaccounted``) to ``e2e_s`` exactly;
@@ -278,8 +314,10 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
     to a bucket add up to it; an extra is the stage ``extra:<bucket>``);
     ``counts`` is what the query's operators counted on the host
     (``Tracer.counts``: probe groups, slots probed, sorts a rung);
-    ``launches`` counts the cached-kernel calls (the ``Kernel.<label>``
-    spans)."""
+    ``nodes`` is both again by plan node (``_nodes``: a key's seconds
+    over the nodes add up to its ``stages_s``, a name's counts to its
+    ``counts``); ``launches`` counts the cached-kernel calls (the
+    ``Kernel.<label>`` spans)."""
     if tracer is not None:
         spans = list(tracer.events)
         t0 = tracer.t_start
@@ -297,18 +335,32 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
         if e2e_s is not None:
             t1 = t0 + e2e_s
     e2e = max(t1 - t0, 0.0)
-    pri_index = {b: i for i, b in enumerate(BUCKET_PRIORITY)}
-    intervals: List[Tuple[float, float, int, str]] = []
+    # the sweep's keys: (plan path, "<op>:<stage>"), each an index
+    keys: Dict[Tuple[Optional[str], str], int] = {}
+    intervals: List[Tuple[float, float, int, int]] = []
     launches = 0
     for sp in spans:
-        if sp.op.startswith("Kernel."):
-            launches += 1
-        b = span_bucket(sp.op, sp.stage)
-        if b is None:
+        launch, pri, name = _span_kind(sp.op, sp.stage)
+        launches += launch
+        if pri is None:
             continue
-        intervals.append((sp.t0, sp.t1, pri_index[b],
-                          f"{sp.op}:{sp.stage}"))
-    per_pri, stages = _project(intervals, t0, t1)
+        k = (getattr(sp, "node", None), name)
+        kid = keys.get(k)
+        if kid is None:
+            kid = keys[k] = len(keys)
+        intervals.append((sp.t0, sp.t1, pri, kid))
+    per_pri, per_key = _project(intervals, len(keys), t0, t1)
+    # the books by operator are the books by node summed over nodes
+    stages: Dict[str, float] = {}
+    by_node: Dict[Optional[str], Dict[str, float]] = {}
+    for (node, key), kid in keys.items():
+        secs = per_key[kid]
+        if secs:
+            stages[key] = stages.get(key, 0.0) + secs
+            mine = by_node.get(node)
+            if mine is None:
+                mine = by_node[node] = {}
+            mine[key] = secs
     buckets = {b: per_pri[i] for i, b in enumerate(BUCKET_PRIORITY)}
     covered = sum(per_pri)
     unaccounted = max(e2e - covered, 0.0)
@@ -316,6 +368,7 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
         if name in buckets and secs:
             buckets[name] += float(secs)
             stages[f"extra:{name}"] = float(secs)
+            by_node.setdefault(None, {})[f"extra:{name}"] = float(secs)
             e2e += float(secs)
     buckets["unaccounted"] = unaccounted
     tol = float(tolerance)
@@ -327,6 +380,7 @@ def attribute(tracer=None, spans: Optional[Iterable] = None,
         "buckets": {b: round(s, 6) for b, s in buckets.items()},
         "stages_s": {k: round(s, 6) for k, s in stages.items()},
         "counts": dict(tracer.counts) if tracer is not None else {},
+        "nodes": _nodes(by_node, tracer),
         "e2e_s": round(e2e, 6),
         "unaccounted_s": round(unaccounted, 6),
         "closed": closed,

@@ -34,7 +34,8 @@ from __future__ import annotations
 import hashlib
 import json
 import threading
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, Iterable, Iterator, List, Optional,
+                    Sequence, Tuple)
 
 from spark_rapids_tpu.runtime import inflight
 from spark_rapids_tpu.runtime.inflight import BOOKS
@@ -140,6 +141,23 @@ def plan_signature(op: str, path: str, schema) -> str:
         fields = ""
     return hashlib.sha1(
         f"{path}/{op}({fields})".encode()).hexdigest()[:12]
+
+
+def plan_paths(plan) -> Iterator[Tuple[Any, str]]:
+    """``(node, path)`` for every node of a physical plan in pre-order,
+    the path being the node's place in the tree: ``"0"`` the root,
+    ``"0.1"`` its second child, ``"0.1.0"`` that one's first.  The one
+    name of a plan node: profile records, EXPLAIN ANALYZE and the
+    ledger's books by node (``runtime/trace.py::Tracer.bind``) all use
+    it.  A fused region's members are not nodes of the tree: their
+    pre-fusion paths are in the region's ``fusion_members``."""
+    stack = [(plan, "0")]
+    while stack:
+        node, path = stack.pop()
+        yield node, path
+        kids = node.children
+        for i in range(len(kids) - 1, -1, -1):
+            stack.append((kids[i], f"{path}.{i}"))
 
 
 class NodeStats:
@@ -335,7 +353,7 @@ class OpStatsCollector:
         ops: List[dict] = []
         exchanges: List[dict] = []
 
-        def walk(node, path: str):
+        def record(node, path: str):
             ns = self._nodes.get(id(node)) or NodeStats()
             rec: Dict[str, Any] = {
                 "op": node.name,
@@ -423,10 +441,9 @@ class OpStatsCollector:
                         mrec["self_s"] = share
                         mrec["total_s"] = share
                     ops.append(mrec)
-            for i, c in enumerate(node.children):
-                walk(c, f"{path}.{i}")
 
-        walk(plan, "0")
+        for node, path in plan_paths(plan):
+            record(node, path)
         out: Dict[str, Any] = {
             "record": "profile",
             "version": 1,

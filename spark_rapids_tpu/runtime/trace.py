@@ -13,9 +13,19 @@ Durations stay on ``time.perf_counter()``.  Where a jax profiler
 session is recording when the query starts (the benchmark's
 ``jax.profiler.start_trace``, ``spark.rapids.profile.enabled``), every
 span is also a ``jax.profiler.TraceAnnotation`` named
-``tpuq.<op>:<stage>`` with the ``query_id`` (and ``partition``) as
-stats: a host event on the clock the device plane uses, same thread,
-same nesting — one timeline, no exporter of its own.
+``tpuq.<op>:<stage>`` with the ``query_id`` (and ``partition``, and
+``node`` as ``<op>@<path>``) as stats: a host event on the clock the
+device plane uses, same thread, same nesting — one timeline, no
+exporter of its own.
+
+Books by plan node: once the query's physical plan is final the tracer
+is handed its root (``bind``) and every span and count carries the
+pre-order path of its operator (``"0"``, ``"0.1"``: the stats plane's
+``plan_paths``).  A span or count made with no node — a kernel launch,
+a spill, the split's counts — takes the node of the innermost span open
+on its thread; with nothing open, or under a query-level span
+(``Query``, ``Plan``, ``Result``, ``PumpTask``, ``QueryServer``), it is
+the query's.
 
 The event log is the reference's driver-log "plan conversion report"
 made machine-readable: one JSONL entry per query
@@ -48,13 +58,16 @@ from spark_rapids_tpu.runtime.inflight import BOOKS
 class Span:
     """One timed range on one thread.  ``child_time`` accumulates the
     durations of directly-nested spans (any operator), so
-    ``self_time = dur - child_time`` is this span's exclusive time."""
+    ``self_time = dur - child_time`` is this span's exclusive time;
+    ``node`` is the plan path of the operator it books under (None:
+    the query's)."""
 
     __slots__ = ("op", "stage", "tid", "t0", "t1", "child_time",
-                 "parent_op", "args", "ann")
+                 "parent_op", "args", "ann", "node")
 
     def __init__(self, op: str, stage: str, tid: int, t0: float,
-                 parent_op: Optional[str], args: Optional[dict]):
+                 parent_op: Optional[str], args: Optional[dict],
+                 node: Optional[str] = None):
         self.op = op
         self.stage = stage
         self.tid = tid
@@ -63,6 +76,7 @@ class Span:
         self.child_time = 0.0
         self.parent_op = parent_op
         self.args = args
+        self.node = node
         # the span's mirror on the profiler's clock (mirroring tracers)
         self.ann = None
 
@@ -136,8 +150,14 @@ class Tracer:
         self.dropped = 0
         self.events: List[Span] = []
         # what the query's operators counted on the host (probe groups,
-        # slots probed, sorts a rung): the ledger's ``counts``
+        # slots probed, sorts a rung): the ledger's ``counts``, and the
+        # same by plan path (None: the query's)
         self.counts: Dict[str, int] = {}
+        self.node_counts: Dict[Optional[str], Dict[str, int]] = {}
+        # id(node) -> plan path and plan path -> operator class of the
+        # bound plan (``bind``)
+        self.paths: Dict[int, str] = {}
+        self.node_ops: Dict[str, str] = {}
         # duck-typed flight-recorder hook (runtime/attribution.py):
         # when set, every closed span also lands in the recorder's
         # bounded ring — one extra deque append, no new timers
@@ -152,16 +172,41 @@ class Tracer:
             st = self._tls.stack = []
         return st
 
-    def begin(self, op: str, stage: str,
-              args: Optional[dict] = None) -> Span:
+    def bind(self, plan) -> None:
+        """Name the nodes of the query's final physical plan by their
+        paths; a fused region's members keep their pre-fusion paths
+        (they run only where the region fell open).  A node built while
+        the query runs is not named: it books under the node open when
+        it runs."""
+        from spark_rapids_tpu.runtime.stats import plan_paths
+        for node, path in plan_paths(plan):
+            self.paths[id(node)] = path
+            self.node_ops[path] = node.name
+            for m, sig in zip(getattr(node, "members", ()),
+                              getattr(node, "fusion_members", ())):
+                self.paths[id(m)] = sig["path"]
+                self.node_ops.setdefault(sig["path"], m.name)
+
+    def begin(self, op: str, stage: str, args: Optional[dict] = None,
+              node=None) -> Span:
         st = self._stack()
-        parent_op = st[-1].op if st else None
+        if st:
+            top = st[-1]
+            parent_op, where = top.op, top.node
+        else:
+            parent_op = where = None
+        if node is not None:
+            where = self.paths.get(id(node), where)
         sp = Span(op, stage, threading.get_ident(), time.perf_counter(),
-                  parent_op, args)
+                  parent_op, args, where)
         if self.mirror:
             stats = {"query_id": self.query_id}
             if args and "partition" in args:
                 stats["partition"] = args["partition"]
+            if where is not None:
+                # "<op>@<path>": the profiler would read a bare "0" or
+                # "0.1" as a number
+                stats["node"] = f"{self.node_ops.get(where)}@{where}"
             sp.ann = TraceAnnotation(f"tpuq.{op}:{stage}", **stats)
             sp.ann.__enter__()
         st.append(sp)
@@ -170,15 +215,22 @@ class Tracer:
     def end(self, span: Span) -> None:
         span.t1 = time.perf_counter()
         st = self._stack()
-        # pop back to (and including) this span — tolerate a leaked
-        # child that never closed (generator dropped mid-pump)
-        while st and st[-1] is not span:
-            _close_mirror(st.pop())
-        if st:
+        if st and st[-1] is span:
             st.pop()
+            if st:
+                st[-1].child_time += span.dur
+        elif span in st:
+            # pop back to (and including) this span — tolerate a leaked
+            # child that never closed (generator dropped mid-pump, or
+            # one that holds its span open across a yield)
+            while st[-1] is not span:
+                _close_mirror(st.pop())
+            st.pop()
+            if st:
+                st[-1].child_time += span.dur
+        # else: popped already as such a child, when the span it opened
+        # under closed; what is open on the thread now stays open
         _close_mirror(span)
-        if st:
-            st[-1].child_time += span.dur
         with self._lock:
             if len(self.events) < self.max_events:
                 self.events.append(span)
@@ -196,10 +248,17 @@ class Tracer:
         self.wall_s = time.perf_counter() - self.t_start
 
     # -- counts -------------------------------------------------------------
-    def count(self, name: str, value: int) -> None:
-        """Add to the query's count ``name``."""
+    def count(self, name: str, value: int, node=None) -> None:
+        """Add to the query's count ``name``, booked under ``node`` (or
+        the innermost span open on the calling thread)."""
+        where = self.paths.get(id(node)) if node is not None else None
+        if where is None:
+            st = self._stack()
+            where = st[-1].node if st else None
         with self._lock:
             self.counts[name] = self.counts.get(name, 0) + value
+            mine = self.node_counts.setdefault(where, {})
+            mine[name] = mine.get(name, 0) + value
 
     # -- export -------------------------------------------------------------
     def rollup(self) -> Dict[str, Dict[str, Any]]:
@@ -282,12 +341,12 @@ def span(op: str, stage: str, args: Optional[dict] = None):
     return tr.span(op, stage, args)
 
 
-def count(name: str, value: int) -> None:
+def count(name: str, value: int, node=None) -> None:
     """``Tracer.count`` on the calling thread's query; nothing where
     the thread works for no query."""
     tr = BOOKS.tracer
     if tr is not None:
-        tr.count(name, value)
+        tr.count(name, value, node)
 
 
 # ---------------------------------------------------------------------------

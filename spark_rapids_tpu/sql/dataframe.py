@@ -888,6 +888,9 @@ class DataFrame:
         try:
             plan = self._execute_plan()
             self._last_plan = plan
+            if tracer is not None:
+                # the plan is final: the books name its nodes by path
+                tracer.bind(plan)
             if conf.get(C.CACHE_ENABLED):
                 from spark_rapids_tpu import cache as cache_mod
                 cache_store = cache_mod.get_cache(conf)

@@ -4,6 +4,7 @@ epilogue, and the ring the benchmark's per-layer metrics read."""
 
 import glob
 import os
+import re
 import time
 
 import jax
@@ -99,6 +100,27 @@ def test_partition_is_a_stat_where_the_span_has_one(mirrored):
     _, events = mirrored
     pumps = [e for e in events if e[0] == "tpuq.PumpTask:pumpTask"]
     assert pumps and all("partition" in e[3] for e in pumps)
+
+
+def test_node_is_a_stat_where_the_span_has_one(mirrored):
+    """A span under a plan node carries the node, by the ledger's key
+    for it; a query-level span carries none."""
+    qid, events = mirrored
+    mine = [e for e in events
+            if e[0].startswith("tpuq.") and e[3].get("query_id") == qid]
+    ops = [e for e in mine if e[0].endswith(":pump")
+           or e[0].startswith("tpuq.Kernel.")]
+    assert ops and all("node" in e[3] for e in ops)
+    for name in ("tpuq.Query:execute", "tpuq.Plan:overrides",
+                 "tpuq.Result:resultD2H", "tpuq.PumpTask:pumpTask"):
+        assert all("node" not in e[3] for e in mine if e[0] == name), name
+    book = next(b for b in attribution.recent() if b["query_id"] == qid)
+    marked = {e[3]["node"] for e in mine if "node" in e[3]}
+    # "<op>@<path>": the node's operator and the ledger's path (a node
+    # whose every instant a more specific span won has no ledger entry)
+    assert {f"{n['op']}@{p}" for p, n in book["nodes"].items()
+            if p != "query"} <= marked
+    assert all(re.fullmatch(r"\w+@0(\.\d+)*", m) for m in marked), marked
 
 
 def test_profile_conf_yields_one_xplane_with_program_spans(tmp_path):

@@ -208,8 +208,8 @@ def test_split_matches_stable_sort_by_bucket(case):
 
 
 def test_the_split_counts_the_slots_it_gathers():
-    """Inside a query the split says how many indices went through a
-    gather: a chunk's capacity, nothing for the cuts."""
+    """Inside a query the split counts what it did: one chunk gathered
+    once, a slice a bucket cut from it."""
     device.ensure_initialized()
     rng = np.random.default_rng(36)
     batches = _batches(["long", "double"], [600, 100], True, rng)
@@ -227,5 +227,5 @@ def test_the_split_counts_the_slots_it_gathers():
             sp.close()
     assert counts["splitChunks"] == 1
     assert counts["spillableSlices"] == NBUCKETS
-    # 700 rows coalesced at their 1 024-slot bucket
-    assert counts["splitSlotsGathered"] == 1024
+    # the chunk's capacity is the host's arithmetic, not an observation
+    assert "splitSlotsGathered" not in counts
