@@ -16,6 +16,11 @@ partial/merge/final split mirrors the reference exactly — partial buffers
 (sum+count, min, max, first) are themselves columns, merged by the same
 segment reduction keyed on ``AggregateFunction.buffer_kinds``, so
 multi-batch and (later) post-shuffle final aggregation reuse one kernel.
+
+Where the first batch's partial holds few groups, the later batches of
+its stream skip the sort altogether (``sort_free_groupby``): masked
+reductions against those known groups into a running accumulator, the
+grouped form of the global aggregate's ``_reduce_batch``.
 """
 
 from __future__ import annotations
@@ -594,6 +599,97 @@ def _reduce_column(data: jnp.ndarray, valid: jnp.ndarray,
                         jnp.reshape(out_valid, (1,)))
 
 
+# Most groups a first partial may hold for the rest of its stream to be
+# reduced sort-free: the largest dictionary measured (8 .. 256 rows) at
+# which ``sort_free_groupby`` costs at most half of ``segment_groupby``
+# on a 1 M-row Q1-shaped batch on the v5e: 14.9 against 57.4 ms at 256
+# (docs/kernels.md "Reducing few groups").
+SORT_FREE_MAX_GROUPS = 256
+
+_TM_SORT_FREE = TM.REGISTRY.counter(
+    "tpuq_agg_sort_free_batches_total",
+    "batches the grouped aggregates folded into a running accumulator "
+    "of known groups, sort-free (the sum of aggSortFreeBatches)")
+
+
+def _dict_rows(n_groups: int) -> int:
+    """Slots of a sort-free pass's dictionary: a power of two, ≥ 8."""
+    return max(8, 1 << (n_groups - 1).bit_length())
+
+
+def _input_shapes(b: DeviceBatch) -> tuple:
+    """What of a batch's columns, besides its capacity, its key columns'
+    shapes can depend on."""
+    return tuple((c.data.shape[1:], c.data.dtype, c.validity is None,
+                  c.lengths is None) for c in b.columns)
+
+
+def sort_free_groupby(
+    key_cols: Sequence[DeviceColumn],
+    sel: jnp.ndarray,
+    value_cols: Sequence[Tuple[DeviceColumn, str]],
+    dict_keys: Sequence[DeviceColumn],
+    dict_sel: jnp.ndarray,
+    acc_cols: Sequence[DeviceColumn],
+    has_nans: bool = True,
+) -> Tuple[List[DeviceColumn], jnp.ndarray, jnp.ndarray]:
+    """Fold a batch into a running accumulator of KNOWN groups, with no
+    sort and no row movement: ``segment_groupby``'s answer where the
+    groups are few and known in advance.
+
+    ``dict_keys`` / ``dict_sel`` are the G groups (live rows marked),
+    ``acc_cols`` their buffers so far, one per ``value_cols`` entry
+    (kinds 'sum', 'min', 'max'; no decimal128).  Rows and groups are
+    encoded by the one ``ORD.group_sort_limbs`` and compared with the
+    grouping equivalence (``ORD.limb_neq``); each buffer is one masked
+    [G, B] reduction, ``_reduce_column`` mapped over the groups, and
+    combined into the accumulator by the same reduction over the pair
+    (sums and counts add, min/max keep the extreme).
+    No scatter: ``segment_sum`` is a serial loop on TPU.
+
+    Returns (new accumulator columns, unmatched [B], unmatched count):
+    the live rows whose key is none of the G, which the caller reduces
+    by the sort path."""
+    g = int(dict_sel.shape[0])
+    _, row_limbs = ORD.group_sort_limbs(list(key_cols), sel)
+    _, dict_limbs = ORD.group_sort_limbs(list(dict_keys), dict_sel)
+    match = dict_sel[:, None] & sel[None, :]
+    for r, d in zip(row_limbs, dict_limbs):
+        match = match & ~ORD.limb_neq(r[None, :], d[:, None])
+    # the dictionary's keys are distinct: a row matches one group at
+    # most, so a sum of (index + 1) over its matches is its group + 1;
+    # each reduction rebuilds its [G, B] mask from that one [B] vector,
+    # so the mask is never stored
+    iota = jnp.arange(g, dtype=jnp.int32)[:, None]
+    gid = jnp.sum(jnp.where(match, iota + 1, 0), axis=0) - 1
+    matched = gid >= 0
+    member = gid[None, :] == iota
+
+    def by_group(data, valid, rows, kind, dt, axis=None):
+        # _reduce_column once a group: row g of rows marks its rows of
+        # data (shared, axis None, or the group's own, axis 0)
+        col = jax.vmap(lambda d, v, r: _reduce_column(d, v, r, kind, dt,
+                                                      has_nans),
+                       in_axes=(axis, axis, 0))(data, valid, rows)
+        return col.data[:, 0], col.validity[:, 0]
+
+    out: List[DeviceColumn] = []
+    shared = {}  # an input asked for twice (sum(x), avg(x)) reduces once
+    for (c, kind), a in zip(value_cols, acc_cols):
+        pkey = (id(c.data), id(c.validity), kind)
+        if pkey not in shared:
+            shared[pkey] = by_group(c.data, c.valid_mask(), member, kind,
+                                    c.dtype)
+        v, got = shared[pkey]
+        data, valid = by_group(jnp.stack([a.data, v], axis=1),
+                               jnp.stack([a.valid_mask(), got], axis=1),
+                               jnp.ones((g, 2), jnp.bool_), kind, c.dtype,
+                               axis=0)
+        out.append(DeviceColumn(a.dtype, data, valid))
+    unmatched = sel & ~matched
+    return out, unmatched, jnp.sum(unmatched.astype(jnp.int32))
+
+
 def _one_row_batch(schema: T.StructType, cols: List[DeviceColumn],
                    bucket: int = 8) -> DeviceBatch:
     """Pad 1-row columns to the minimum bucket; row 0 live."""
@@ -1015,27 +1111,47 @@ class TpuHashAggregateExec(TpuExec):
             return concat_device_batches(batches[0].schema, batches,
                                          counts=gcounts)
 
-    def _decide_skip(self, outs1: List[DeviceBatch], n_in: int) -> bool:
-        """Should later batches skip the per-batch reduction?
-        ``outs1`` = the first input batch's partial(s) (plural when the
-        OOM-retry split it), ``n_in`` its live rows [REF:
-        GpuHashAggregateExec skipAggPassReductionRatio]."""
+    def _first_groups(self, outs1: List[DeviceBatch], n_in: int
+                      ) -> Optional[int]:
+        """The live groups of the first input batch's partial(s)
+        (``outs1``, plural when the OOM-retry split it; ``n_in`` its
+        live rows), pulled for the skip-agg-pass ratio [REF:
+        GpuHashAggregateExec skipAggPassReductionRatio]; None where
+        the ratio is off or the batch too small to establish it."""
         if self.skip_ratio >= 1.0:
-            return False
+            return None
         # small batches can't establish the ratio (64 rows → 60 groups
         # says nothing about 6M rows)
         if n_in < 4096:
-            return False
+            return None
         from spark_rapids_tpu.exec.basic import _overlapped_live_counts
-        n_groups = sum(_overlapped_live_counts(outs1))
-        return (n_groups / max(n_in, 1)) > self.skip_ratio
+        return sum(_overlapped_live_counts(outs1))
+
+    def _sort_free_fits(self, outs1: List[DeviceBatch],
+                        n_groups: Optional[int]) -> bool:
+        """May the rest of the stream fold into the first partial's
+        groups sort-free?  Few groups, one partial, and buffers that
+        ``sort_free_groupby`` reduces (sum/min/max, no decimal128).
+        Not where the fused filter kept no row of the first batch:
+        every later row would miss the empty dictionary."""
+        from spark_rapids_tpu.ops import decimal128 as D128
+        if (not n_groups or len(outs1) != 1
+                or n_groups > SORT_FREE_MAX_GROUPS
+                or _dict_rows(n_groups) > outs1[0].capacity):
+            return False
+        return (all(k in ("sum", "min", "max")
+                    for k in merge_kinds(self.fns))
+                and not any(D128.is128(dt) for fn in self.fns
+                            for dt in fn.buffer_dtypes()))
 
     def _partial_stream(self, stream, pre, pre_key, mgr
                         ) -> Tuple[Optional[List[DeviceBatch]], bool]:
         """Shared partial-pass driver (complete AND staged-partial
         modes): coalesce, run the first group's partial under retry,
-        decide skip-agg-pass from its reduction ratio, stream the rest.
-        Returns (partials | None for an empty stream, skip)."""
+        decide from its group count (skip-agg-pass when nearly every
+        row is a group, the sort-free pass when the groups are few),
+        stream the rest.  Returns (partials | None for an empty stream,
+        skip)."""
         from spark_rapids_tpu.exec.basic import _overlapped_live_counts
         from spark_rapids_tpu.runtime.memory import with_retry
         stream = self._coalesced(stream)
@@ -1053,9 +1169,16 @@ class TpuHashAggregateExec(TpuExec):
             outs1 = list(with_retry(
                 iter([first]), closure_partial,
                 max_attempts=mgr.retry_max_attempts, manager=mgr))
-            skip = self._decide_skip(outs1, n_in)
+            n_groups = self._first_groups(outs1, n_in)
+            skip = (n_groups is not None
+                    and n_groups / max(n_in, 1) > self.skip_ratio)
         if skip:
             self.count("skippedAggPasses", 1)
+        elif self._sort_free_fits(outs1, n_groups):
+            with self.timer("partialTime"):
+                return self._sort_free_stream(
+                    first, outs1[0], n_groups, stream, pre, pre_key,
+                    mgr), False
 
         def closure(b):
             with mgr.transient(b.nbytes()):
@@ -1068,6 +1191,124 @@ class TpuHashAggregateExec(TpuExec):
                 stream, closure, max_attempts=mgr.retry_max_attempts,
                 manager=mgr))
         return partials, skip
+
+    def _sort_free_stream(self, first: DeviceBatch, out1: DeviceBatch,
+                          n_groups: int, stream, pre, pre_key, mgr
+                          ) -> List[DeviceBatch]:
+        """The sort-free partial pass: the first partial's groups are
+        the dictionary, its buffers seed an accumulator of
+        ``_dict_rows(n_groups)`` rows, and every later batch folds into
+        it by ``sort_free_groupby``.  A batch's unmatched count is read
+        one batch late, once the next batch is dispatched: where it is
+        not 0, those rows alone take ``_partial`` and join the merge
+        beside the accumulator.  A batch whose key columns are shaped
+        unlike the dictionary's takes ``_partial`` whole.  Dictionary
+        and accumulator are this execution's: two queries may run the
+        node at once."""
+        from spark_rapids_tpu.runtime.memory import with_retry
+        acc = self._sort_free_seed(out1, _dict_rows(n_groups))
+        fits = {_input_shapes(first): True}
+        fallbacks: List[DeviceBatch] = []
+
+        def partial(b):
+            with mgr.transient(b.nbytes()):
+                return self._partial(b, pre, pre_key)
+
+        def fold(b):
+            nonlocal acc
+            shapes = _input_shapes(b)
+            if shapes not in fits:
+                fits[shapes] = self._keys_like(b, pre, acc)
+            if not fits[shapes]:
+                return partial(b)
+            with mgr.transient(b.nbytes()):
+                acc2, unmatched, n = self._sort_free(b, acc, pre, pre_key)
+            acc = acc2
+            n.copy_to_host_async()
+            return b, unmatched, n
+
+        def settle(b, unmatched, n):
+            if int(np.asarray(n)):
+                self.count("aggSortFreeFallbacks", 1)
+                fallbacks.extend(with_retry(
+                    iter([b.with_sel(unmatched)]), partial,
+                    max_attempts=mgr.retry_max_attempts, manager=mgr))
+
+        pending = None
+        for res in with_retry(stream, fold,
+                              max_attempts=mgr.retry_max_attempts,
+                              manager=mgr):
+            if pending is not None:
+                settle(*pending)
+                pending = None
+            if isinstance(res, DeviceBatch):
+                self.count("aggSortFreeFallbacks", 1)
+                fallbacks.append(res)
+            else:
+                self.count("aggSortFreeBatches", 1)
+                _TM_SORT_FREE.inc()
+                pending = res
+        if pending is not None:
+            settle(*pending)
+        return [acc] + fallbacks
+
+    def _sort_free_seed(self, out1: DeviceBatch, rows: int) -> DeviceBatch:
+        """The first partial's front ``rows`` slots (its groups are at
+        the front): the dictionary's keys and the accumulator's seed."""
+        from spark_rapids_tpu.runtime.kernel_cache import cached_kernel
+
+        def build():
+            def run(p):
+                return jax.tree_util.tree_map(lambda x: x[:rows], p)
+            return run
+
+        return cached_kernel(("agg_sort_free_seed", rows), build)(out1)
+
+    def _keys_like(self, batch: DeviceBatch, pre, acc: DeviceBatch
+                   ) -> bool:
+        """Do ``batch``'s key columns come out shaped as the
+        dictionary's (string widths, null planes)?  Traced, not run."""
+        nk = len(self.grouping)
+
+        def keys(b):
+            if pre is not None:
+                b = pre(b)
+            return [g.eval_tpu(b) for g in self.grouping]
+
+        def trailing(cols):
+            leaves, tree = jax.tree_util.tree_flatten(list(cols))
+            return tree, [(x.shape[1:], x.dtype) for x in leaves]
+
+        return (trailing(jax.eval_shape(keys, batch))
+                == trailing(acc.columns[:nk]))
+
+    def _sort_free(self, batch: DeviceBatch, acc: DeviceBatch, pre=None,
+                   pre_key=()):
+        """One batch into the accumulator: ``sort_free_groupby`` fused
+        with the upstream filter/project, as ``_partial`` is.  Returns
+        (accumulator, unmatched [B], unmatched count)."""
+        from spark_rapids_tpu.runtime.kernel_cache import (
+            cached_kernel, fingerprint)
+        grouping, fns = self.grouping, self.fns
+        nk = len(grouping)
+        has_nans = self.has_nans
+
+        def build():
+            def run(b, a):
+                if pre is not None:
+                    b = pre(b)
+                keys = [g.eval_tpu(b) for g in grouping]
+                vals = update_value_cols(fns, b)
+                bufs, unmatched, n = sort_free_groupby(
+                    keys, b.sel, vals, a.columns[:nk], a.sel,
+                    a.columns[nk:], has_nans=has_nans)
+                return (DeviceBatch(a.schema, a.columns[:nk] + tuple(bufs),
+                                    a.sel, compacted=True), unmatched, n)
+            return run
+
+        key = ("agg_sort_free", pre_key, has_nans, fingerprint(grouping),
+               fingerprint(fns), acc.capacity)
+        return cached_kernel(key, build)(batch, acc)
 
     def _execute_grouped(self, src, pre, pre_key) -> List[DeviceBatch]:
         """Update-per-batch under the OOM-retry framework: a RetryOOM

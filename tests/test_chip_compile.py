@@ -111,6 +111,19 @@ def test_q1_shaped_groupby_compiles(one_chip):
     assert hlo.count(" gather(") == 6
 
 
+@pytest.mark.parametrize("gcap", [8, 256])
+def test_q1_shaped_sort_free_compiles(one_chip, gcap):
+    # a batch folded into a dictionary of gcap groups: masked reductions
+    # only, and the [G, B] mask is never stored
+    from test_agg_sort_free import q1_shaped_sort_free
+    fn, shapes = q1_shaped_sort_free(BATCH_ROWS, gcap)
+    c = _compile(fn, one_chip, *shapes)
+    hlo = c.as_text()
+    for op in (" sort(", " gather(", " scatter("):
+        assert op not in hlo, op
+    assert c.memory_analysis().temp_size_in_bytes < 16 * BATCH_ROWS
+
+
 def test_compact_order_compiles(one_chip):
     # the sort of the dead flag alone: one program a capacity
     from spark_rapids_tpu.columnar import column as C
